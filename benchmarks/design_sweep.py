@@ -25,11 +25,12 @@ atomically (tmp + rename).
 
 Env knobs
 ---------
-``REPRO_XLA_CACHE_DIR``
-    Persistent XLA compilation-cache directory (default
-    ``~/.cache/repro/jax``; ``off``/``none``/``0``/empty disables).
-    With a warm cache, "cold" sweeps skip their XLA compiles entirely —
-    across benchmark runs and CI jobs.
+``JAX_COMPILATION_CACHE_DIR``
+    Persistent XLA compilation-cache directory (default: the
+    git-ignored ``.jax_cache/`` at the repo root;
+    ``JAX_ENABLE_COMPILATION_CACHE=false`` disables it).  With a warm
+    cache, "cold" sweeps skip their XLA compiles entirely — across
+    benchmark runs and CI jobs.
 ``REPRO_SWEEP_SHARDS``
     Lane-axis shard count for the fused grid kernel (``auto`` = one
     shard per jax device, an integer is clamped to the device count,

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import argparse
 
+from repro.core.compilecache import enable_compilation_cache
+
 from . import (accuracy_sweep, chaos_sweep, common, design_sweep,
                fig4_survey, fig5_validation, fig6_tech, fig7_casestudy,
                kernel_bench, lm_imc_casestudy, roofline_table,
@@ -63,6 +65,7 @@ def main(argv=None) -> None:
     if unknown:
         ap.error(f"unknown benchmark(s) {unknown}; see --list")
 
+    enable_compilation_cache()
     common.header()
     for name in names:
         BENCHMARKS[name]()

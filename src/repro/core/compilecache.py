@@ -2,25 +2,31 @@
 
 Cold sweep time is dominated by XLA compiles that are identical from
 process to process (the fused grid kernel compiles once per distinct
-lattice shape).  JAX ships a persistent compilation cache
-(``jax.experimental.compilation_cache``) that serializes compiled
-executables to a directory keyed by HLO fingerprint; enabling it makes
-every process after the first start warm — locally, across benchmark
-runs, and across CI jobs when the directory is carried by
-``actions/cache``.
+lattice shape).  JAX ships a persistent compilation cache that
+serializes compiled executables to a directory keyed by HLO
+fingerprint; enabling it makes every process after the first start
+warm.
 
-Env knobs (all read at first :func:`enable_compilation_cache` call):
+Where the cache lives (resolved at the first
+:func:`enable_compilation_cache` call):
 
-``REPRO_XLA_CACHE_DIR``
-    Cache directory.  Unset -> ``$XDG_CACHE_HOME/repro/jax`` (or
-    ``~/.cache/repro/jax``).  The values ``""``, ``"0"``, ``"off"``,
-    ``"none"``, ``"disabled"`` disable persistence entirely.
+* ``JAX_COMPILATION_CACHE_DIR`` set -> jax's own setting is left alone;
+* otherwise -> the fixed in-checkout directory ``<repo>/.jax_cache``
+  (git-ignored).  The path is part of the cache key, so it never
+  carries a temp name, pid or timestamp;
+* ``JAX_ENABLE_COMPILATION_CACHE=false`` -> persistence is off and no
+  directory is configured or created.
 
 The thresholds ``jax_persistent_cache_min_entry_size_bytes`` and
 ``jax_persistent_cache_min_compile_time_secs`` are forced to "cache
 everything": the sweep kernels compile in fractions of a second each,
 below jax's default 1s persistence floor, which would silently skip
 exactly the compiles we want to persist.
+
+Entry points (``chip_smoke.py``, ``launch/serve.py:main``,
+``benchmarks/run.py``) enable the cache before their first compile; the
+kernel builders call it again lazily, which is a no-op after the first
+call.
 """
 
 from __future__ import annotations
@@ -30,39 +36,31 @@ from pathlib import Path
 
 from .. import obs
 
-_DISABLED_VALUES = {"", "0", "off", "none", "disabled", "false"}
+#: ``<repo>/.jax_cache``: this file is ``<repo>/src/repro/core/...``
+DEFAULT_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 #: tri-state: None = not yet configured, "" = disabled, else the dir
 _STATE: dict[str, str | None] = {"dir": None}
 
 
-def _default_dir() -> str:
-    xdg = os.environ.get("XDG_CACHE_HOME")
-    base = Path(xdg) if xdg else Path.home() / ".cache"
-    return str(base / "repro" / "jax")
-
-
-def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
+def enable_compilation_cache() -> str | None:
     """Idempotently enable jax's persistent compilation cache.
 
-    ``cache_dir`` overrides the env/default resolution (tests use
-    this); pass-through no-op on every call after the first.  Returns
-    the active cache directory, or ``None`` when persistence is
-    disabled via env.
+    Returns the active cache directory, or ``None`` when persistence is
+    disabled through ``JAX_ENABLE_COMPILATION_CACHE``.
     """
     if _STATE["dir"] is not None:
         return _STATE["dir"] or None
-    if cache_dir is None:
-        cache_dir = os.environ.get("REPRO_XLA_CACHE_DIR")
-        if cache_dir is None:
-            cache_dir = _default_dir()
-    if cache_dir.strip().lower() in _DISABLED_VALUES:
-        _STATE["dir"] = ""
-        return None
     import jax
 
-    Path(cache_dir).mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not jax.config.jax_enable_compilation_cache:
+        _STATE["dir"] = ""
+        return None
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if not cache_dir:
+        cache_dir = DEFAULT_DIR
+        Path(cache_dir).mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # persist every executable: the grid kernels compile fast enough to
     # fall under jax's default floors, which would skip them silently
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

@@ -118,7 +118,7 @@ from .. import obs
 from ..faults.model import (FaultSpec, SurvivorMask, fault_legal,
                             mapping_survives, survivor_mask)
 from .designs import MacroBatch
-from .energy import EnergyBreakdown
+from .energy import EnergyBreakdown, fold_add
 from .hardware import IMCMacro
 from .mapping import (MappingCost, candidate_batch, enumerate_mappings,
                       evaluate, evaluate_batch)
@@ -140,7 +140,7 @@ class LayerResult:
 
     @property
     def total_energy_fj(self) -> float:
-        return self.macro_energy_fj + sum(self.memory_energy_fj.values())
+        return self.macro_energy_fj + fold_add(self.memory_energy_fj.values())
 
     @property
     def edp(self) -> float:
@@ -170,7 +170,7 @@ class NetworkResult:
 
     @property
     def total_energy_fj(self) -> float:
-        return sum(l.total_energy_fj for l in self.layers)
+        return fold_add(l.total_energy_fj for l in self.layers)
 
     @property
     def total_cycles(self) -> float:
@@ -275,7 +275,7 @@ def best_mapping_batched(layer: Layer, macro: IMCMacro, mem: MemoryModel,
         raise ValueError(f"no legal mapping for {layer.name} on {macro.name}")
     costs = evaluate_batch(layer, macro, batch, alpha=alpha)
     mem_fj = mem.traffic_energy_batch(costs, resident)
-    # Scalar association: sum(dict.values()) == ((w + i) + o) + p, then
+    # Scalar association: fold_add(dict.values()) == ((w + i) + o) + p, then
     # macro total + memory total.
     mem_total = ((mem_fj["weights"] + mem_fj["inputs"])
                  + mem_fj["outputs"]) + mem_fj["psums"]
@@ -1072,7 +1072,7 @@ def _sweep_networks_traced(networks, designs, objective, alpha, mem,
     covers lattice build, every bucket dispatch and result assembly, so
     trace wall-time coverage of a sweep is the root span itself."""
     # persist XLA executables across processes (no-op after first call;
-    # env knob REPRO_XLA_CACHE_DIR — see core.compilecache)
+    # see core.compilecache)
     from .compilecache import enable_compilation_cache
     enable_compilation_cache()
     scheds = _normalize_schedules(schedules)

@@ -88,6 +88,17 @@ DEFAULT_ALPHA = 0.35
 WRITE_CINV_FACTOR = 4.0
 
 
+def fold_add(values):
+    """``((a + b) + c) + ...``, left to right: the association every
+    pricing path reproduces bitwise.  Not ``sum()``, which compensates
+    float rounding from Python 3.12 on and so can differ in the last
+    bit."""
+    total = 0
+    for v in values:
+        total = total + v
+    return total
+
+
 @dataclasses.dataclass(frozen=True)
 class MacroTile:
     """One tiled MVM execution resident on a macro.
@@ -619,7 +630,6 @@ def _sharded_grid_kernel(shards: int, tile_rank: int):
     if fn is None:
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         from .compilecache import enable_compilation_cache
@@ -636,10 +646,10 @@ def _sharded_grid_kernel(shards: int, tile_rank: int):
         else:
             tile_spec = P(None, None, "lane")
             out_spec = P(None, None, "lane")
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             wrapped, mesh=mesh,
             in_specs=(col_spec,) * 19 + (tile_spec,) * 5 + (P(),),
-            out_specs=(out_spec,) * 10, check_rep=False))
+            out_specs=(out_spec,) * 10, check_vma=False))
         _SHARDED_GRID_KERNELS[key] = fn
     return fn
 
@@ -670,7 +680,7 @@ def _dispatch_grid_kernel(designs, n_inputs, rows_used, cols_used,
     later blocks on the results, e.g. the reduced sweep's finalize
     span).  Returns ``(parts, sharded)``.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     # 1-D tile args broadcast straight against the (D, 1) design columns;
     # layer-stacked (..., L, C) args get the design axis spliced in
@@ -691,8 +701,6 @@ def _dispatch_grid_kernel(designs, n_inputs, rows_used, cols_used,
     if shards > 1 and n_inputs.shape[-1] % shards == 0 \
             and rows_used.shape == n_inputs.shape \
             and cols_used.shape == n_inputs.shape:
-        import jax
-
         if shards <= jax.device_count():
             kern = _sharded_grid_kernel(
                 shards, 1 if n_inputs.ndim == 1 else 3)
@@ -706,7 +714,7 @@ def _dispatch_grid_kernel(designs, n_inputs, rows_used, cols_used,
     with obs.span("energy.grid_kernel", lanes=int(n_inputs.shape[-1]),
                   designs=len(designs.rows), sharded=sharded,
                   realized=realize):
-        with enable_x64():
+        with jax.enable_x64(True):
             parts = kern(
                 col(cst["analog"]), col(cst["mmux1"]), col(cst["rows"]),
                 col(cst["d1"]), col(cst["bw"]), col(cst["m"]),
@@ -740,7 +748,7 @@ def tile_energy_grid(designs, n_inputs, rows_used, cols_used,
     into (D, C) outputs.  ``schedule_os`` marks output-stationary tile
     columns (bool, broadcastable against the tile axis).  One fused
     ``jax.jit`` pass (on whatever backend JAX finds; float64 via
-    ``jax.experimental.enable_x64``) prices the lattice; the result is
+    ``jax.enable_x64``) prices the lattice; the result is
     bitwise identical to running the scalar oracle at every
     (design, tile) pair — the same contract ``tile_energy_batch``
     honours per macro, extended over designs.
@@ -1024,7 +1032,7 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
     registers its own distinct kernel-shape entry (the compile-count
     proxy — it re-traces per (lane count, segment count, objective)).
     """
-    from jax.experimental import enable_x64
+    import jax
 
     (n_inputs, rows_used, cols_used, weight_loads,
      sched_os) = _coerce_tile_args(n_inputs, rows_used, cols_used,
@@ -1053,7 +1061,7 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
         with obs.span("energy.reduce_kernel", lanes=int(lanes),
                       designs=int(n_designs), segments=len(seg_bounds),
                       objective=objective, fused_terms=False):
-            with enable_x64():
+            with jax.enable_x64(True):
                 terms = terms_k(e_wl, e_bl, e_logic, e_adc, e_tree,
                                 e_dac, e_write, x_adc, x_dac,
                                 active_macros, weight_tiles, weight_bits,
@@ -1071,7 +1079,7 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
     with obs.span("energy.grid_kernel", lanes=int(lanes),
                   designs=int(n_designs), sharded=False, realized=False,
                   fused_terms=True):
-        with enable_x64():
+        with jax.enable_x64(True):
             terms = fused_k(
                 col(cst["analog"]), col(cst["mmux1"]), col(cst["rows"]),
                 col(cst["d1"]), col(cst["bw"]), col(cst["m"]),
@@ -1089,7 +1097,7 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
     with obs.span("energy.reduce_kernel", lanes=int(lanes),
                   designs=int(n_designs), segments=len(seg_bounds),
                   objective=objective, fused_terms=True):
-        with enable_x64():
+        with jax.enable_x64(True):
             return argmin_k(*terms, wt_ipt, cc_per_input, write_cycles,
                             legal, seg_ids, seg_starts)
 

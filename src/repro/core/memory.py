@@ -27,6 +27,7 @@ import dataclasses
 import numpy as np
 
 from . import tech as _tech
+from .energy import fold_add
 from .mapping import MappingCost, MappingCostBatch
 
 #: Global-buffer read/write energy per bit, in units of C_inv * V^2.
@@ -77,7 +78,7 @@ class MemoryModel:
 
     def total_traffic_energy_fj(self, cost: MappingCost,
                                 resident_bytes: int = 0) -> float:
-        return sum(self.traffic_energy_fj(cost, resident_bytes).values())
+        return fold_add(self.traffic_energy_fj(cost, resident_bytes).values())
 
     def traffic_energy_batch(self, costs: MappingCostBatch,
                              resident_bytes: int = 0) -> dict:

@@ -111,7 +111,7 @@ def _evaluate_grid_impl(forward: ForwardFn, designs: MacroBatch,
                         noise: NoiseSpec, n_seeds: int,
                         seed: int) -> FidelityGrid:
     # persist the per-group jit executables across processes (no-op
-    # after the first call; env knob REPRO_XLA_CACHE_DIR)
+    # after the first call; see core.compilecache)
     from repro.core.compilecache import enable_compilation_cache
     enable_compilation_cache()
     base = jax.random.fold_in(jax.random.PRNGKey(seed), 0)

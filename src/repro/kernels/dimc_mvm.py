@@ -65,12 +65,23 @@ def dimc_mvm(x: jax.Array, w: jax.Array, *, bi: int = 8, bw: int = 8,
 
     Block shapes are MXU-aligned (multiples of (8,128)); VMEM working set
     is bm*bk + bk*bn + bm*bn 4-byte words — (128,128,512) ≈ 0.6 MB.
+
+    K is padded up to a multiple of ``bk`` with zeros: a partial block
+    on the reduction axis would otherwise read past the array, whose
+    contents are unspecified on the TPU.  Zero columns add nothing to
+    the adder tree.  Ragged M/N edges need no padding — out-of-bounds
+    rows and columns only reach output elements that are never stored.
     """
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
+    if k % bk:
+        pad = bk - k % bk
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+        w = jnp.pad(w, ((0, pad), (0, 0)))
+        k = k + pad
+    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), k // bk)
     kernel = functools.partial(_dimc_kernel, bi=bi, bw=bw,
                                signed_inputs=signed_inputs)
     return pl.pallas_call(

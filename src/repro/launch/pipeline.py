@@ -23,7 +23,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -38,9 +37,9 @@ def gpipe(stage_fn, stage_params, x, *, mesh: Mesh, axis: str = "pod"):
     fwd_perm = [(i, i + 1) for i in range(n_stages - 1)]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def run(params_local, x_all):
         sid = jax.lax.axis_index(axis)
         params_here = jax.tree.map(lambda t: t[0], params_local)

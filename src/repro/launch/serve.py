@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs, obs
+from repro.core.compilecache import enable_compilation_cache
 from repro.faults.trace import NodeLossError, TransientFault
 from repro.models.common import Dist
 from repro.models.lm import LM
@@ -367,6 +368,25 @@ class ServeLoop:
         return tokens, stats
 
 
+def build(arch: str, *, smoke: bool = False, batch: int = 4,
+          prompt_len: int = 16, gen: int = 24, model_axis: int = 16,
+          seed: int = 0, monitor: StepMonitor | None = None):
+    """The serving stack :func:`main` runs: config, a ``(data, model)``
+    mesh over every device when there is more than one, weights and
+    prompts drawn from ``seed``, and the :class:`ServeLoop`.  Returns
+    ``(loop, params, prompts)``."""
+    cfg = configs.get_smoke(arch) if smoke else configs.get(arch)
+    dist = Dist(mesh=None) if len(jax.devices()) == 1 else \
+        Dist(mesh=make_mesh_from_devices(model_axis=model_axis))
+    lm = LM(cfg, dist)
+    params = lm.init(jax.random.PRNGKey(seed))
+    rng = np.random.default_rng(seed)
+    prompts = rng.integers(0, cfg.vocab_size,
+                           (batch, prompt_len)).astype(np.int32)
+    loop = ServeLoop(lm, batch, prompt_len + gen, monitor=monitor)
+    return loop, params, prompts
+
+
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b",
@@ -378,19 +398,12 @@ def main(argv=None) -> dict:
     ap.add_argument("--model-axis", type=int, default=16)
     args = ap.parse_args(argv)
 
-    cfg = configs.get_smoke(args.arch) if args.smoke else \
-        configs.get(args.arch)
-    dist = Dist(mesh=None) if len(jax.devices()) == 1 else \
-        Dist(mesh=make_mesh_from_devices(model_axis=args.model_axis))
-    lm = LM(cfg, dist)
-    params = lm.init(jax.random.PRNGKey(0))
-
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(0, cfg.vocab_size,
-                           (args.batch, args.prompt_len)).astype(np.int32)
+    enable_compilation_cache()
     monitor = StepMonitor() if obs.trace_enabled() else None
-    loop = ServeLoop(lm, args.batch, args.prompt_len + args.gen,
-                     monitor=monitor)
+    loop, params, prompts = build(
+        args.arch, smoke=args.smoke, batch=args.batch,
+        prompt_len=args.prompt_len, gen=args.gen,
+        model_axis=args.model_axis, monitor=monitor)
     tokens, stats = loop.generate(params, prompts, args.gen)
     print(f"[serve] batch={args.batch} prompt={args.prompt_len} "
           f"gen={args.gen}: prefill {stats['prefill_s']:.2f}s, "

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from typing import Any, Callable, Mapping
 
 import jax
@@ -189,18 +190,27 @@ def init_params(specs: ParamTree, key: jax.Array, param_dtype=jnp.float32,
     def build(path, spec: ParamSpec):
         k = key
         for part in path:
-            k = jax.random.fold_in(k, hash(part) % (2 ** 31))
+            # crc32, not hash(): str hashes are salted per process, so
+            # the weights would not follow from the seed alone
+            k = jax.random.fold_in(k, zlib.crc32(part.encode()) & 0x7FFFFFFF)
         dtype = spec.dtype or param_dtype
-        if spec.init == "zeros":
-            v = jnp.zeros(spec.shape, dtype)
-        elif spec.init == "ones":
-            v = jnp.ones(spec.shape, dtype)
-        else:
-            std = 0.02 if spec.init == "embed" else spec.stddev()
-            v = (jax.random.normal(k, spec.shape, jnp.float32) * std
-                 ).astype(dtype)
+        std = 0.02 if spec.init == "embed" else spec.stddev()
+
+        def make(k):
+            if spec.init == "zeros":
+                return jnp.zeros(spec.shape, dtype)
+            if spec.init == "ones":
+                return jnp.ones(spec.shape, dtype)
+            return (jax.random.normal(k, spec.shape, jnp.float32) * std
+                    ).astype(dtype)
+
         sh = dist.sharding(spec.logical, spec.shape)
-        return jax.device_put(v, sh) if sh is not None else v
+        if sh is None:
+            return make(k)
+        # each device draws its own shard: a whole leaf built on one
+        # device first may not fit there (glm4-9b's stacked FFN weights
+        # alone are 9 GB in f32)
+        return jax.jit(make, out_shardings=sh)(k)
 
     out: dict = {}
     for path, spec in _iter_specs(specs):

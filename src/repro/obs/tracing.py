@@ -7,9 +7,8 @@ the nesting explicit for the validator and the JSONL export; Chrome's
 trace viewer infers it from interval containment per thread).
 
 Off by default: tracing is enabled by the ``REPRO_TRACE`` env knob
-(same truthy convention as ``REPRO_XLA_CACHE_DIR`` — ``""``/``"0"``/
-``"off"``/``"false"``/``"none"``/``"disabled"`` mean off, anything else
-on), resolved once and overridable in-process via
+(``""``/``"0"``/``"off"``/``"false"``/``"none"``/``"disabled"`` mean
+off, anything else on), resolved once and overridable in-process via
 :func:`set_trace_enabled`.  When disabled, :func:`span` returns a
 shared no-op context manager without allocating — the per-call cost is
 one dict build for the kwargs plus one flag check, which is what keeps

@@ -16,7 +16,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -63,8 +62,8 @@ def compressed_psum(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
     """
     spec = P()  # x replicated w.r.t. the reduced axis
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=spec,
-                       out_specs=spec, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=spec,
+                       out_specs=spec, check_vma=False)
     def _inner(xl):
         amax_l = jnp.max(jnp.abs(xl))
         amax = jax.lax.pmax(amax_l, axis)

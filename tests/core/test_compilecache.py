@@ -13,13 +13,14 @@ import subprocess
 import sys
 from pathlib import Path
 
-from repro.core.compilecache import compilation_cache_info
+from repro.core.compilecache import DEFAULT_DIR, compilation_cache_info
+
+REPO = Path(__file__).resolve().parent.parent.parent
 
 _WORKER = """
 import json, os
 from repro.core import designs, dse, workloads
-from repro.core.compilecache import (compilation_cache_info,
-                                     enable_compilation_cache)
+from repro.core.compilecache import compilation_cache_info
 
 grid = designs.macro_grid(rows=(64,), cols=(256,), adc_bits=(5,),
                           dac_bits=(2,), m_mux=(1,), tech_nm=(22,))
@@ -31,17 +32,15 @@ print(json.dumps({"dir": info["dir"], "entries": info["entries"],
 """
 
 
-def _run_worker(cache_env: str | None, tmp_path: Path) -> dict:
-    repo = Path(__file__).resolve().parent.parent.parent
-    env = {"PYTHONPATH": str(repo / "src"), "PATH": "/usr/bin:/bin",
+def _run_worker(tmp_path: Path, **cache_env: str) -> dict:
+    env = {"PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin",
            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
-           # HOME inside tmp so the default-dir branch can't touch the
-           # real user cache from a test
+           # HOME inside tmp so no branch can touch the real user cache
+           # from a test
            "HOME": str(tmp_path)}
     if "TMPDIR" in os.environ:
         env["TMPDIR"] = os.environ["TMPDIR"]
-    if cache_env is not None:
-        env["REPRO_XLA_CACHE_DIR"] = cache_env
+    env.update(cache_env)
     res = subprocess.run([sys.executable, "-c", _WORKER],
                          capture_output=True, text=True, env=env,
                          timeout=900)
@@ -50,35 +49,36 @@ def _run_worker(cache_env: str | None, tmp_path: Path) -> dict:
 
 
 def test_sweep_populates_cache_dir_and_warm_start(tmp_path):
-    """A sweep persists its XLA executables into the env-configured
-    directory; a fresh process reuses them (entry count does not grow)
-    and reproduces identical results."""
+    """``JAX_COMPILATION_CACHE_DIR`` is honoured: a sweep persists its
+    XLA executables there; a fresh process reuses them (entry count does
+    not grow) and reproduces identical results."""
     cache = tmp_path / "xla"
-    cold = _run_worker(str(cache), tmp_path)
+    cold = _run_worker(tmp_path, JAX_COMPILATION_CACHE_DIR=str(cache))
     assert cold["dir"] == str(cache)
     assert cold["entries"] > 0
     assert cold["bytes"] > 0
 
-    warm = _run_worker(str(cache), tmp_path)
+    warm = _run_worker(tmp_path, JAX_COMPILATION_CACHE_DIR=str(cache))
     assert warm["entries"] == cold["entries"]    # hits, not re-compiles
     assert warm["energy0"] == cold["energy0"]    # bitwise across processes
 
 
 def test_cache_disabled_by_env(tmp_path):
-    """``off`` (and friends) disable persistence: no directory appears,
-    the sweep still runs."""
-    out = _run_worker("off", tmp_path)
+    """jax's own ``JAX_ENABLE_COMPILATION_CACHE=false`` disables
+    persistence: no directory is configured, the sweep still runs."""
+    out = _run_worker(tmp_path, JAX_ENABLE_COMPILATION_CACHE="false")
     assert out["dir"] is None
     assert out["entries"] == 0
-    # nothing created under the fake HOME's default location either
-    assert not (tmp_path / ".cache" / "repro").exists()
+    assert not (tmp_path / ".cache").exists()
 
 
-def test_default_dir_under_home(tmp_path):
-    """With no env knob the cache lands in ``~/.cache/repro/jax``."""
-    out = _run_worker(None, tmp_path)
-    assert out["dir"] == str(tmp_path / ".cache" / "repro" / "jax")
+def test_default_dir_in_checkout(tmp_path):
+    """With no env knob the cache lands in the fixed, git-ignored
+    ``<repo>/.jax_cache`` and nowhere under HOME."""
+    out = _run_worker(tmp_path)
+    assert out["dir"] == str(REPO / ".jax_cache") == DEFAULT_DIR
     assert out["entries"] > 0
+    assert not (tmp_path / ".cache").exists()
 
 
 def test_cache_info_tolerates_unconfigured_state():

@@ -67,3 +67,40 @@ def test_weight_plane_recombination_identity():
     recon = sum((-(1 << j) if j == 3 else (1 << j)) * p
                 for j, p in enumerate(planes))
     np.testing.assert_array_equal(np.asarray(recon), np.asarray(w))
+
+
+def _pallas_operand_shapes(fn, *args):
+    """Shapes the ``pallas_call`` inside ``fn`` receives."""
+    import jax
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return [tuple(v.aval.shape) for v in eqn.invars]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found = walk(sub)
+                if found:
+                    return found
+        return None
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("m,k,n,bk", [(8, 2816, 256, 512),
+                                      (16, 600, 128, 512),
+                                      (8, 600, 64, 256)])
+def test_dimc_ragged_k_zero_padded(m, k, n, bk):
+    """K not a multiple of ``bk`` (qwen1.5-0.5b's d_ff=2816 with the
+    default bk=512): the kernel zero-pads the reduction axis to whole
+    blocks instead of reading past the array — out-of-bounds block
+    contents are unspecified on the TPU — and stays exact."""
+    rng = np.random.default_rng(k + n)
+    x = jnp.asarray(rng.integers(-128, 128, (m, k)), jnp.int8)
+    w = jnp.asarray(rng.integers(-128, 128, (k, n)), jnp.int8)
+    y = ops.dimc_matmul(x, w, bk=bk)
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.asarray(ref.dimc_mvm_ref(x, w, 8, 8)))
+    kp = -(-k // bk) * bk
+    shapes = _pallas_operand_shapes(
+        lambda a, b: ops.dimc_matmul(a, b, bk=bk), x, w)
+    assert shapes == [(m, kp), (kp, n)], shapes

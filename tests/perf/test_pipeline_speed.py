@@ -77,7 +77,7 @@ def _run_pipeline_guard(pipeline: str) -> dict:
            # cold must mean a cold compile in both modes: a warm
            # persistent XLA cache would shrink exactly the compile wall
            # the pipeline overlaps with builder work
-           "REPRO_XLA_CACHE_DIR": "off",
+           "JAX_ENABLE_COMPILATION_CACHE": "false",
            "REPRO_SWEEP_PIPELINE": pipeline}
     env.update({k: os.environ[k] for k in ("HOME", "TMPDIR")
                 if k in os.environ})

@@ -145,8 +145,8 @@ def _run_network_guard(mode: str) -> dict:
            # this guard measures in-process compile amortization (one
            # fused compile vs one per lattice width), so the persistent
            # XLA cache must not pre-warm either subprocess — a warm
-           # ~/.cache/repro/jax would erase exactly the gap under test
-           "REPRO_XLA_CACHE_DIR": "off"}
+           # compile cache would erase exactly the gap under test
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
     env.update({k: os.environ[k] for k in ("HOME", "TMPDIR")
                 if k in os.environ})
     res = subprocess.run(
