@@ -341,11 +341,10 @@ _T_BUCKET_FIRST = obs.timer("dse.bucket.first_call")
 _T_BUCKET_WARM = obs.timer("dse.bucket.warm")
 #: reduced-path telemetry: device→host volume actually realized by the
 #: pricing loop (the host path ships the full component grids, the
-#: reduced path only the per-segment winners), per-bucket device
-#: execute+transfer wall, and the pipeline's shape for the last sweep.
+#: reduced path only the per-segment winners) and the pipeline's shape
+#: for the last sweep.
 _C_TRANSFER = obs.counter("dse.transfer_bytes")
 _C_PIPE_BUCKETS = obs.counter("dse.pipeline.buckets")
-_T_BUCKET_EXECUTE = obs.timer("dse.bucket.execute")
 _G_PIPE_DEPTH = obs.gauge("dse.pipeline.depth")
 _G_PIPE_OCC = obs.gauge("dse.pipeline.occupancy")
 
@@ -639,23 +638,6 @@ def _grid_for(layer: Layer, designs: MacroBatch, scheds,
     return grid
 
 
-def _synced_lap(sp, results, label: str = "kernel"):
-    """Record a span lap only after the device work behind ``results``
-    has completed.
-
-    Cost results may be asynchronous jax arrays (the reduced path keeps
-    them on device), so a bare ``sp.lap`` would attribute still-running
-    device execution to whatever the span times next.  ``Span.wait``
-    walks ``results`` through ``block_until_ready`` before the lap; the
-    null span (tracing off) skips the sync entirely — it costs nothing,
-    and correctness never depends on it because consumers still block
-    at their ``np.asarray`` conversion.  Returns ``results``.
-    """
-    sp.wait(results)
-    sp.lap(label)
-    return results
-
-
 def _with_survivors(net, survivors: SurvivorMask | None):
     """AND a survivor mask's fault legality into one bucket's lattice.
 
@@ -698,7 +680,9 @@ def _price_buckets(buckets, designs: MacroBatch, objective: str,
     trace+compile time (or a persistent compile-cache deserialize; the
     span's ``persistent_cache`` attr records whether one was active to
     attribute suspiciously-fast first calls).  Warm buckets are pure
-    execute.  The split is what "compile vs execute" means per bucket.
+    execute: this host path realizes the kernel's outputs as NumPy
+    arrays inside the span, so its wall includes the device's time and
+    the copy.  The split is what "compile vs execute" means per bucket.
     """
     from .compilecache import persistent_cache_dir
     from .energy import grid_kernel_info
@@ -714,10 +698,6 @@ def _price_buckets(buckets, designs: MacroBatch, objective: str,
         with obs.span("dse.price_bucket", bucket=bi, lanes=len(net),
                       layers=len(net.layers), designs=net.n_designs) as sp:
             costs = evaluate_network_grid(net, designs, alpha=alpha)
-            # lap only once the kernel results are synced (this host
-            # path realizes NumPy arrays, so the wait is a no-op — but
-            # the contract is the walker, not the realization)
-            _synced_lap(sp, costs.macro_energy)
             new_shapes = (grid_kernel_info()["distinct_shapes"]
                           - shapes_before)
             timer = _T_BUCKET_FIRST if new_shapes else _T_BUCKET_WARM
@@ -818,7 +798,7 @@ def _price_shapes(shape_layers: Sequence[Layer], designs: MacroBatch,
 
 
 def _bucket_builder(shape_layers, designs, scheds, pad_q, out_q,
-                    stop: threading.Event):
+                    stop: threading.Event, parent_span: int):
     """Builder-thread body of the pipelined engine: greedily assemble
     lane buckets (same ``_BUCKET_ELEMS`` byte budget as the host path;
     shapes never split) and fuse each into one :class:`NetworkGrid`,
@@ -831,8 +811,13 @@ def _bucket_builder(shape_layers, designs, scheds, pad_q, out_q,
     alone exceeds the byte budget the *boundaries* between buckets may
     differ.  Results are bitwise identical either way — every shape
     segment is priced independently.
+
+    ``parent_span`` is the starter's open span (the sweep's root), which
+    this thread's spans name as their parent.
     """
     from .mapping import network_grid
+
+    obs.adopt_parent(parent_span)
 
     def put(item) -> bool:
         while not stop.is_set():
@@ -879,23 +864,35 @@ def _bucket_builder(shape_layers, designs, scheds, pad_q, out_q,
 
 
 def _finalize_bucket(entry, out) -> None:
-    """Sync one in-flight reduced bucket, realize its (S, D) winners on
-    the host and scatter them into the per-shape output table."""
+    """Realize one in-flight reduced bucket's (S, D) winners on the host
+    and scatter them into the per-shape output table."""
     members, net, red = entry
     with obs.span("dse.finalize_bucket", lanes=len(net),
                   layers=len(net.layers), designs=net.n_designs) as sp:
-        t0 = time.perf_counter()
-        _synced_lap(sp, (red.best_idx, red.total, red.cycles))
-        best = np.asarray(red.best_idx)
-        total = np.asarray(red.total)
-        cyc = np.asarray(red.cycles)
-        _T_BUCKET_EXECUTE.observe(time.perf_counter() - t0)
+        # the realizations block until the device has priced the bucket,
+        # so this span is the host's wait for the chip plus the copy
+        with obs.span("dse.device_wait"):
+            best = np.asarray(red.best_idx)
+            total = np.asarray(red.total)
+            cyc = np.asarray(red.cycles)
         _C_TRANSFER.inc(red.transfer_bytes)
         sp.set(transfer_bytes=red.transfer_bytes)
         for row, si in enumerate(members):
             out[si] = (net.grids[row], best[row], total[row], cyc[row])
     _C_LAT_LANES.inc(len(net))
     _C_LAT_PAD_LANES.inc(net.pad_lanes)
+
+
+def _next_bucket(out_q: queue.Queue, builder: threading.Thread) -> tuple:
+    """The builder's next queue item; raises if the builder thread died
+    without posting one."""
+    while True:
+        try:
+            return out_q.get(timeout=0.5)
+        except queue.Empty:
+            if not builder.is_alive():
+                raise RuntimeError(
+                    "sweep bucket builder died without a result")
 
 
 def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
@@ -916,12 +913,17 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
     winners (``best_idx`` / ``total`` / ``cycles``, 3·S·D values) ever
     cross the device→host boundary.
 
-    Telemetry mirrors the host path — one ``dse.price_bucket`` span and
-    one ``dse.bucket.first_call``/``warm`` observation per bucket, on
-    the dispatch wall (jit trace+compile is synchronous, so first-call
-    cost lands there) — plus ``dse.finalize_bucket`` spans with the
-    synced execute wall (``dse.bucket.execute``), ``dse.transfer_bytes``
-    and the ``dse.pipeline.*`` depth/occupancy gauges.
+    Telemetry: the main thread's time is split into consecutive spans —
+    ``dse.await_bucket`` (waiting on the builder's queue;
+    ``in_flight`` says how many buckets the device still has queued),
+    ``dse.price_bucket`` (host dispatch only: jit trace+compile is
+    synchronous, so first-call cost lands there, with one
+    ``dse.bucket.first_call``/``warm`` observation per bucket) and
+    ``dse.finalize_bucket`` holding ``dse.device_wait`` (the
+    realization of the winners: the wait for the device plus the copy).
+    The builder thread's spans name the caller's open span (the sweep's
+    root) as their parent.  Plus ``dse.transfer_bytes`` and the
+    ``dse.pipeline.*`` depth/occupancy gauges.
     """
     from .compilecache import persistent_cache_dir
     from .energy import grid_kernel_info
@@ -934,9 +936,8 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
     builder = threading.Thread(
         target=_bucket_builder,
         args=(shape_layers, designs, scheds, _bucket_pad_quantum(),
-              out_q, stop),
+              out_q, stop, obs.current_span_id()),
         name="repro-sweep-builder", daemon=True)
-    builder.start()
 
     pending: collections.deque = collections.deque()
     busy = 0.0
@@ -945,26 +946,26 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
     bi = 0
     try:
         while True:
-            try:
-                item = out_q.get(timeout=0.5)
-            except queue.Empty:
-                if builder.is_alive():
-                    continue
-                raise RuntimeError(
-                    "sweep bucket builder died without a result")
+            with obs.span("dse.await_bucket", in_flight=len(pending)):
+                if builder.ident is None:
+                    # started inside the first wait: the new thread takes
+                    # the interpreter lock, and getting it back is part
+                    # of waiting for the builder
+                    builder.start()
+                item = _next_bucket(out_q, builder)
             if item[0] == "error":
                 raise item[1]
             if item[0] == "done":
                 break
             _, members, net = item
-            net = _with_survivors(net, survivors)
-            shapes_before = grid_kernel_info()["distinct_shapes"]
-            t0 = time.perf_counter()
-            if busy_start is None:
-                busy_start = t0
             with obs.span("dse.price_bucket", bucket=bi, lanes=len(net),
                           layers=len(net.layers),
                           designs=net.n_designs, reduced=True) as sp:
+                net = _with_survivors(net, survivors)
+                shapes_before = grid_kernel_info()["distinct_shapes"]
+                t0 = time.perf_counter()
+                if busy_start is None:
+                    busy_start = t0
                 resident = np.asarray(
                     [_resident_bytes_cached(l) for l in net.layers],
                     dtype=np.int64)[net.lane_layer]
@@ -997,7 +998,8 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
             busy_start = None
     finally:
         stop.set()
-        builder.join(timeout=10.0)
+        if builder.ident is not None:
+            builder.join(timeout=10.0)
     wall = time.perf_counter() - t_loop
     _G_PIPE_OCC.set(busy / wall if wall > 0 else 0.0)
     return out
@@ -1069,8 +1071,9 @@ def _sweep_networks_traced(networks, designs, objective, alpha, mem,
                            survivors: SurvivorMask | None = None
                            ) -> tuple[SweepResult, ...]:
     """Body of :func:`sweep_networks`, under its root span — the span
-    covers lattice build, every bucket dispatch and result assembly, so
-    trace wall-time coverage of a sweep is the root span itself."""
+    covers lattice build, every bucket dispatch and result assembly
+    (``dse.assemble``), so trace wall-time coverage of a sweep is the
+    root span itself."""
     # persist XLA executables across processes (no-op after first call;
     # see core.compilecache)
     from .compilecache import enable_compilation_cache
@@ -1098,38 +1101,39 @@ def _sweep_networks_traced(networks, designs, objective, alpha, mem,
     priced = _price_shapes(shape_layers, designs, objective, alpha,
                            per_bit, buffer_bytes, dram, scheds,
                            survivors=survivors)
-    _C_LAT_SLOTS.inc(len(shape_layers))
-    _C_LAT_LAYERS.inc(sum(len(n[2]) for n in nets))
+    with obs.span("dse.assemble", networks=len(nets)):
+        _C_LAT_SLOTS.inc(len(shape_layers))
+        _C_LAT_LAYERS.inc(sum(len(n[2]) for n in nets))
 
-    area = designs.area_mm2()
-    results = []
-    for network, eligible, layer_shape in nets:
-        # per-network slot table in first-appearance order, so the
-        # stored shapes/_layer_shape match what sweep() alone builds
-        local: dict[int, int] = {}
-        shapes: list[tuple] = []
-        local_shape: list[int] = []
-        for layer, si in zip(eligible, layer_shape):
-            if si not in local:
-                local[si] = len(shapes)
-                grid, best_idx, total, cyc = priced[si]
-                shapes.append((layer, grid, best_idx, total, cyc))
-            local_shape.append(local[si])
-        # network totals, accumulated in layer order like NetworkResult
-        energy = np.zeros(n_designs, dtype=np.float64)
-        cycles = np.zeros(n_designs, dtype=np.int64)
-        for si in local_shape:
-            energy = energy + shapes[si][3]
-            cycles = cycles + shapes[si][4]
-        results.append(SweepResult(
-            network=network, objective=objective, designs=designs,
-            energy_fj=energy, cycles=cycles, area_mm2=area,
-            layer_names=tuple(l.name for l in eligible),
-            schedules=_schedule_names(scheds),
-            survivors=survivors,
-            _shapes=tuple((s[0], s[1], s[2]) for s in shapes),
-            _layer_shape=tuple(local_shape), _alpha=alpha, _mem=mem))
-    return tuple(results)
+        area = designs.area_mm2()
+        results = []
+        for network, eligible, layer_shape in nets:
+            # per-network slot table in first-appearance order, so the
+            # stored shapes/_layer_shape match what sweep() alone builds
+            local: dict[int, int] = {}
+            shapes: list[tuple] = []
+            local_shape: list[int] = []
+            for layer, si in zip(eligible, layer_shape):
+                if si not in local:
+                    local[si] = len(shapes)
+                    grid, best_idx, total, cyc = priced[si]
+                    shapes.append((layer, grid, best_idx, total, cyc))
+                local_shape.append(local[si])
+            # network totals, accumulated in layer order like NetworkResult
+            energy = np.zeros(n_designs, dtype=np.float64)
+            cycles = np.zeros(n_designs, dtype=np.int64)
+            for si in local_shape:
+                energy = energy + shapes[si][3]
+                cycles = cycles + shapes[si][4]
+            results.append(SweepResult(
+                network=network, objective=objective, designs=designs,
+                energy_fj=energy, cycles=cycles, area_mm2=area,
+                layer_names=tuple(l.name for l in eligible),
+                schedules=_schedule_names(scheds),
+                survivors=survivors,
+                _shapes=tuple((s[0], s[1], s[2]) for s in shapes),
+                _layer_shape=tuple(local_shape), _alpha=alpha, _mem=mem))
+        return tuple(results)
 
 
 def sweep(network: str, layers: Sequence[Layer], designs: MacroBatch,
@@ -1278,38 +1282,39 @@ def sweep_serving(points: Sequence[ServingPoint], designs: MacroBatch,
         sweeps = sweep_networks(nets, designs, objective=objective,
                                 alpha=alpha, mem=mem, schedules=schedules,
                                 faults=faults)
-        per_bit, _, _ = _mem_pricing(designs, mem)
-        f_clk = _f_clk_ghz(designs)
-        n_designs = len(designs)
+        with obs.span("dse.assemble", points=len(points)):
+            per_bit, _, _ = _mem_pricing(designs, mem)
+            f_clk = _f_clk_ghz(designs)
+            n_designs = len(designs)
 
-        results = []
-        it = iter(sweeps)
-        for pt in points:
-            if pt.tokens_out <= 0:
-                raise ValueError(f"{pt.name}: no generated tokens "
-                                 f"(gen_len must be >= 1)")
-            with obs.span("dse.serving_point", point=pt.name,
-                          phases=len(pt.phases)):
-                phase_sweeps = tuple(next(it) for _ in pt.phases)
-                energy = np.zeros(n_designs, dtype=np.float64)
-                kv = np.zeros(n_designs, dtype=np.float64)
-                cycles = np.zeros(n_designs, dtype=np.float64)
-                for ph, sw in zip(pt.phases, phase_sweeps):
-                    energy = energy + sw.energy_fj * ph.repeats
-                    cycles = (cycles
-                              + sw.cycles.astype(np.float64) * ph.repeats)
-                    kv = kv + kv_traffic_energy_grid(
-                        per_bit, ph.kv_read_bytes, ph.kv_write_bytes,
-                        ph.kv_live_bytes, kv_hier)
-                total = energy + kv
-                time_s = cycles / (f_clk * 1e9)
-                results.append(ServingPointResult(
-                    point=pt, objective=objective, designs=designs,
-                    phase_sweeps=phase_sweeps,
-                    energy_fj=energy, kv_energy_fj=kv, cycles=cycles,
-                    tokens_per_s=pt.tokens_out / time_s,
-                    j_per_token=(total * 1e-15) / pt.tokens_out))
-        return tuple(results)
+            results = []
+            it = iter(sweeps)
+            for pt in points:
+                if pt.tokens_out <= 0:
+                    raise ValueError(f"{pt.name}: no generated tokens "
+                                     f"(gen_len must be >= 1)")
+                with obs.span("dse.serving_point", point=pt.name,
+                              phases=len(pt.phases)):
+                    phase_sweeps = tuple(next(it) for _ in pt.phases)
+                    energy = np.zeros(n_designs, dtype=np.float64)
+                    kv = np.zeros(n_designs, dtype=np.float64)
+                    cycles = np.zeros(n_designs, dtype=np.float64)
+                    for ph, sw in zip(pt.phases, phase_sweeps):
+                        energy = energy + sw.energy_fj * ph.repeats
+                        cycles = (cycles + sw.cycles.astype(np.float64)
+                                  * ph.repeats)
+                        kv = kv + kv_traffic_energy_grid(
+                            per_bit, ph.kv_read_bytes, ph.kv_write_bytes,
+                            ph.kv_live_bytes, kv_hier)
+                    total = energy + kv
+                    time_s = cycles / (f_clk * 1e9)
+                    results.append(ServingPointResult(
+                        point=pt, objective=objective, designs=designs,
+                        phase_sweeps=phase_sweeps,
+                        energy_fj=energy, kv_energy_fj=kv, cycles=cycles,
+                        tokens_per_s=pt.tokens_out / time_s,
+                        j_per_token=(total * 1e-15) / pt.tokens_out))
+            return tuple(results)
 
 
 def serving_point_scalar(pt: ServingPoint, macro: IMCMacro,

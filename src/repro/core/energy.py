@@ -711,27 +711,24 @@ def _dispatch_grid_kernel(designs, n_inputs, rows_used, cols_used,
 
     cst = _design_constants(designs)
     col = lambda a: a[:, None]                     # (D,) -> (D, 1)
-    with obs.span("energy.grid_kernel", lanes=int(n_inputs.shape[-1]),
-                  designs=len(designs.rows), sharded=sharded,
-                  realized=realize):
-        with jax.enable_x64(True):
-            parts = kern(
-                col(cst["analog"]), col(cst["mmux1"]), col(cst["rows"]),
-                col(cst["d1"]), col(cst["bw"]), col(cst["m"]),
-                col(cst["cc_bs"]), col(cst["e_wl_line"]),
-                col(cst["e_bl_word"]), col(cst["p_logic"]),
-                col(cst["adc_e"]), col(cst["denom_adc"]),
-                col(cst["cols_per_adc"]), col(cst["f_tree_a"]),
-                col(cst["f_tree_d"]), col(cst["p_tree"]),
-                col(cst["denom_occ"]), col(cst["dac_e"]), col(cst["p_write"]),
-                tile(n_inputs), tile(rows_used), tile(cols_used),
-                tile(weight_loads), tile(sched_os), alpha)
-            if realize:
-                # np.asarray forces execution, so the span's wall covers
-                # dispatch through device completion (compile included
-                # on a fresh shape).
-                parts = tuple(np.asarray(p, dtype=np.float64)
-                              for p in parts)
+    with jax.enable_x64(True):
+        parts = kern(
+            col(cst["analog"]), col(cst["mmux1"]), col(cst["rows"]),
+            col(cst["d1"]), col(cst["bw"]), col(cst["m"]),
+            col(cst["cc_bs"]), col(cst["e_wl_line"]),
+            col(cst["e_bl_word"]), col(cst["p_logic"]),
+            col(cst["adc_e"]), col(cst["denom_adc"]),
+            col(cst["cols_per_adc"]), col(cst["f_tree_a"]),
+            col(cst["f_tree_d"]), col(cst["p_tree"]),
+            col(cst["denom_occ"]), col(cst["dac_e"]), col(cst["p_write"]),
+            tile(n_inputs), tile(rows_used), tile(cols_used),
+            tile(weight_loads), tile(sched_os), alpha)
+        if realize:
+            # np.asarray forces execution, so the caller's span covers
+            # dispatch through device completion (compile included
+            # on a fresh shape).
+            parts = tuple(np.asarray(p, dtype=np.float64)
+                          for p in parts)
     return parts, sharded
 
 
@@ -1058,17 +1055,14 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
         (e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write, _macs,
          x_adc, x_dac) = parts
         terms_k = _reduce_terms_kernel(has_os)
-        with obs.span("energy.reduce_kernel", lanes=int(lanes),
-                      designs=int(n_designs), segments=len(seg_bounds),
-                      objective=objective, fused_terms=False):
-            with jax.enable_x64(True):
-                terms = terms_k(e_wl, e_bl, e_logic, e_adc, e_tree,
-                                e_dac, e_write, x_adc, x_dac,
-                                active_macros, weight_tiles, weight_bits,
-                                input_bits, output_bits, psum_bits,
-                                per_bit, per_bit_spill, off_chip)
-                return argmin_k(*terms, wt_ipt, cc_per_input,
-                                write_cycles, legal, seg_ids, seg_starts)
+        with jax.enable_x64(True):
+            terms = terms_k(e_wl, e_bl, e_logic, e_adc, e_tree,
+                            e_dac, e_write, x_adc, x_dac,
+                            active_macros, weight_tiles, weight_bits,
+                            input_bits, output_bits, psum_bits,
+                            per_bit, per_bit_spill, off_chip)
+            return argmin_k(*terms, wt_ipt, cc_per_input,
+                            write_cycles, legal, seg_ids, seg_starts)
 
     _C_KERNEL_CALLS.inc()
     _GRID_KERNEL_SHAPES.add((n_inputs.shape, n_designs))
@@ -1076,30 +1070,23 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
     fused_k = _reduced_fused_kernel(has_os)
     cst = _design_constants(designs)
     col = lambda a: a[:, None]                     # (D,) -> (D, 1)
-    with obs.span("energy.grid_kernel", lanes=int(lanes),
-                  designs=int(n_designs), sharded=False, realized=False,
-                  fused_terms=True):
-        with jax.enable_x64(True):
-            terms = fused_k(
-                col(cst["analog"]), col(cst["mmux1"]), col(cst["rows"]),
-                col(cst["d1"]), col(cst["bw"]), col(cst["m"]),
-                col(cst["cc_bs"]), col(cst["e_wl_line"]),
-                col(cst["e_bl_word"]), col(cst["p_logic"]),
-                col(cst["adc_e"]), col(cst["denom_adc"]),
-                col(cst["cols_per_adc"]), col(cst["f_tree_a"]),
-                col(cst["f_tree_d"]), col(cst["p_tree"]),
-                col(cst["denom_occ"]), col(cst["dac_e"]),
-                col(cst["p_write"]),
-                n_inputs, rows_used, cols_used, weight_loads, sched_os,
-                alpha, active_macros, weight_tiles, weight_bits,
-                input_bits, output_bits, psum_bits,
-                per_bit, per_bit_spill, off_chip)
-    with obs.span("energy.reduce_kernel", lanes=int(lanes),
-                  designs=int(n_designs), segments=len(seg_bounds),
-                  objective=objective, fused_terms=True):
-        with jax.enable_x64(True):
-            return argmin_k(*terms, wt_ipt, cc_per_input, write_cycles,
-                            legal, seg_ids, seg_starts)
+    with jax.enable_x64(True):
+        terms = fused_k(
+            col(cst["analog"]), col(cst["mmux1"]), col(cst["rows"]),
+            col(cst["d1"]), col(cst["bw"]), col(cst["m"]),
+            col(cst["cc_bs"]), col(cst["e_wl_line"]),
+            col(cst["e_bl_word"]), col(cst["p_logic"]),
+            col(cst["adc_e"]), col(cst["denom_adc"]),
+            col(cst["cols_per_adc"]), col(cst["f_tree_a"]),
+            col(cst["f_tree_d"]), col(cst["p_tree"]),
+            col(cst["denom_occ"]), col(cst["dac_e"]),
+            col(cst["p_write"]),
+            n_inputs, rows_used, cols_used, weight_loads, sched_os,
+            alpha, active_macros, weight_tiles, weight_bits,
+            input_bits, output_bits, psum_bits,
+            per_bit, per_bit_spill, off_chip)
+        return argmin_k(*terms, wt_ipt, cc_per_input, write_cycles,
+                        legal, seg_ids, seg_starts)
 
 
 def _design_constants(designs) -> dict[str, np.ndarray]:

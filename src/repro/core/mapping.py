@@ -726,12 +726,7 @@ def candidate_grid(layer: Layer, designs,
     oracle.
     """
     _C_BUILDS.inc()
-    with obs.span("mapping.candidate_grid", layer=layer.name,
-                  designs=len(designs.rows)) as sp:
-        grid = _candidate_grid_impl(layer, designs, max_candidates,
-                                    schedules)
-        sp.set(candidates=len(grid))
-    return grid
+    return _candidate_grid_impl(layer, designs, max_candidates, schedules)
 
 
 _C_BUILDS = obs.counter("mapping.lattice.builds")
@@ -1020,14 +1015,8 @@ def network_grid(layers: Sequence[Layer], designs,
     ``pad_quantum - 1`` filler lanes per bucket), so fusing never
     explodes the lattice the way a rectangular (L, C_max) pad would.
     """
-    with obs.span("mapping.network_grid", layers=len(layers),
-                  designs=len(designs.rows),
-                  prebuilt=grids is not None) as sp:
-        out = _network_grid_impl(layers, designs, schedules,
-                                 max_candidates, grids, pad_quantum,
-                                 max_lanes)
-        sp.set(buckets=len(out), lanes=sum(len(n) for n in out))
-    return out
+    return _network_grid_impl(layers, designs, schedules, max_candidates,
+                              grids, pad_quantum, max_lanes)
 
 
 def _network_grid_impl(layers, designs, schedules, max_candidates,

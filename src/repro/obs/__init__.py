@@ -1,7 +1,8 @@
 """Unified telemetry layer: metrics registry + span tracer + exporters.
 
-Zero-dependency (stdlib-only) observability substrate for the fused
-sweep engine.  Three pieces:
+Observability substrate for the fused sweep engine, importable with the
+standard library alone (jax is imported lazily, by the first span opened
+with tracing on).  Three pieces:
 
 * :mod:`repro.obs.registry` — process-global counters / gauges /
   timers, labeled by subsystem via dotted names, with atomic
@@ -10,9 +11,12 @@ sweep engine.  Three pieces:
   are compatibility views over this registry.
 * :mod:`repro.obs.tracing` — nestable, thread-safe wall-time spans
   over the hot path (lattice build, per-bucket jit dispatch with
-  compile-vs-execute attribution, fidelity groups, serving phases,
-  serve-loop steps).  Off by default; the ``REPRO_TRACE`` env knob
-  (or :func:`set_trace_enabled`) turns recording on.  Tracing is inert
+  compile-vs-execute attribution, the wait for the device, result
+  assembly, fidelity groups, serving phases, serve-loop steps).  Off by
+  default; the ``REPRO_TRACE`` env knob (or :func:`set_trace_enabled`)
+  turns recording on.  While on, each span is also a
+  ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+  trace shows the spans on the device trace's clock.  Tracing is inert
   by contract: outputs are bitwise identical with tracing on or off
   (``tests/obs/test_inert.py``).
 * :mod:`repro.obs.export` — JSONL + Chrome trace-event writers through
@@ -29,7 +33,7 @@ Typical instrumentation::
 
     def build(...):
         _BUILDS.inc()
-        with obs.span("mapping.candidate_grid", layer=layer.name) as sp:
+        with obs.span("dse.lattice_build", layer=layer.name) as sp:
             grid = ...
             sp.set(lanes=len(grid))
         return grid
@@ -47,8 +51,9 @@ from .export import (export_all, export_chrome, export_jsonl,
                      write_text_atomic)
 from .registry import (REGISTRY, Counter, Gauge, MetricsRegistry, Timer,
                        counter, gauge, reset, snapshot, timer)
-from .tracing import (Span, drain_spans, iter_spans, set_trace_enabled,
-                      span, span_summary, sync, trace_enabled, traced)
+from .tracing import (Span, adopt_parent, current_span_id, drain_spans,
+                      iter_spans, set_trace_enabled, span, span_summary,
+                      sync, trace_enabled, traced)
 
 __all__ = [
     # registry
@@ -56,7 +61,8 @@ __all__ = [
     "counter", "gauge", "timer", "snapshot", "reset",
     # tracing
     "Span", "span", "traced", "trace_enabled", "set_trace_enabled",
-    "drain_spans", "iter_spans", "span_summary", "sync",
+    "current_span_id", "adopt_parent", "drain_spans", "iter_spans",
+    "span_summary", "sync",
     # export
     "export_all", "export_chrome", "export_jsonl", "telemetry_block",
     "write_json_atomic", "write_text_atomic",

@@ -4,23 +4,33 @@
 records one timed interval into the process-global trace buffer.  Spans
 nest through a per-thread stack (the ``parent``/``depth`` fields make
 the nesting explicit for the validator and the JSONL export; Chrome's
-trace viewer infers it from interval containment per thread).
+trace viewer infers it from interval containment per thread).  A worker
+thread started on behalf of an open span passes that span's id
+(:func:`current_span_id`) to :func:`adopt_parent`, so the spans it opens
+name the span that caused them as their parent.
 
 Off by default: tracing is enabled by the ``REPRO_TRACE`` env knob
 (``""``/``"0"``/``"off"``/``"false"``/``"none"``/``"disabled"`` mean
 off, anything else on), resolved once and overridable in-process via
 :func:`set_trace_enabled`.  When disabled, :func:`span` returns a
-shared no-op context manager without allocating — the per-call cost is
-one dict build for the kwargs plus one flag check, which is what keeps
-the instrumented sweep within the 2 % overhead guard
-(``tests/perf/test_obs_overhead.py``).
+shared no-op context manager without allocating or touching jax — the
+per-call cost is one dict build for the kwargs plus one flag check,
+which is what keeps the instrumented sweep within the 2 % overhead
+guard (``tests/perf/test_obs_overhead.py``).
 
-Device-time attribution: jax dispatch is asynchronous, so a span that
-closes right after a jit call would bank only the dispatch and leak the
-execution into whichever span runs next.  ``Span.wait(x)`` blocks on
-every jax array reachable from ``x`` (the same walker
-``benchmarks.common.sync`` re-exports) *before* the span's clock stops,
-so device time lands in the span that caused it.
+One clock with the device: while tracing is on, every span also opens a
+``jax.profiler.TraceAnnotation`` under its bare name, so a profiler
+trace taken meanwhile holds the program's spans on its host plane (one
+line per thread) beside the device's operations.  Attributes stay in
+the span record only (JAX would fold them into the event name).
+``jax.profiler`` is imported on the first traced span, never at import
+time: this module needs nothing beyond the standard library, and
+without jax a span records as usual.
+
+A span times host wall clock only.  jax dispatch is asynchronous, so a
+span around a jit call measures the enqueue; the device's time shows
+in the span that realizes the result on the host (``np.asarray``), or
+in the profiler trace itself.
 
 The buffer is bounded (``_MAX_SPANS``); overflow increments the
 ``obs.spans.dropped`` counter instead of growing without limit.
@@ -43,13 +53,17 @@ from .registry import counter as _counter
 
 __all__ = [
     "span", "traced", "Span", "trace_enabled", "set_trace_enabled",
-    "drain_spans", "iter_spans", "span_summary", "sync",
+    "current_span_id", "adopt_parent", "drain_spans", "iter_spans",
+    "span_summary", "sync",
 ]
 
 _DISABLED_VALUES = {"", "0", "off", "false", "none", "disabled"}
 
 #: tri-state: None = resolve from env on next check
 _STATE: dict = {"enabled": None}
+#: ``jax.profiler.TraceAnnotation`` once the first traced span looked it
+#: up (``None`` without jax); absent until then
+_ANNOTATION: dict = {}
 
 _MAX_SPANS = 200_000
 
@@ -85,8 +99,7 @@ def sync(x):
     result under-reports wall time by whatever is still in flight.
     Walks containers and dataclasses; NumPy arrays and scalars pass
     through untouched.  Returns ``x`` so it can wrap a call expression
-    inline.  (This is the canonical walker — ``benchmarks.common.sync``
-    re-exports it.)
+    inline.  (``benchmarks.common.sync`` re-exports it.)
     """
     seen: set[int] = set()
 
@@ -111,10 +124,33 @@ def sync(x):
     return x
 
 
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use; ``None``
+    where jax cannot be imported."""
+    if "cls" not in _ANNOTATION:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = None
+        _ANNOTATION["cls"] = TraceAnnotation
+    return _ANNOTATION["cls"]
+
+
+class _Parent:
+    """Base of a thread's span stack standing for a span another thread
+    holds open (:func:`adopt_parent`)."""
+
+    __slots__ = ("id",)
+
+    def __init__(self, span_id: int):
+        self.id = span_id
+
+
 class Span:
     """One live span.  Use via ``with span(name, **attrs) as sp:``."""
 
-    __slots__ = ("name", "attrs", "id", "parent", "depth", "tid", "t0")
+    __slots__ = ("name", "attrs", "id", "parent", "depth", "tid", "t0",
+                 "annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -126,17 +162,11 @@ class Span:
 
     def lap(self, label: str) -> float:
         """Record the elapsed time since span start as attribute
-        ``<label>_s`` and return it (e.g. ``sp.lap("dispatch")`` right
-        after a jit call splits dispatch from the post-``wait``
-        remainder)."""
+        ``<label>_s`` and return it (e.g. ``sp.lap("restore")`` after
+        one phase of a multi-phase span)."""
         dt = (time.perf_counter_ns() - self.t0) / 1e9
         self.attrs[label + "_s"] = dt
         return dt
-
-    def wait(self, x):
-        """:func:`sync` ``x`` so its device time is charged to this
-        span, then return it."""
-        return sync(x)
 
     def __enter__(self) -> "Span":
         stack = getattr(_TLS, "stack", None)
@@ -147,11 +177,17 @@ class Span:
         self.depth = len(stack)
         self.tid = threading.get_ident()
         stack.append(self)
+        cls = _trace_annotation()
+        self.annotation = None if cls is None else cls(self.name)
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter_ns()
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
         stack = getattr(_TLS, "stack", [])
         # tolerate exception-path teardown out of order
         if stack and stack[-1] is self:
@@ -192,9 +228,6 @@ class _NullSpan:
     def lap(self, label: str) -> float:
         return 0.0
 
-    def wait(self, x):
-        return x
-
     def __enter__(self) -> "_NullSpan":
         return self
 
@@ -211,6 +244,23 @@ def span(name: str, **attrs) -> Span | _NullSpan:
     if not trace_enabled():
         return _NULL
     return Span(name, attrs)
+
+
+def current_span_id() -> int:
+    """Id of the innermost span open on this thread; 0 when none is
+    (always, with tracing off)."""
+    stack = getattr(_TLS, "stack", None)
+    return stack[-1].id if stack else 0
+
+
+def adopt_parent(span_id: int) -> None:
+    """Make the span ``span_id``, held open by another thread, the base
+    of this thread's span stack: the spans this thread opens next name
+    it as their parent, one level below it.  Call at the start of a
+    worker thread with the id :func:`current_span_id` gave its starter;
+    0 (no span open, or tracing off) leaves the stack as it is."""
+    if span_id:
+        _TLS.stack = [_Parent(span_id)]
 
 
 def traced(name: str | None = None):
