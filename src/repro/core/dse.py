@@ -308,10 +308,10 @@ _CACHE_MAX = 4096
 #: per-shape union-lattice memo: (shape, designs signature, schedules,
 #: max_candidates) -> mapping.MappingGrid.  Repeated sweeps over the
 #: same design grid (the warm path of the fused engine) skip lattice
-#: construction entirely.  Bounded LRU: grids carry (D, C) legality
-#: masks (MBs at D >= 1000), so beyond ``_LATTICE_CACHE_MAX`` entries
-#: the least-recently-used are evicted — a long-lived process refining
-#: many different design grids stays flat.
+#: construction entirely.  Bounded LRU: grids carry (C,) candidate
+#: columns and per-class legality rows, so beyond ``_LATTICE_CACHE_MAX``
+#: entries the least-recently-used are evicted — a long-lived process
+#: refining many different design grids stays flat.
 _LATTICE_CACHE: "collections.OrderedDict[tuple, object]" = \
     collections.OrderedDict()
 _LATTICE_CACHE_MAX = 512
@@ -344,6 +344,9 @@ _T_BUCKET_WARM = obs.timer("dse.bucket.warm")
 #: reduced path only the per-segment winners) and the pipeline's shape
 #: for the last sweep.
 _C_TRANSFER = obs.counter("dse.transfer_bytes")
+#: legality bytes handed to the device by the reduced engine: per bucket
+#: the class rows plus each design's class index
+_C_LEGAL_BYTES = obs.counter("dse.legal_bytes")
 _C_PIPE_BUCKETS = obs.counter("dse.pipeline.buckets")
 _G_PIPE_DEPTH = obs.gauge("dse.pipeline.depth")
 _G_PIPE_OCC = obs.gauge("dse.pipeline.occupancy")
@@ -642,20 +645,23 @@ def _with_survivors(net, survivors: SurvivorMask | None):
     """AND a survivor mask's fault legality into one bucket's lattice.
 
     ``None`` returns ``net`` unchanged (the inertness contract: faults
-    off is the identical object, not an equal one).  Otherwise the
-    bucket is re-wrapped with ``legal &= fault_legal(...)`` — grids in
-    ``_LATTICE_CACHE`` stay fault-free (masks are per-sweep, caches are
-    per-shape) and every downstream path (host ``np.where`` sentinels,
-    reduced ``reduce_objective_grid(legal=...)``, sharded lanes) sees
-    the degraded legality through the one field they already consume.
-    The all-ones mapping survives any clamp-to->=1 mask, so every
-    (layer, design) segment keeps >= 1 legal lane and sentinels still
-    never win the argmin.
+    off is the identical object, not an equal one).  Otherwise survivors
+    differ per design, so the bucket is re-wrapped with one legality
+    class per design: ``legal_rows = legal & fault_legal(...)`` (D,
+    Ctot) and ``design_class = arange(D)``.  Grids in ``_LATTICE_CACHE``
+    stay fault-free (masks are per-sweep, caches are per-shape) and
+    every downstream path (host ``np.where`` sentinels, the reduced
+    kernel's class gather, sharded lanes) sees the degraded legality
+    through the fields they already consume.  The all-ones mapping
+    survives any clamp-to->=1 mask, so every (layer, design) segment
+    keeps >= 1 legal lane and sentinels still never win the argmin.
     """
     if survivors is None:
         return net
+    legal = net.legal & fault_legal(survivors, net.cand)
     return dataclasses.replace(
-        net, legal=net.legal & fault_legal(survivors, net.cand))
+        net, legal_rows=legal,
+        design_class=np.arange(len(legal), dtype=np.int32))
 
 
 def _price_buckets(buckets, designs: MacroBatch, objective: str,
@@ -918,11 +924,13 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
     ``in_flight`` says how many buckets the device still has queued),
     ``dse.price_bucket`` (host dispatch only: jit trace+compile is
     synchronous, so first-call cost lands there, with one
-    ``dse.bucket.first_call``/``warm`` observation per bucket) and
+    ``dse.bucket.first_call``/``warm`` observation per bucket; attr
+    ``legal_rows`` is the bucket's legality class count) and
     ``dse.finalize_bucket`` holding ``dse.device_wait`` (the
     realization of the winners: the wait for the device plus the copy).
     The builder thread's spans name the caller's open span (the sweep's
-    root) as their parent.  Plus ``dse.transfer_bytes`` and the
+    root) as their parent.  Plus ``dse.transfer_bytes``,
+    ``dse.legal_bytes`` (legality handed to the device) and the
     ``dse.pipeline.*`` depth/occupancy gauges.
     """
     from .compilecache import persistent_cache_dir
@@ -975,6 +983,8 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
                     resident_bytes=resident, buffer_bytes=buffer_bytes,
                     dram_fj_per_bit=dram)
                 sp.lap("dispatch")
+                _C_LEGAL_BYTES.inc(net.legal_rows.nbytes
+                                   + net.design_class.nbytes)
                 new_shapes = (grid_kernel_info()["distinct_shapes"]
                               - shapes_before)
                 timer = _T_BUCKET_FIRST if new_shapes else _T_BUCKET_WARM
@@ -982,7 +992,8 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
                 sp.set(new_kernel_shapes=new_shapes,
                        first_call=bool(new_shapes),
                        persistent_cache=persistent_cache_dir()
-                       is not None)
+                       is not None,
+                       legal_rows=len(net.legal_rows))
             pending.append((members, net, red))
             bi += 1
             _C_PIPE_BUCKETS.inc()

@@ -939,7 +939,10 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
     / ``((w+i)+o)+p`` association ``dse._price_buckets`` runs in NumPy
     — have no producer multiply for LLVM to contract with.  Cycles are
     int64 (exact on device); the objective column replaces illegal and
-    padded lanes with the finite sentinels.
+    padded lanes with the finite sentinels.  Legality arrives per class
+    — ``legal_rows`` (U, Ctot) and each design's row ``design_class``
+    (D,) — and is gathered to (D, Ctot) here on the device, so no
+    per-design mask crosses from the host.
 
     The per-segment argmin runs as two ``segment_min`` passes over the
     lane axis instead of one ``jnp.argmin`` per static segment slice —
@@ -964,7 +967,9 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
 
         def kernel(s_wl, s_bl, s_logic, s_adc, s_tree, s_dac, s_write,
                    m_w, m_i, m_o, m_p, wt_ipt, cc_per_input,
-                   write_cycles, legal, seg_ids, seg_starts):
+                   write_cycles, legal_rows, design_class, seg_ids,
+                   seg_starts):
+            legal = legal_rows[design_class]
             total = s_wl + s_bl
             total = total + s_logic
             total = total + (s_adc + s_tree)
@@ -1006,12 +1011,15 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
                           wt_ipt, write_cycles, cc_per_input,
                           weight_bits, input_bits, output_bits,
                           psum_bits, per_bit, per_bit_spill, off_chip,
-                          legal):
+                          legal_rows, design_class):
     """The reduced sweep's whole device chain: stage-1 grid kernel +
     fold + scale + traffic + sentinel-masked per-segment argmin,
     returning ``(best_idx, total, cycles)`` as (S, D) jax arrays — S
     segment rows of (D,) winners, the only data that ever reaches the
     host.
+
+    Legality is ``mapping.NetworkGrid``'s compact pair: ``legal_rows``
+    (U, Ctot), one row per legality class, and ``design_class`` (D,).
 
     Unsharded (the default), stage-1 and the term products run as ONE
     fused executable (:func:`_reduced_fused_kernel` — no ten-grid
@@ -1034,9 +1042,11 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
     (n_inputs, rows_used, cols_used, weight_loads,
      sched_os) = _coerce_tile_args(n_inputs, rows_used, cols_used,
                                    weight_loads, schedule_os)
-    n_designs, lanes = legal.shape
+    n_classes, lanes = legal_rows.shape
+    n_designs = len(design_class)
     _GRID_KERNEL_SHAPES.add(
-        ((lanes,), n_designs, "reduce", objective, len(seg_bounds), has_os))
+        ((lanes,), n_designs, "reduce", objective, len(seg_bounds), has_os,
+         n_classes))
     _G_KERNEL_SHAPES.set(len(_GRID_KERNEL_SHAPES))
     argmin_k = _reduce_argmin_kernel(objective, len(seg_bounds))
     # lane -> segment id, pads (the tail past the last bound) mapped to
@@ -1062,7 +1072,8 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
                             input_bits, output_bits, psum_bits,
                             per_bit, per_bit_spill, off_chip)
             return argmin_k(*terms, wt_ipt, cc_per_input,
-                            write_cycles, legal, seg_ids, seg_starts)
+                            write_cycles, legal_rows, design_class,
+                            seg_ids, seg_starts)
 
     _C_KERNEL_CALLS.inc()
     _GRID_KERNEL_SHAPES.add((n_inputs.shape, n_designs))
@@ -1086,7 +1097,7 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
             input_bits, output_bits, psum_bits,
             per_bit, per_bit_spill, off_chip)
         return argmin_k(*terms, wt_ipt, cc_per_input, write_cycles,
-                        legal, seg_ids, seg_starts)
+                        legal_rows, design_class, seg_ids, seg_starts)
 
 
 def _design_constants(designs) -> dict[str, np.ndarray]:

@@ -41,6 +41,7 @@ enumeration order in both paths).  Enforced by
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Iterator, Mapping, Sequence
 
@@ -454,20 +455,32 @@ class MappingGrid:
     Different designs have different legal-mapping lattices (the unroll
     caps depend on ``d1`` / ``rows`` / ``n_macros``), so the grid holds
     the *union* lattice as one flat :class:`MappingBatch` of C
-    candidates plus a (D, C) ``legal`` mask.  The union is ordered
+    candidates plus per-design legality.  The union is ordered
     exactly like ``enumerate_mappings`` orders candidates (k_col outer,
     row triple middle, macro option inner, each axis ascending), and a
     design's legal subsequence *is* its own enumeration order — so a
     masked argmin over the candidate axis tie-breaks identically to the
     scalar oracle's first-wins loop, per design.
+
+    Legality only depends on a design's ``(d1, rows, n_macros)``, so it
+    is held per legality *class*: ``legal_rows`` has one (C,) row per
+    class and ``design_class`` names each design's row.  ``legal`` is
+    the (D, C) mask ``legal_rows[design_class]``, expanded on first
+    read.
     """
 
     cand: MappingBatch        # union lattice, flat candidate axis (C,)
-    legal: np.ndarray         # (D, C) bool: candidate j legal on design i
+    legal_rows: np.ndarray    # (U, C) bool: candidate j legal in class u
+    design_class: np.ndarray  # (D,) int32: legality class of design i
 
     @property
     def n_designs(self) -> int:
-        return self.legal.shape[0]
+        return len(self.design_class)
+
+    @functools.cached_property
+    def legal(self) -> np.ndarray:
+        """(D, C) bool: candidate j legal on design i."""
+        return self.legal_rows[self.design_class]
 
     def __len__(self) -> int:
         return len(self.cand)
@@ -476,8 +489,9 @@ class MappingGrid:
         """Design ``d``'s legal candidates, in its enumeration order.
         With multiple schedules enabled each spatial mapping appears
         once per schedule (legality is schedule-independent)."""
+        row = self.legal_rows[self.design_class[d]]
         return tuple(self.cand.mapping_at(int(j))
-                     for j in np.flatnonzero(self.legal[d]))
+                     for j in np.flatnonzero(row))
 
 
 def _pow2_member(u: np.ndarray, dim: int | np.ndarray,
@@ -598,7 +612,8 @@ def candidate_grid_loop(layer: Layer, designs,
     cand = _with_schedule_axis(cand, scheds)
     if len(cand) != legal.shape[1]:
         legal = np.repeat(legal, len(scheds), axis=1)
-    return MappingGrid(cand=cand, legal=legal)
+    return MappingGrid(cand=cand, legal_rows=legal,
+                       design_class=np.arange(len(legal), dtype=np.int32))
 
 
 def _assemble_grid(layer: Layer, designs, scheds, max_candidates: int,
@@ -606,9 +621,9 @@ def _assemble_grid(layer: Layer, designs, scheds, max_candidates: int,
                    fy_un: np.ndarray, mac_dim: np.ndarray,
                    mac_un: np.ndarray) -> MappingGrid:
     """Shared tail of the loop/vectorized lattice builders: derived
-    candidate columns, per-design legality (computed once per *distinct*
-    legality-relevant design triple, then gathered), ``max_candidates``
-    truncation, and the schedule crossing."""
+    candidate columns, legality (computed and kept once per *distinct*
+    legality-relevant design triple), ``max_candidates`` truncation, and
+    the schedule crossing."""
     k = layer.dim("K")
     spatial_total = math.prod(layer.dim(d) for d in MACRO_DUP_DIMS)
     is_k = mac_dim == _MAC_K
@@ -630,10 +645,10 @@ def _assemble_grid(layer: Layer, designs, scheds, max_candidates: int,
         dup_macros=np.where(is_dup, mac_un, 1),
         n_spatial_temporal=nst)
 
-    # --- per-design legality: membership of every component ------------------
+    # --- per-class legality: membership of every component -------------------
     # Legality only sees (d1, rows, n_macros); compute the mask on the
-    # distinct triples (U rows, typically 10-50x fewer than D designs)
-    # and gather — boolean rows, so the gather is exactly identity.
+    # distinct triples (U rows, typically 10-100x fewer than D designs)
+    # and keep it there: ``inv`` names each design's row.
     d1_a = np.asarray(designs.d1, dtype=np.int64)
     rows_a = np.asarray(designs.rows, dtype=np.int64)
     nm_a = np.asarray(designs.n_macros, dtype=np.int64)
@@ -658,11 +673,11 @@ def _assemble_grid(layer: Layer, designs, scheds, max_candidates: int,
                  _pow2_member(mac_un, dup_dim_size, nm_d)))
     legal &= mac_ok
     legal &= np.cumsum(legal, axis=1) <= max_candidates
-    legal = legal[inv]
     cand = _with_schedule_axis(cand, scheds)
     if len(cand) != legal.shape[1]:
         legal = np.repeat(legal, len(scheds), axis=1)
-    return MappingGrid(cand=cand, legal=legal)
+    return MappingGrid(cand=cand, legal_rows=legal,
+                       design_class=inv.astype(np.int32))
 
 
 def _unroll_pool(dim: int, caps: np.ndarray) -> np.ndarray:
@@ -966,11 +981,13 @@ class NetworkGrid:
     (layer, design, candidate) triple of the bucket in a single jit
     dispatch.
 
-    Masks: ``valid`` (Ctot,) marks real (non-pad) lanes; ``legal``
-    (D, Ctot) is the per-design legality of each lane (all-False on pad
-    lanes).  A design's legal subsequence of a segment *is* that
-    layer's scalar enumeration order, so masked per-segment argmins
-    tie-break exactly like the per-layer scalar oracle.
+    Masks: ``valid`` (Ctot,) marks real (non-pad) lanes; legality is
+    held per class as in :class:`MappingGrid` — ``legal_rows`` (U, Ctot)
+    with all-False pad lanes, ``design_class`` (D,) shared by every
+    segment — and ``legal`` is the expanded (D, Ctot) mask.  A design's
+    legal subsequence of a segment *is* that layer's scalar enumeration
+    order, so masked per-segment argmins tie-break exactly like the
+    per-layer scalar oracle.
     """
 
     layers: tuple[Layer, ...]          # one representative per segment
@@ -979,7 +996,8 @@ class NetworkGrid:
     starts: np.ndarray                 # (S+1,) int64 segment bounds
     cand: MappingBatch                 # flat lane axis (Ctot,)
     lane_layer: np.ndarray             # (Ctot,) int64 segment per lane
-    legal: np.ndarray                  # (D, Ctot) bool
+    legal_rows: np.ndarray             # (U, Ctot) bool, per class
+    design_class: np.ndarray           # (D,) int32 row of each design
     valid: np.ndarray                  # (Ctot,) bool, False on pad lanes
 
     def __len__(self) -> int:
@@ -987,7 +1005,12 @@ class NetworkGrid:
 
     @property
     def n_designs(self) -> int:
-        return self.legal.shape[0]
+        return len(self.design_class)
+
+    @functools.cached_property
+    def legal(self) -> np.ndarray:
+        """(D, Ctot) bool: lane j legal on design i."""
+        return self.legal_rows[self.design_class]
 
     @property
     def pad_lanes(self) -> int:
@@ -1059,10 +1082,17 @@ def _network_grid_impl(layers, designs, schedules, max_candidates,
         if pad:
             lane_layer = np.concatenate(
                 [lane_layer, np.zeros(pad, dtype=np.int64)])
-        legal = np.concatenate(
-            [g.legal for g in segs]
-            + ([np.zeros((segs[0].legal.shape[0], pad), dtype=bool)]
-               if pad else []), axis=1)
+        # every segment built over the same designs numbers its classes
+        # alike; grids of other provenance fall back to one class per design
+        design_class = segs[0].design_class
+        if all(np.array_equal(g.design_class, design_class)
+               for g in segs[1:]):
+            rows = [g.legal_rows for g in segs]
+        else:
+            rows = [g.legal for g in segs]
+            design_class = np.arange(len(design_class), dtype=np.int32)
+        if pad:
+            rows.append(np.zeros((len(rows[0]), pad), dtype=bool))
         valid = np.zeros(padded, dtype=bool)
         valid[:ctot] = True
         out.append(NetworkGrid(
@@ -1070,7 +1100,9 @@ def _network_grid_impl(layers, designs, schedules, max_candidates,
             grids=tuple(segs),
             shape_indices=tuple(members),
             starts=starts, cand=MappingBatch(**fields),
-            lane_layer=lane_layer, legal=legal, valid=valid))
+            lane_layer=lane_layer,
+            legal_rows=np.concatenate(rows, axis=1),
+            design_class=design_class, valid=valid))
     return tuple(out)
 
 
@@ -1270,7 +1302,7 @@ def _reduced_network_cost(net, designs, alpha, objective, per_bit,
         weight_bits=weight_bits, input_bits=input_bits,
         output_bits=output_bits, psum_bits=psum_bits,
         per_bit=pb, per_bit_spill=pb_spill, off_chip=off_chip,
-        legal=net.legal)
+        legal_rows=net.legal_rows, design_class=net.design_class)
     nbytes = sum(a.dtype.itemsize * a.size
                  for a in (best_idx, total, cycles))
     return ReducedNetworkCost(net=net, objective=objective,

@@ -19,6 +19,7 @@ spatial truncation first, schedule expansion second.
 """
 
 import numpy as np
+import pytest
 
 from repro.testing.hypocompat import given, settings, st
 
@@ -222,3 +223,97 @@ def test_zero_legal_matches_loop_oracle():
                                         schedules=scheds),
             mapping.candidate_grid(layer, grid, max_candidates=0,
                                    schedules=scheds))
+
+
+# --------------------------------------------------------------------------- #
+# per-class legality: legal_rows[design_class] is the per-design mask          #
+# --------------------------------------------------------------------------- #
+def _grid_1620() -> designs.MacroBatch:
+    """The 1620-design sweep grid (``benchmarks.design_sweep.make_grid``):
+    5 rows x 3 cols, so 15 legality classes."""
+    return designs.macro_grid(
+        rows=(64, 128, 256, 512, 1024), cols=(128, 256, 512),
+        adc_bits=(4, 5, 6, 7, 8), dac_bits=(1, 2, 4), m_mux=(1, 4, 16),
+        tech_nm=(5, 22, 28), vdd=(0.7, 0.8))
+
+
+def _grid_multi_macro() -> designs.MacroBatch:
+    return designs.macro_grid(
+        rows=(64, 256, 1024), cols=(128, 512), bw=(2, 8), adc_bits=(4, 8),
+        dac_bits=(1, 2), m_mux=(1, 16), tech_nm=(22,), vdd=(0.8,),
+        n_macros=(1, 2, 4))
+
+
+def _n_classes(grid: designs.MacroBatch) -> int:
+    return len({(int(a), int(b), int(c)) for a, b, c in
+                zip(grid.d1, grid.rows, grid.n_macros)})
+
+
+_CLASS_LAYERS = (
+    workloads.Layer("conv", "conv2d",
+                    dict(B=1, K=64, C=32, OX=16, OY=16, FX=3, FY=3)),
+    workloads.dense("fc", 1, 640, 128),
+    workloads.Layer("dw", "conv2d",
+                    dict(B=1, K=1, C=1, OX=25, OY=5, FX=3, FY=3, G=64)),
+)
+
+
+@pytest.mark.parametrize("max_candidates", [4096, 3])
+@pytest.mark.parametrize("scheds", [("ws",), ("os",), ("ws", "os")])
+@pytest.mark.parametrize("grid_fn", [_grid_1620, _grid_multi_macro])
+def test_class_rows_expand_to_per_design_mask(grid_fn, scheds,
+                                              max_candidates):
+    """The vectorized builder keeps legality on the distinct
+    (d1, rows, n_macros) classes; gathered through ``design_class`` it
+    is the loop oracle's per-design mask element for element, truncation
+    and schedule crossing included, and ``mappings_for`` reads one
+    design's row without expanding the rest."""
+    grid = grid_fn()
+    for layer in _CLASS_LAYERS:
+        loop = mapping.candidate_grid_loop(
+            layer, grid, max_candidates=max_candidates, schedules=scheds)
+        vec = mapping.candidate_grid(
+            layer, grid, max_candidates=max_candidates, schedules=scheds)
+        assert loop.legal_rows.shape == (len(grid), len(loop))
+        assert np.array_equal(loop.design_class, np.arange(len(grid)))
+        assert vec.legal_rows.shape == (_n_classes(grid), len(vec))
+        assert vec.design_class.shape == (len(grid),)
+        assert vec.design_class.dtype == np.int32
+        assert vec.n_designs == len(grid)
+        assert np.array_equal(vec.legal_rows[vec.design_class],
+                              loop.legal_rows)
+        if max_candidates == 3:
+            assert (vec.legal_rows.sum(axis=1) <= 3 * len(scheds)).all()
+        for d in (0, len(grid) // 2, len(grid) - 1):
+            assert vec.mappings_for(d) == loop.mappings_for(d)
+    if grid_fn is _grid_1620:
+        assert _n_classes(grid) == 15
+
+
+def test_network_grid_shares_design_class_and_pads_false():
+    """``network_grid`` concatenates the class rows of every segment:
+    one ``design_class`` for the bucket (each segment's own), pad lanes
+    False in every class, and each segment's lanes its grid's rows.
+    Grids numbering classes differently fall back to one class per
+    design with the same expanded mask."""
+    grid = _grid_multi_macro()
+    scheds = ("ws", "os")
+    grids = [mapping.candidate_grid(l, grid, schedules=scheds)
+             for l in _CLASS_LAYERS]
+    (net,) = mapping.network_grid(_CLASS_LAYERS, grid, schedules=scheds,
+                                  grids=grids, pad_quantum=256)
+    assert net.pad_lanes > 0, "fixture no longer pads the lane axis"
+    assert net.legal_rows.shape == (_n_classes(grid), len(net))
+    for s, g in enumerate(grids):
+        assert np.array_equal(g.design_class, net.design_class)
+        assert np.array_equal(net.legal_rows[:, net.segment(s)],
+                              g.legal_rows)
+        assert np.array_equal(net.legal[:, net.segment(s)], g.legal)
+    assert not net.legal_rows[:, ~net.valid].any()
+
+    mixed = [mapping.candidate_grid_loop(_CLASS_LAYERS[0], grid,
+                                         schedules=scheds)] + grids[1:]
+    (net_m,) = mapping.network_grid(_CLASS_LAYERS, grid, schedules=scheds,
+                                    grids=mixed, pad_quantum=256)
+    assert np.array_equal(net_m.design_class, np.arange(len(grid)))
+    assert np.array_equal(net_m.legal_rows, net.legal)

@@ -53,7 +53,8 @@ def _layer(k, c, ox, oy, name="r-layer"):
                            dict(B=1, K=k, C=c, OX=ox, OY=oy, FX=3, FY=3))
 
 
-def _price_both(shape_layers, grid, objective, scheds, depth=2):
+def _price_both(shape_layers, grid, objective, scheds, depth=2,
+                survivors=None):
     """Price the same shapes through the host oracle and the reduced
     pipelined engine; return both per-shape result lists."""
     per_bit, buffer_bytes, dram = dse._mem_pricing(grid, None)
@@ -61,12 +62,33 @@ def _price_both(shape_layers, grid, objective, scheds, depth=2):
     dse.cache_clear()
     dse.set_sweep_pipeline(0)
     host = dse._price_shapes(shape_layers, grid, objective, None,
-                             per_bit, buffer_bytes, dram, sch)
+                             per_bit, buffer_bytes, dram, sch,
+                             survivors=survivors)
     dse.cache_clear()
     dse.set_sweep_pipeline(depth)
     red = dse._price_shapes(shape_layers, grid, objective, None,
-                            per_bit, buffer_bytes, dram, sch)
+                            per_bit, buffer_bytes, dram, sch,
+                            survivors=survivors)
     return host, red
+
+
+@pytest.fixture
+def legality_seen(monkeypatch):
+    """Record the legality pair of every reduced dispatch."""
+    from repro.core import energy
+    seen = []
+    real = energy.reduce_objective_grid
+
+    def spy(designs, **kw):
+        # any other (D, lanes) bool argument would be a per-design mask
+        assert not [k for k, v in kw.items()
+                    if k != "legal_rows" and np.ndim(v) == 2
+                    and np.asarray(v).dtype == bool]
+        seen.append((kw["legal_rows"], kw["design_class"]))
+        return real(designs, **kw)
+
+    monkeypatch.setattr(energy, "reduce_objective_grid", spy)
+    return seen
 
 
 def _assert_slots_bitwise(host, red):
@@ -96,6 +118,99 @@ def test_reduced_matches_host_oracle(rows, cols, bw, adc_bits, m_mux,
               _layer(max(1, k // 2), c, ox, oy, name="r-half")]
     host, red = _price_both(layers, grid, objective, scheds, depth=depth)
     _assert_slots_bitwise(host, red)
+
+
+# --------------------------------------------------------------------------- #
+# per-class legality through the reduced kernel, faults off and on             #
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("objective", ["energy", "latency", "edp"])
+@pytest.mark.parametrize("faults", [False, True], ids=["faults-off",
+                                                       "faults-on"])
+def test_class_legality_matches_host_oracle(legality_seen, faults,
+                                            objective):
+    """The reduced kernel gathers ``legal_rows[design_class]`` on the
+    device; winners, totals and cycles stay bitwise the host oracle's.
+    Fault-free buckets carry one row per (d1, rows, n_macros) class;
+    with a survivor mask every design is its own class (U = D)."""
+    from repro.faults import FaultSpec, survivor_mask
+    grid = designs.macro_grid(rows=(64, 256), cols=(64, 512), bw=(2, 8),
+                              adc_bits=(4, 8), m_mux=(1, 4), tech_nm=(28,),
+                              n_macros=(1, 4))
+    layers = [_layer(40, 24, 5, 7), _layer(96, 8, 1, 1, name="r-b"),
+              _layer(12, 60, 16, 7, name="r-c")]
+    survivors = (survivor_mask(FaultSpec(column_fail_rate=0.4,
+                                         macro_fail_rate=0.4, seed=3),
+                               grid) if faults else None)
+    host, red = _price_both(layers, grid, objective, ("ws", "os"),
+                            survivors=survivors)
+    _assert_slots_bitwise(host, red)
+    n_classes = len(set(zip(grid.d1, grid.rows, grid.n_macros)))
+    assert n_classes < len(grid)
+    assert legality_seen
+    for legal_rows, design_class in legality_seen:
+        assert len(legal_rows) == (len(grid) if faults else n_classes)
+        assert design_class.shape == (len(grid),)
+    if faults:
+        clean, _ = _price_both(layers, grid, objective, ("ws", "os"))
+        assert any(not np.array_equal(c[1], h[1])
+                   for c, h in zip(clean, host)), \
+            "fixture's survivor mask no longer moves a winner"
+
+
+def _grid_1620():
+    """The 1620-design sweep grid (``benchmarks.design_sweep.make_grid``):
+    15 (d1, rows, n_macros) legality classes."""
+    return designs.macro_grid(
+        rows=(64, 128, 256, 512, 1024), cols=(128, 256, 512),
+        adc_bits=(4, 5, 6, 7, 8), dac_bits=(1, 2, 4), m_mux=(1, 4, 16),
+        tech_nm=(5, 22, 28), vdd=(0.7, 0.8))
+
+
+@pytest.mark.parametrize("faults", [False, True], ids=["faults-off",
+                                                       "faults-on"])
+def test_sweep_hands_class_legality_to_device(legality_seen, monkeypatch,
+                                              faults):
+    """Mechanism pin on the 1620-design grid: a fault-free sweep never
+    expands per-design legality on the host, every bucket hands the
+    device its 15 class rows, and ``dse.legal_bytes`` counts exactly
+    U * Ctot + 4 * D per bucket (the ``dse.price_bucket`` span names U).
+    With faults on every design is its own class, U = D."""
+    from repro import obs
+    from repro.core import mapping
+    from repro.faults import FaultSpec
+    grid = _grid_1620()
+    if not faults:
+        def expanded(self):
+            raise AssertionError("per-design legality built on the host")
+        monkeypatch.setattr(mapping.MappingGrid, "legal",
+                            property(expanded))
+        monkeypatch.setattr(mapping.NetworkGrid, "legal",
+                            property(expanded))
+    spec = FaultSpec(column_fail_rate=0.3, seed=1) if faults else None
+    monkeypatch.setattr(dse, "_BUCKET_ELEMS", 1)      # a bucket per shape
+    dse.cache_clear()
+    dse.set_sweep_pipeline(2)
+    obs.set_trace_enabled(True)
+    obs.drain_spans()
+    try:
+        dse.sweep_networks([("dae", workloads.deep_autoencoder())], grid,
+                           schedules=("ws", "os"), faults=spec)
+        legal_bytes = obs.snapshot("dse.")["dse.legal_bytes"]
+        spans = [r for r in obs.drain_spans()
+                 if r["name"] == "dse.price_bucket"]
+    finally:
+        obs.set_trace_enabled(None)
+    n_classes = len(grid) if faults else 15
+    assert len(legality_seen) >= 2
+    expect = 0
+    for legal_rows, design_class in legality_seen:
+        assert legal_rows.shape[0] == n_classes
+        assert design_class.shape == (len(grid),)
+        assert design_class.dtype == np.int32
+        expect += n_classes * legal_rows.shape[1] + 4 * len(grid)
+    assert legal_bytes == expect
+    assert [r["attrs"]["legal_rows"] for r in spans] == \
+        [n_classes] * len(legality_seen)
 
 
 # --------------------------------------------------------------------------- #

@@ -26,33 +26,64 @@ from repro.core import designs, dse, energy, workloads
 from repro.core.mapping import PAD_QUANTUM
 
 #: subprocess worker: 4 forced host devices; sweeps the same networks
-#: unsharded then sharded and prints exact comparison bits as JSON.
+#: unsharded then sharded (the reduced engine), and through the depth-0
+#: host oracle, with faults off and on, and prints exact comparison bits
+#: and the legality class counts the sharded dispatches saw as JSON.
 _SHARD_WORKER = """
 import json
 import numpy as np
 from repro.core import designs, dse, energy, workloads
+from repro.faults import FaultSpec
 
 grid = designs.macro_grid(
     rows=(64, 256, 1024), cols=(128, 512), adc_bits=(4, 8), dac_bits=(1, 2),
     m_mux=(1, 16), tech_nm=(22,), vdd=(0.8,), n_macros=(1, 2, 4))
 nets = [("dae", workloads.deep_autoencoder()),
         ("ds_cnn", workloads.ds_cnn())]
+faults = FaultSpec(column_fail_rate=0.3, macro_fail_rate=0.3, seed=5)
+
+def sweep(spec=None):
+    dse.cache_clear()
+    return dse.sweep_networks(nets, grid, schedules=("ws", "os"),
+                              faults=spec)
+
+def same(ra, rb):
+    return all(a.network == b.network
+               and np.array_equal(a.energy_fj, b.energy_fj)
+               and np.array_equal(a.cycles, b.cycles)
+               for a, b in zip(ra, rb))
 
 energy.set_lane_shards(1)
-ref = dse.sweep_networks(nets, grid, schedules=("ws", "os"))
+ref = sweep()
+dse.set_sweep_pipeline(0)
+host, host_f = sweep(), sweep(faults)
+dse.set_sweep_pipeline(None)
+
+seen = []
+real = energy.reduce_objective_grid
+def spy(designs, **kw):
+    seen.append(len(kw["legal_rows"]))
+    return real(designs, **kw)
+energy.reduce_objective_grid = spy
 
 energy.set_lane_shards(4)
-dse.cache_clear()
-sharded = dse.sweep_networks(nets, grid, schedules=("ws", "os"))
+sharded = sweep()
+rows_off = sorted(set(seen))
+seen.clear()
+sharded_f = sweep(faults)
 info = energy.grid_kernel_info()
 
-equal = all(
-    a.network == b.network
-    and np.array_equal(a.energy_fj, b.energy_fj)
-    and np.array_equal(a.cycles, b.cycles)
-    for a, b in zip(ref, sharded))
 import jax
-print(json.dumps({"devices": jax.device_count(), "bitwise": equal,
+print(json.dumps({"devices": jax.device_count(),
+                  "bitwise": same(ref, sharded),
+                  "host_bitwise": same(host, sharded),
+                  "faults_bitwise": same(host_f, sharded_f),
+                  "faults_differ": not same(host, host_f),
+                  "legal_rows_off": rows_off,
+                  "legal_rows_on": sorted(set(seen)),
+                  "n_classes": len(set(zip(grid.d1, grid.rows,
+                                           grid.n_macros))),
+                  "n_designs": len(grid),
                   "sharded_calls": info["sharded_calls"]}))
 """
 
@@ -74,14 +105,22 @@ def _run_worker(extra_env: dict) -> dict:
 
 
 def test_sharded_sweep_bitwise_equals_unsharded():
-    """ISSUE 6 acceptance: the shard_map lane path over a 4-device host
-    mesh returns bitwise the single-device sweep — totals and cycles of
-    every network, every design."""
+    """The shard_map lane path over a 4-device host mesh returns bitwise
+    the single-device sweep and the depth-0 host oracle — totals and
+    cycles of every network, every design — with the per-class legality
+    pair (fault-free: one row per legality class) and with faults on
+    (one class per design)."""
     out = _run_worker(
         {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
     assert out["devices"] == 4
     assert out["sharded_calls"] > 0            # the shard path really ran
     assert out["bitwise"] is True
+    assert out["host_bitwise"] is True
+    assert out["faults_bitwise"] is True
+    assert out["faults_differ"] is True        # the mask really bites
+    assert out["n_classes"] < out["n_designs"]
+    assert out["legal_rows_off"] == [out["n_classes"]]
+    assert out["legal_rows_on"] == [out["n_designs"]]
 
 
 @pytest.fixture

@@ -57,7 +57,7 @@ def test_mvm_kernel_compiles_to_mosaic(one_chip, kernel, m, k, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-D, C, S = 8, 256, 4
+D, C, S, U = 8, 256, 4, 3
 
 
 def test_raw_grid_kernel_compiles_f64(one_chip):
@@ -82,12 +82,13 @@ def test_reduce_argmin_kernel_compiles_f64(one_chip):
         wt_ipt = _spec((C,), np.int64, one_chip)
         cc_per_input = _spec((D, 1), np.int64, one_chip)
         write_cycles = _spec((D, C), np.int64, one_chip)
-        legal = _spec((D, C), np.bool_, one_chip)
+        legal_rows = _spec((U, C), np.bool_, one_chip)
+        design_class = _spec((D,), np.int32, one_chip)
         seg_ids = _spec((C,), np.int64, one_chip)
         seg_starts = _spec((S,), np.int64, one_chip)
         compiled = energy._reduce_argmin_kernel("energy", S).lower(
-            *terms, wt_ipt, cc_per_input, write_cycles, legal, seg_ids,
-            seg_starts).compile()
+            *terms, wt_ipt, cc_per_input, write_cycles, legal_rows,
+            design_class, seg_ids, seg_starts).compile()
     best, total, cycles = compiled.out_info
     assert best.shape == total.shape == cycles.shape == (S, D)
     assert total.dtype == np.float64 and cycles.dtype == np.int64
