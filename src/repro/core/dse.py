@@ -1187,7 +1187,8 @@ class ServingPointResult:
     """Per-design serving cost at ONE (prompt_len x batch) operating
     point: the phase-split (prefill + decode) MVM cost from the fused
     lattice plus the KV-cache hierarchy traffic, folded into
-    (tokens/s, J/token).
+    (tokens/s, J/token).  ``sum_phase`` runs over ``point.phases`` in
+    order: every layer group of every phase.
 
     All arrays are (D,), indexed like ``designs``.  The float
     association of every derived column is pinned (and property-tested)
@@ -1272,9 +1273,10 @@ def sweep_serving(points: Sequence[ServingPoint], designs: MacroBatch,
     """Price a serving operating-point grid against a macro grid in one
     fused pass — the serving axis of the DSE lattice.
 
-    Every phase of every point enters :func:`sweep_networks` as its own
-    workload, so the whole (point x phase x layer x design x mapping x
-    dataflow) lattice shares one lane axis, one set of jit dispatches
+    Every phase group of every point enters :func:`sweep_networks` as
+    its own workload (named ``<point>/<PhaseWorkload.tag>``), so the
+    whole (point x phase x layer x design x mapping x dataflow) lattice
+    shares one lane axis, one set of jit dispatches
     and the usual finite-sentinel masking; the per-(layer, design)
     argmin is therefore taken *per operating point* and is bitwise what
     ``map_network`` on that phase alone would pick.  On top of the MVM
@@ -1289,7 +1291,7 @@ def sweep_serving(points: Sequence[ServingPoint], designs: MacroBatch,
         nets = []
         for pt in points:
             for ph in pt.phases:
-                nets.append((f"{pt.name}/{ph.phase}", list(ph.layers)))
+                nets.append((f"{pt.name}/{ph.tag}", list(ph.layers)))
         sweeps = sweep_networks(nets, designs, objective=objective,
                                 alpha=alpha, mem=mem, schedules=schedules,
                                 faults=faults)
@@ -1345,7 +1347,7 @@ def serving_point_scalar(pt: ServingPoint, macro: IMCMacro,
     kv = 0.0
     cycles = 0.0
     for ph in pt.phases:
-        net = map_network(f"{pt.name}/{ph.phase}", list(ph.layers), macro,
+        net = map_network(f"{pt.name}/{ph.tag}", list(ph.layers), macro,
                           objective=objective, mem=m, alpha=alpha,
                           engine="scalar", schedules=schedules)
         energy = energy + net.total_energy_fj * ph.repeats

@@ -17,73 +17,96 @@ live KV window read back per step).  :func:`lm_imc_workloads` takes a
 bytes-based KV-cache traffic volumes the memory hierarchy prices
 (``memory.KVCacheHierarchy``) — into a ``workloads.ServingPoint`` for
 ``dse.sweep_serving``.
+
+Mixture-of-experts phases are priced as layer groups
+(:func:`phase_groups`): a dense prologue, the MoE block (mixers,
+router, shared experts) and, per MoE position, the routed experts a
+phase unit touches under balanced routing -- each touched expert a
+weight set of its own, so weight writes count every expert while MACs
+stay those of ``top_k`` experts per token.
 """
 
 from __future__ import annotations
 
 from typing import Sequence
 
+from repro import obs
 from repro.core.workloads import (Layer, LMBlockSpec, PhaseWorkload,
                                   ServingPoint, dense)
 from repro.models.lm import ModelConfig
 
 
-def _superblock_projections(cfg: ModelConfig) -> list[tuple[str, int, int, int]]:
-    """(name, in_features, out_features, calls_per_superblock)."""
+def _ffn_projections(name: str, d: int, width: int, act: str
+                     ) -> list[tuple[str, int, int]]:
+    """(name, in, out) of one dense FFN: up, down, then the gate of a
+    gated activation."""
+    projs = [(f"{name}_up", d, width), (f"{name}_down", width, d)]
+    if act in ("swiglu", "geglu"):
+        projs.append((f"{name}_gate", d, width))
+    return projs
+
+
+def _block_projections(cfg: ModelConfig, dense_ffn: bool = False
+                       ) -> list[tuple[int, bool, str, int, int]]:
+    """(position, routed, name, in_features, out_features) of one
+    superblock, in pricing order (``name`` without its ``p<pos>.``
+    tag).  ``routed`` marks a routed expert's projection (one expert's
+    weights; ``top_k`` of them per token).  ``dense_ffn`` lowers MoE
+    positions to the dense FFN of ``d_ff``, as the leading dense layers
+    of a ``first_dense`` config run."""
     d = cfg.d_model
-    projs: list[tuple[str, int, int, int]] = []
+    projs: list[tuple[int, bool, str, int, int]] = []
+
+    def add(pos: int, items, routed: bool = False) -> None:
+        projs.extend((pos, routed, *item) for item in items)
+
     for pos, kind in enumerate(cfg.pattern):
-        tag = f"p{pos}"
         if kind == "attn":
             a = cfg.attn
-            projs += [(f"{tag}.wq", d, a.q_dim, 1),
-                      (f"{tag}.wk", d, a.kv_dim, 1),
-                      (f"{tag}.wv", d, a.kv_dim, 1),
-                      (f"{tag}.wo", a.q_dim, d, 1)]
+            add(pos, [("wq", d, a.q_dim), ("wk", d, a.kv_dim),
+                      ("wv", d, a.kv_dim), ("wo", a.q_dim, d)])
         elif kind == "mla":
             m = cfg.mla
-            projs += [(f"{tag}.wq_a", d, m.q_lora_rank, 1),
-                      (f"{tag}.wq_b", m.q_lora_rank,
-                       m.n_heads * m.qk_dim, 1),
-                      (f"{tag}.wkv_a", d, m.kv_lora_rank + m.qk_rope_dim, 1),
-                      (f"{tag}.wk_b", m.kv_lora_rank,
-                       m.n_heads * m.qk_nope_dim, 1),
-                      (f"{tag}.wv_b", m.kv_lora_rank,
-                       m.n_heads * m.v_dim, 1),
-                      (f"{tag}.wo", m.n_heads * m.v_dim, d, 1)]
+            add(pos, [("wq_a", d, m.q_lora_rank),
+                      ("wq_b", m.q_lora_rank, m.n_heads * m.qk_dim),
+                      ("wkv_a", d, m.kv_lora_rank + m.qk_rope_dim),
+                      ("wk_b", m.kv_lora_rank, m.n_heads * m.qk_nope_dim),
+                      ("wv_b", m.kv_lora_rank, m.n_heads * m.v_dim),
+                      ("wo", m.n_heads * m.v_dim, d)])
         elif kind == "mamba":
             c = cfg.mamba
             di, r = c.d_inner(d), c.rank(d)
-            projs += [(f"{tag}.in_proj", d, 2 * di, 1),
-                      (f"{tag}.x_proj", di, r + 2 * c.d_state, 1),
-                      (f"{tag}.dt_proj", r, di, 1),
-                      (f"{tag}.out_proj", di, d, 1)]
+            add(pos, [("in_proj", d, 2 * di),
+                      ("x_proj", di, r + 2 * c.d_state),
+                      ("dt_proj", r, di), ("out_proj", di, d)])
         elif kind == "rwkv6":
-            projs += [(f"{tag}.w{n}", d, d, 1) for n in "rkvg"]
-            projs += [(f"{tag}.wo", d, d, 1),
-                      (f"{tag}.cm_wk", d, cfg.d_ff, 1),
-                      (f"{tag}.cm_wv", cfg.d_ff, d, 1),
-                      (f"{tag}.cm_wr", d, d, 1)]
-        # FFN / MoE (rwkv6 channel-mix already added above)
-        if kind == "rwkv6":
+            # rwkv6 carries its own channel mix in place of the FFN
+            add(pos, [(f"w{n}", d, d) for n in "rkvg"]
+                + [("wo", d, d), ("cm_wk", d, cfg.d_ff),
+                   ("cm_wv", cfg.d_ff, d), ("cm_wr", d, d)])
             continue
-        if cfg.layer_is_moe(pos):
+        if cfg.layer_is_moe(pos) and not dense_ffn:
             m = cfg.moe
-            # top_k experts touched per token
-            projs += [(f"{tag}.moe_gate", d, m.d_ff_expert, m.top_k),
-                      (f"{tag}.moe_up", d, m.d_ff_expert, m.top_k),
-                      (f"{tag}.moe_down", m.d_ff_expert, d, m.top_k)]
+            f = m.d_ff_expert
+            add(pos, [("router", d, m.n_experts)])
+            add(pos, [("moe_gate", d, f), ("moe_up", d, f),
+                      ("moe_down", f, d)], routed=True)
+            if m.n_shared:
+                add(pos, _ffn_projections("shared", d, m.n_shared * f,
+                                          cfg.ffn_act))
             if m.dense_residual:
-                projs += [(f"{tag}.ffn_gate", d, cfg.d_ff, 1),
-                          (f"{tag}.ffn_up", d, cfg.d_ff, 1),
-                          (f"{tag}.ffn_down", cfg.d_ff, d, 1)]
+                add(pos, _ffn_projections("ffn", d, cfg.d_ff, cfg.ffn_act))
         else:
-            n_mats = 3 if cfg.ffn_act in ("swiglu", "geglu") else 2
-            projs += [(f"{tag}.ffn_up", d, cfg.d_ff, 1),
-                      (f"{tag}.ffn_down", cfg.d_ff, d, 1)]
-            if n_mats == 3:
-                projs += [(f"{tag}.ffn_gate", d, cfg.d_ff, 1)]
+            add(pos, _ffn_projections("ffn", d, cfg.d_ff, cfg.ffn_act))
     return projs
+
+
+def _superblock_projections(cfg: ModelConfig) -> list[tuple[str, int, int, int]]:
+    """(name, in_features, out_features, calls_per_superblock): a routed
+    expert's projection is called ``top_k`` times per token."""
+    k = cfg.moe.top_k if cfg.moe is not None else 1
+    return [(f"p{pos}.{name}", fi, fo, k if routed else 1)
+            for pos, routed, name, fi, fo in _block_projections(cfg)]
 
 
 def _global_attn_frac(cfg: ModelConfig, pos: int) -> float:
@@ -163,6 +186,75 @@ def lm_imc_workloads(cfg: ModelConfig, tokens: int,
     return [dense(prefix + name, tokens * calls, fin, fout,
                   w_prec=w_prec, i_prec=i_prec)
             for (name, fin, fout, calls) in spec.projections]
+
+
+# --------------------------------------------------------------------------- #
+# layer groups of one phase unit (dense prologue, MoE block, routed experts)   #
+# --------------------------------------------------------------------------- #
+def balanced_routing(tokens: int, n_experts: int, top_k: int
+                     ) -> tuple[tuple[int, int], ...]:
+    """((tokens per expert, experts), ...) of ``tokens * top_k`` routing
+    assignments spread as evenly as they go over ``n_experts``: they
+    touch ``n = min(n_experts, tokens * top_k)`` experts, ``r`` of which
+    get ``q + 1`` tokens and ``n - r`` get ``q`` (``q, r = divmod(A,
+    n)``).  Empty classes are left out, the larger class comes first."""
+    a = tokens * top_k
+    n = min(n_experts, a)
+    q, r = divmod(a, n)
+    return tuple((b, c) for b, c in ((q + 1, r), (q, n - r)) if c)
+
+
+def phase_groups(cfg: ModelConfig, tokens: int, phase: str,
+                 w_prec: int = 4, i_prec: int = 4
+                 ) -> list[tuple[str, tuple[Layer, ...], int]]:
+    """(group, layers, superblocks) of ONE phase unit of ``tokens``
+    tokens, in pricing order; the group's repeats are its superblock
+    count (times ``gen_len`` in decode).
+
+    Without MoE: one unnamed group, :func:`lm_imc_workloads` over
+    ``n_super`` superblocks.  With MoE every touched expert is a weight
+    set of its own, as in a deployment:
+
+    * ``dense``: the ``first_dense`` leading layers with their dense FFN;
+    * ``moe``: every projection of the remaining superblocks but the
+      routed experts' (mixers, router, shared experts, dense FFNs of the
+      non-MoE positions and a dense residual);
+    * ``p<pos>.routed.b<B>``: per MoE position, one class of
+      :func:`balanced_routing` -- one expert's gate, up and down at
+      ``B`` tokens, repeated over the class's experts and the ``moe``
+      group's superblocks.  Its MACs equal ``top_k`` calls per token.
+    """
+    if cfg.moe is None:
+        return [("", tuple(lm_imc_workloads(
+            cfg, tokens, w_prec=w_prec, i_prec=i_prec, phase=phase)),
+            cfg.n_super)]
+    m = cfg.moe
+
+    def lower(group: str, projs, b: int) -> tuple[Layer, ...]:
+        return tuple(dense(f"{phase}.{group}.{name}", b, fi, fo,
+                           w_prec=w_prec, i_prec=i_prec)
+                     for name, fi, fo in projs)
+
+    groups = []
+    if m.first_dense:
+        groups.append(("dense", lower("dense", [
+            (f"p{pos}.{name}", fi, fo) for pos, _, name, fi, fo
+            in _block_projections(cfg, dense_ffn=True)], tokens),
+            m.first_dense))
+    block = _block_projections(cfg)
+    n_moe = cfg.n_super - m.first_dense
+    groups.append(("moe", lower("moe", [
+        (f"p{pos}.{name}", fi, fo) for pos, routed, name, fi, fo in block
+        if not routed], tokens), n_moe))
+    for pos in range(len(cfg.pattern)):
+        routed = [(name, fi, fo) for p, r, name, fi, fo in block
+                  if r and p == pos]
+        if not routed:
+            continue
+        for b, count in balanced_routing(tokens, m.n_experts, m.top_k):
+            group = f"p{pos}.routed.b{b}"
+            groups.append((group, lower(group, routed, b), n_moe * count))
+    return groups
 
 
 # --------------------------------------------------------------------------- #
@@ -316,40 +408,46 @@ def serving_points(cfg: ModelConfig,
     """Build the (prompt_len x batch) operating-point grid of one LM as
     phase-split :class:`~repro.core.workloads.ServingPoint` bundles.
 
-    Each point carries a prefill :class:`PhaseWorkload` (one superblock
-    at B = batch * prompt_len, repeated ``n_super`` times) and a decode
-    one (one superblock at B = batch for ONE step, repeated
-    ``n_super * gen_len`` times), plus the whole-phase KV-cache byte
-    volumes at that point's context.  Feed the tuple straight to
-    ``dse.sweep_serving``.
+    Each phase is one :class:`PhaseWorkload` per layer group of
+    :func:`phase_groups` (one group without MoE): prefill's unit is the
+    whole prompt at B = batch * prompt_len, repeated over the group's
+    superblocks; decode's is ONE step at B = batch, repeated over the
+    group's superblocks times ``gen_len``.  A phase's first group
+    carries its whole-phase KV-cache byte volumes at that point's
+    context, and decode's first group the generated tokens.  Feed the
+    tuple straight to ``dse.sweep_serving``.
     """
-    points = []
-    for prompt_len, batch in grid:
-        name = f"{cfg.name}/p{prompt_len}xb{batch}"
-        ctx = prompt_len + gen_len
-        pre_layers = tuple(lm_imc_workloads(
-            cfg, tokens=batch * prompt_len, w_prec=w_prec, i_prec=i_prec,
-            phase="prefill", ctx_len=prompt_len))
-        dec_layers = tuple(lm_imc_workloads(
-            cfg, tokens=batch, w_prec=w_prec, i_prec=i_prec,
-            phase="decode", ctx_len=ctx))
-        pre_r, pre_w = kv_phase_traffic(cfg, "prefill", prompt_len, batch)
-        dec_r, dec_w = kv_phase_traffic(cfg, "decode", prompt_len, batch,
-                                        gen_len=gen_len)
-        points.append(ServingPoint(
-            name=name, prompt_len=prompt_len, batch=batch, gen_len=gen_len,
-            phases=(
-                PhaseWorkload(
-                    phase="prefill", layers=pre_layers,
-                    repeats=float(cfg.n_super),
-                    kv_read_bytes=pre_r, kv_write_bytes=pre_w,
-                    kv_live_bytes=kv_live_bytes(cfg, prompt_len, batch),
-                    tokens_out=0.0),
-                PhaseWorkload(
-                    phase="decode", layers=dec_layers,
-                    repeats=float(cfg.n_super) * gen_len,
-                    kv_read_bytes=dec_r, kv_write_bytes=dec_w,
-                    kv_live_bytes=kv_live_bytes(cfg, ctx, batch),
-                    tokens_out=float(batch) * gen_len),
-            )))
+    with obs.span("lm_bridge.serving_points", points=len(grid)) as sp:
+        points = []
+        groups = entries = touched = 0
+        for prompt_len, batch in grid:
+            ctx = prompt_len + gen_len
+            phases = []
+            for phase, tokens, steps, live_ctx, out in (
+                    ("prefill", batch * prompt_len, 1, prompt_len, 0.0),
+                    ("decode", batch, gen_len, ctx,
+                     float(batch) * gen_len)):
+                kv_r, kv_w = kv_phase_traffic(cfg, phase, prompt_len, batch,
+                                              gen_len=steps)
+                for i, (group, layers, supers) in enumerate(phase_groups(
+                        cfg, tokens, phase, w_prec=w_prec, i_prec=i_prec)):
+                    first = i == 0
+                    phases.append(PhaseWorkload(
+                        phase=phase, layers=layers,
+                        repeats=float(supers) * steps, group=group,
+                        kv_read_bytes=kv_r if first else 0.0,
+                        kv_write_bytes=kv_w if first else 0.0,
+                        kv_live_bytes=(kv_live_bytes(cfg, live_ctx, batch)
+                                       if first else 0.0),
+                        tokens_out=out if first else 0.0))
+                    entries += len(layers)
+                if cfg.moe is not None:
+                    touched = max(touched, min(cfg.moe.n_experts,
+                                               tokens * cfg.moe.top_k))
+            groups += len(phases)
+            points.append(ServingPoint(
+                name=f"{cfg.name}/p{prompt_len}xb{batch}",
+                prompt_len=prompt_len, batch=batch, gen_len=gen_len,
+                phases=tuple(phases)))
+        sp.set(groups=groups, entries=entries, experts_touched=touched)
     return tuple(points)

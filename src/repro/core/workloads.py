@@ -176,14 +176,21 @@ SERVING_PHASES = ("prefill", "decode")
 
 @dataclasses.dataclass(frozen=True)
 class PhaseWorkload:
-    """One serving phase of one operating point, ready for the fused DSE.
+    """One layer group of one serving phase of one operating point,
+    ready for the fused DSE.
 
     ``layers`` hold the MVM workloads of ONE superblock for ONE unit of
     the phase (the whole prompt for prefill, one decode step for
     decode); ``repeats`` scales the priced unit to the whole request
-    batch's phase (``n_super`` superblocks, times ``gen_len`` steps for
-    decode).  The KV fields are whole-phase, whole-model byte volumes
-    for the bytes-based cache hierarchy (``memory.KVCacheHierarchy``):
+    batch's phase (the group's superblocks, times ``gen_len`` steps for
+    decode) and is the only multiplier the sweep applies.  A phase
+    whose layers do not all repeat alike -- a dense prologue, the MoE
+    block, one routed-expert class -- is several consecutive entries of
+    ``ServingPoint.phases`` with the same ``phase``, one per ``group``;
+    a one-group phase leaves ``group`` empty.  The KV fields are
+    whole-phase, whole-model byte volumes for the bytes-based cache
+    hierarchy (``memory.KVCacheHierarchy``), held by the phase's first
+    group (0 on the others):
 
     * ``kv_read_bytes`` / ``kv_write_bytes`` — cache traffic the phase
       generates (attention reads the live window per token, appends one
@@ -202,11 +209,18 @@ class PhaseWorkload:
     kv_write_bytes: float = 0.0
     kv_live_bytes: float = 0.0
     tokens_out: float = 0.0
+    group: str = ""                  # layer group within the phase
 
     def __post_init__(self) -> None:
         if self.phase not in SERVING_PHASES:
             raise ValueError(f"unknown serving phase {self.phase!r}; "
                              f"expected one of {SERVING_PHASES}")
+
+    @property
+    def tag(self) -> str:
+        """``phase`` or ``phase/group``: names the group's network in a
+        serving sweep."""
+        return f"{self.phase}/{self.group}" if self.group else self.phase
 
 
 @dataclasses.dataclass(frozen=True)
@@ -220,7 +234,7 @@ class ServingPoint:
     prompt_len: int
     batch: int
     gen_len: int
-    phases: tuple[PhaseWorkload, ...]
+    phases: tuple[PhaseWorkload, ...]   # every group of every phase, in order
 
     @property
     def tokens_out(self) -> float:

@@ -83,6 +83,15 @@ class ModelConfig:
             f"{len(self.pattern)} != 0"
         if self.moe is not None:
             assert len(self.pattern) % self.moe.every == 0
+            if self.moe.first_dense and len(self.pattern) != 1:
+                raise ValueError(
+                    f"{self.name}: a dense prologue (first_dense "
+                    f"{self.moe.first_dense}) needs a one-position pattern, "
+                    f"not {self.pattern}")
+            if not 0 <= self.moe.first_dense < self.n_layers:
+                raise ValueError(f"{self.name}: first_dense "
+                                 f"{self.moe.first_dense} outside "
+                                 f"[0, {self.n_layers})")
 
     @property
     def n_super(self) -> int:
@@ -203,6 +212,13 @@ class LM:
     """Functional model handle: config + dist context."""
 
     def __init__(self, cfg: ModelConfig, dist: Dist = NO_DIST):
+        m = cfg.moe
+        if m is not None and (m.n_shared or m.first_dense):
+            raise NotImplementedError(
+                f"{cfg.name}: LM has no shared experts (n_shared "
+                f"{m.n_shared}) and no dense prologue (first_dense "
+                f"{m.first_dense}); such a config is priced by "
+                f"core.lm_bridge only")
         self.cfg = cfg
         self.dist = dataclasses.replace(
             dist, fsdp_over_pod=cfg.fsdp_over_pod)

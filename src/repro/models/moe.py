@@ -32,6 +32,13 @@ class MoEConfig:
     dense_residual: bool = False  # arctic: dense FFN in parallel with MoE
     capacity_factor: float = 1.25
     aux_coef: float = 0.01
+    #: always-on experts of width ``d_ff_expert`` beside the routed ones
+    #: (HF ``n_shared_experts``); priced by ``core.lm_bridge`` only
+    n_shared: int = 0
+    #: leading layers that keep the dense FFN of ``d_ff`` (HF
+    #: ``first_k_dense_replace``); priced by ``core.lm_bridge`` only,
+    #: and only for a one-position pattern (``ModelConfig`` checks)
+    first_dense: int = 0
 
 
 # --------------------------------------------------------------------------- #
