@@ -345,8 +345,13 @@ _T_BUCKET_WARM = obs.timer("dse.bucket.warm")
 #: for the last sweep.
 _C_TRANSFER = obs.counter("dse.transfer_bytes")
 #: legality bytes handed to the device by the reduced engine: per bucket
-#: the class rows plus each design's class index
+#: the class rows, per sweep each design's class index (an int64 row of
+#: the design block)
 _C_LEGAL_BYTES = obs.counter("dse.legal_bytes")
+#: host arrays the reduced engine hands the device: the design block's
+#: two per sweep, then each bucket's dispatch (incremented in
+#: ``energy``, which does the handing)
+_C_H2D = obs.counter("dse.h2d_arrays")
 _C_PIPE_BUCKETS = obs.counter("dse.pipeline.buckets")
 _G_PIPE_DEPTH = obs.gauge("dse.pipeline.depth")
 _G_PIPE_OCC = obs.gauge("dse.pipeline.occupancy")
@@ -925,17 +930,24 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
     ``dse.price_bucket`` (host dispatch only: jit trace+compile is
     synchronous, so first-call cost lands there, with one
     ``dse.bucket.first_call``/``warm`` observation per bucket; attr
-    ``legal_rows`` is the bucket's legality class count) and
+    ``legal_rows`` is the bucket's legality class count,
+    ``h2d_arrays`` the host arrays it handed the device) and
     ``dse.finalize_bucket`` holding ``dse.device_wait`` (the
     realization of the winners: the wait for the device plus the copy).
     The builder thread's spans name the caller's open span (the sweep's
     root) as their parent.  Plus ``dse.transfer_bytes``,
-    ``dse.legal_bytes`` (legality handed to the device) and the
-    ``dse.pipeline.*`` depth/occupancy gauges.
+    ``dse.legal_bytes`` (legality handed to the device),
+    ``dse.h2d_arrays`` and the ``dse.pipeline.*`` depth/occupancy
+    gauges.
+
+    The per-design arguments every bucket shares (design constants,
+    traffic rates, ``alpha``, legality classes) are put on the device
+    once, at the first bucket (``mapping.reduced_design_block``), and
+    handed to each dispatch from there; nothing is kept past the sweep.
     """
     from .compilecache import persistent_cache_dir
     from .energy import grid_kernel_info
-    from .mapping import evaluate_network_grid
+    from .mapping import evaluate_network_grid, reduced_design_block
 
     _G_PIPE_DEPTH.set(depth)
     out: list[tuple | None] = [None] * len(shape_layers)
@@ -948,6 +960,7 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
         name="repro-sweep-builder", daemon=True)
 
     pending: collections.deque = collections.deque()
+    block = None
     busy = 0.0
     busy_start: float | None = None
     t_loop = time.perf_counter()
@@ -971,20 +984,24 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
                           designs=net.n_designs, reduced=True) as sp:
                 net = _with_survivors(net, survivors)
                 shapes_before = grid_kernel_info()["distinct_shapes"]
+                h2d_before = _C_H2D.value
                 t0 = time.perf_counter()
                 if busy_start is None:
                     busy_start = t0
+                if block is None:
+                    block = reduced_design_block(
+                        designs, net.design_class, per_bit=per_bit,
+                        alpha=alpha, dram_fj_per_bit=dram)
+                    _C_LEGAL_BYTES.inc(8 * len(net.design_class))
                 resident = np.asarray(
                     [_resident_bytes_cached(l) for l in net.layers],
                     dtype=np.int64)[net.lane_layer]
                 red = evaluate_network_grid(
-                    net, designs, alpha=alpha, reduce=True,
-                    objective=objective, per_bit=per_bit,
-                    resident_bytes=resident, buffer_bytes=buffer_bytes,
-                    dram_fj_per_bit=dram)
+                    net, designs, reduce=True, objective=objective,
+                    design_block=block, resident_bytes=resident,
+                    buffer_bytes=buffer_bytes)
                 sp.lap("dispatch")
-                _C_LEGAL_BYTES.inc(net.legal_rows.nbytes
-                                   + net.design_class.nbytes)
+                _C_LEGAL_BYTES.inc(net.legal_rows.nbytes)
                 new_shapes = (grid_kernel_info()["distinct_shapes"]
                               - shapes_before)
                 timer = _T_BUCKET_FIRST if new_shapes else _T_BUCKET_WARM
@@ -993,7 +1010,8 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
                        first_call=bool(new_shapes),
                        persistent_cache=persistent_cache_dir()
                        is not None,
-                       legal_rows=len(net.legal_rows))
+                       legal_rows=len(net.legal_rows),
+                       h2d_arrays=_C_H2D.value - h2d_before)
             pending.append((members, net, red))
             bi += 1
             _C_PIPE_BUCKETS.inc()

@@ -827,43 +827,205 @@ _REDUCE_TERMS_KERNELS: dict = {}
 _REDUCED_FUSED_KERNELS: dict = {}
 _REDUCE_ARGMIN_KERNELS: dict = {}
 
+#: Packed arguments of the reduced route.  Each host array handed to a
+#: jit call is its own host-to-device transfer (about 0.1 ms apiece on a
+#: TPU v5e host), so the route's arguments cross as a few row-packed
+#: blocks: the per-design ``(rows, D)`` pair below, put once per sweep
+#: (:func:`put_design_block`), and one int64 ``(rows, Ctot)`` lane block
+#: per bucket.  Every column keeps its dtype — floats ride the float64
+#: block, ints and bools the int64 ones (bools as 0/1, restored inside
+#: the kernels) — so the kernels only slice rows and run the same float
+#: ops as on loose arguments.  ``seg_starts`` holds the S segment starts
+#: padded to the lane width (every segment spans at least one lane).
+_DESIGN_F64_ROWS = ("e_wl_line", "e_bl_word", "p_logic", "adc_e",
+                    "f_tree_a", "f_tree_d", "p_tree", "dac_e", "p_write",
+                    "per_bit", "per_bit_spill", "alpha")
+_DESIGN_I64_ROWS = ("analog", "mmux1", "rows", "d1", "bw", "m", "cc_bs",
+                    "denom_adc", "cols_per_adc", "denom_occ",
+                    "cc_per_input", "design_class")
+_LANE_ROWS = ("n_inputs", "rows_used", "cols_used", "weight_loads",
+              "schedule_os", "active_macros", "weight_tiles",
+              "weight_bits", "input_bits", "output_bits", "psum_bits",
+              "off_chip", "wt_ipt", "write_cycles", "seg_ids",
+              "seg_starts")
+_BOOL_ROWS = frozenset(("analog", "mmux1", "schedule_os", "off_chip"))
+#: the raw grid kernel's design columns, in its parameter order
+_RAW_DESIGN_ARGS = ("analog", "mmux1", "rows", "d1", "bw", "m", "cc_bs",
+                    "e_wl_line", "e_bl_word", "p_logic", "adc_e",
+                    "denom_adc", "cols_per_adc", "f_tree_a", "f_tree_d",
+                    "p_tree", "denom_occ", "dac_e", "p_write")
 
-def _reduce_terms_kernel(has_os: bool):
-    """Stage-2a: OS fold + active-macro scaling + traffic products.
+#: host arrays handed to the device by the reduced route: the design
+#: block's two at each put, and each bucket's dispatch (its lane block
+#: and ``legal_rows``; the sharded route adds its stage-1 columns).
+#: Kept with the sweep's counters, so ``dse.cache_clear`` resets it.
+_C_H2D = obs.counter("dse.h2d_arrays")
 
-    Reproduces the host oracle's per-term float ops exactly: the fold
-    (``e_adc + x_adc`` on raw kernel outputs, before scaling — adds of
-    program parameters, uncontractable), the two-multiply
-    ``(x * active_macros) * weight_tiles`` scaling, and the four
+
+def _pack(names, values: dict, dtype, n: int) -> np.ndarray:
+    """Stack ``values[name]`` (each broadcastable to ``(n,)``) as the
+    rows of one ``(len(names), n)`` block.  A float value never enters
+    an int block nor an int one the float block: that would change the
+    op chain the kernels run."""
+    kinds = "f" if np.dtype(dtype).kind == "f" else "biu"
+    out = np.empty((len(names), n), dtype=dtype)
+    for r, name in enumerate(names):
+        v = np.asarray(values[name])
+        if v.dtype.kind not in kinds:
+            raise TypeError(f"{name} ({v.dtype}) cannot ride a {out.dtype} "
+                            f"block")
+        out[r] = v
+    return out
+
+
+class _Rows:
+    """Named rows of packed blocks inside a kernel, bools restored.
+
+    ``row`` gives a lane row as (1, n), which broadcasts against the
+    (D, 1) design columns exactly as a (n,) row does; ``col`` a design
+    row as (n, 1), sliced from the block transposed once.  Each is
+    sliced once, with ``lax`` primitives, so the kernel's trace stays
+    close to its size on loose arguments.
+    """
+
+    def __init__(self, *blocks):
+        self._at = {name: (block, r) for block, names in blocks
+                    for r, name in enumerate(names)}
+        self._memo: dict = {}
+
+    def _get(self, key, make):
+        if key not in self._memo:
+            self._memo[key] = make()
+        return self._memo[key]
+
+    def _bool(self, name, x):
+        from jax import lax
+        # bool(x) is x != 0
+        return (lax.convert_element_type(x, np.bool_)
+                if name in _BOOL_ROWS else x)
+
+    def row(self, name):
+        """Row ``name`` as (1, n)."""
+        from jax import lax
+        block, r = self._at[name]
+        return self._get(("row", name), lambda: self._bool(
+            name, lax.slice_in_dim(block, r, r + 1)))
+
+    def col(self, name):
+        """Row ``name`` as an (n, 1) column."""
+        from jax import lax
+        block, r = self._at[name]
+        t = self._get(("t", id(block)),
+                      lambda: lax.transpose(block, (1, 0)))
+        return self._get(("col", name), lambda: self._bool(
+            name, lax.slice_in_dim(t, r, r + 1, axis=1)))
+
+    def vec(self, name):
+        """Row ``name`` as (n,)."""
+        from jax import lax
+        x = self.row(name)
+        return self._get(("vec", name),
+                         lambda: lax.reshape(x, x.shape[1:]))
+
+    def first(self, name):
+        """The first entry of row ``name``, a scalar."""
+        from jax import lax
+        block, r = self._at[name]
+        return self._get(("first", name), lambda: lax.reshape(
+            lax.slice(block, (r, 0), (r + 1, 1)), ()))
+
+
+@dataclasses.dataclass(frozen=True)
+class DesignBlock:
+    """The reduced route's per-design arguments, on the device.
+
+    ``f64`` holds :data:`_DESIGN_F64_ROWS` and ``i64``
+    :data:`_DESIGN_I64_ROWS`, each ``(rows, D)``.  ``design_class`` is
+    the host copy of the classes the block holds: every bucket priced
+    with the block must carry the same.  ``alpha`` stays on the host for
+    the sharded route's stage-1, which keeps its own arguments.
+    """
+
+    f64: object                  # jax float64 (len(_DESIGN_F64_ROWS), D)
+    i64: object                  # jax int64 (len(_DESIGN_I64_ROWS), D)
+    design_class: np.ndarray     # (D,) int
+    alpha: float
+
+
+def put_design_block(designs, design_class, *, alpha: float, per_bit,
+                     per_bit_spill, cc_per_input) -> DesignBlock:
+    """Put the per-design arguments of every bucket of one sweep over
+    ``designs`` on the device: :func:`_design_constants`, the traffic
+    rates ``per_bit`` / ``per_bit_spill`` and ``cc_per_input`` (each
+    (D,) or a scalar), ``alpha`` and each design's legality class."""
+    import jax
+
+    n = len(design_class)
+    rows = dict(_design_constants(designs), alpha=alpha, per_bit=per_bit,
+                per_bit_spill=per_bit_spill, cc_per_input=cc_per_input,
+                design_class=design_class)
+    f64 = _pack(_DESIGN_F64_ROWS, rows, np.float64, n)
+    i64 = _pack(_DESIGN_I64_ROWS, rows, np.int64, n)
+    # outside enable_x64 the put would demote both blocks to 32 bits
+    with jax.enable_x64(True):
+        f64, i64 = jax.device_put((f64, i64))
+    _C_H2D.inc(2)
+    return DesignBlock(f64=f64, i64=i64,
+                       design_class=np.asarray(design_class),
+                       alpha=float(alpha))
+
+
+def _terms(parts, f, lanes, has_os: bool):
+    """Stage-2a body: OS fold + active-macro scaling + traffic products.
+
+    ``parts`` are the raw grid kernel's seven energy terms and its two
+    OS extras; ``f`` and ``lanes`` give the float design rows and the
+    lane rows (:class:`_Rows`).  Reproduces the host oracle's per-term
+    float ops exactly: the fold (``e_adc + x_adc`` on raw kernel
+    outputs, before scaling — adds whose operands end in
+    ``fdiv``/``select``, uncontractable), the two-multiply ``(x *
+    active_macros) * weight_tiles`` scaling, and the four
     ``memory.traffic_terms`` products.  Returns the eleven term grids;
     no float term is ever added to another here.
     """
+    import jax.numpy as jnp
+
+    from .memory import traffic_terms
+    e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write, x_adc, x_dac = parts
+    if has_os:
+        e_adc = e_adc + x_adc
+        e_dac = e_dac + x_dac
+
+    def scale2(x):
+        return (x * lanes.row("active_macros")) * lanes.row("weight_tiles")
+
+    terms = [scale2(p) for p in
+             (e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write)]
+    terms += list(traffic_terms(
+        jnp, f.col("per_bit"), f.col("per_bit_spill"),
+        lanes.row("off_chip"), lanes.row("weight_bits"),
+        lanes.row("input_bits"), lanes.row("output_bits"),
+        lanes.row("psum_bits")))
+    return tuple(terms)
+
+
+def _reduce_terms_kernel(has_os: bool):
+    """Stage-2a alone (:func:`_terms`), for the sharded route: takes
+    the stage-1 terms and OS extras as program parameters, then the
+    float design block and the lane block."""
     fn = _REDUCE_TERMS_KERNELS.get(has_os)
     if fn is None:
         import jax
-        import jax.numpy as jnp
 
         from .compilecache import enable_compilation_cache
-        from .memory import traffic_terms
         enable_compilation_cache()
 
         def kernel(e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write,
-                   x_adc, x_dac, active_macros, weight_tiles,
-                   weight_bits, input_bits, output_bits, psum_bits,
-                   per_bit, per_bit_spill, off_chip):
-            if has_os:
-                e_adc = e_adc + x_adc
-                e_dac = e_dac + x_dac
-
-            def scale2(x):
-                return (x * active_macros) * weight_tiles
-
-            terms = [scale2(p) for p in
-                     (e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write)]
-            terms += list(traffic_terms(
-                jnp, per_bit, per_bit_spill, off_chip,
-                weight_bits, input_bits, output_bits, psum_bits))
-            return tuple(terms)
+                   x_adc, x_dac, f64, lanes):
+            return _terms((e_wl, e_bl, e_logic, e_adc, e_tree, e_dac,
+                           e_write, x_adc, x_dac),
+                          _Rows((f64, _DESIGN_F64_ROWS)),
+                          _Rows((lanes, _LANE_ROWS)), has_os)
 
         fn = jax.jit(kernel)
         _REDUCE_TERMS_KERNELS[has_os] = fn
@@ -871,60 +1033,42 @@ def _reduce_terms_kernel(has_os: bool):
 
 
 def _reduced_fused_kernel(has_os: bool):
-    """Stage-1 grid kernel + stage-2a terms in ONE executable.
+    """Stage-1 grid kernel + stage-2a terms in ONE executable, on the
+    packed arguments ``(f64, i64, lanes)``.
 
     The unsharded reduced path's fast dispatch: composes
-    :func:`_raw_grid_kernel` with the OS fold, the active-macro scaling
-    and the traffic products inside a single jit module, so stage-1's
-    ten (D, C) float64 intermediates are never materialized as buffers
-    between executables — for a full 4M-element bucket that saves
-    ~640 MB of memory traffic per dispatch plus one compile.
+    :func:`_raw_grid_kernel` — fed (D, 1) design columns and (Ctot,)
+    lane rows sliced from the blocks — with :func:`_terms` inside a
+    single jit module, so stage-1's ten (D, C) float64 intermediates
+    are never materialized as buffers between executables — for a full
+    4M-element bucket that saves ~640 MB of memory traffic per dispatch
+    plus one compile.
 
     Bitwise safety (see the cache-block comment above): the raw kernel
     body has no float adds, the OS fold adds operands end in
     ``fdiv``/``select`` and their sums feed multiplies, so the merged
     module exposes no ``fmul``→``fadd`` edge for LLVM to contract —
     every float op lands exactly as in the split two-kernel chain
-    (property-pinned in ``tests/core/test_reduced_sweep.py``).
+    (property-pinned in ``tests/core/test_reduced_sweep.py``).  Row
+    slices add no float arithmetic.
     """
     fn = _REDUCED_FUSED_KERNELS.get(has_os)
     if fn is None:
         import jax
-        import jax.numpy as jnp
 
         from .compilecache import enable_compilation_cache
-        from .memory import traffic_terms
         enable_compilation_cache()
         raw = _raw_grid_kernel()
 
-        def kernel(analog, mmux1, rows, d1, bw, m, cc_bs,
-                   e_wl_line, e_bl_word, p_logic, adc_e, denom_adc,
-                   cols_per_adc, f_tree_a, f_tree_d, p_tree, denom_occ,
-                   dac_e, p_write,
-                   n_inputs, rows_used, cols_used, weight_loads, sched_os,
-                   alpha, active_macros, weight_tiles,
-                   weight_bits, input_bits, output_bits, psum_bits,
-                   per_bit, per_bit_spill, off_chip):
-            (e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write, _macs,
-             x_adc, x_dac) = raw(
-                analog, mmux1, rows, d1, bw, m, cc_bs, e_wl_line,
-                e_bl_word, p_logic, adc_e, denom_adc, cols_per_adc,
-                f_tree_a, f_tree_d, p_tree, denom_occ, dac_e, p_write,
-                n_inputs, rows_used, cols_used, weight_loads, sched_os,
-                alpha)
-            if has_os:
-                e_adc = e_adc + x_adc
-                e_dac = e_dac + x_dac
-
-            def scale2(x):
-                return (x * active_macros) * weight_tiles
-
-            terms = [scale2(p) for p in
-                     (e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write)]
-            terms += list(traffic_terms(
-                jnp, per_bit, per_bit_spill, off_chip,
-                weight_bits, input_bits, output_bits, psum_bits))
-            return tuple(terms)
+        def kernel(f64, i64, lanes):
+            d = _Rows((f64, _DESIGN_F64_ROWS), (i64, _DESIGN_I64_ROWS))
+            ln = _Rows((lanes, _LANE_ROWS))
+            parts = raw(*(d.col(k) for k in _RAW_DESIGN_ARGS),
+                        *(ln.row(k) for k in ("n_inputs", "rows_used",
+                                              "cols_used", "weight_loads",
+                                              "schedule_os")),
+                        d.first("alpha"))
+            return _terms(parts[:7] + parts[8:], d, ln, has_os)
 
         fn = jax.jit(kernel)
         _REDUCED_FUSED_KERNELS[has_os] = fn
@@ -937,12 +1081,14 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
     The eleven term grids enter as program parameters, so the chained
     adds below — the same ``(((e_wl+e_bl)+e_logic)+(e_adc+e_tree))+...``
     / ``((w+i)+o)+p`` association ``dse._price_buckets`` runs in NumPy
-    — have no producer multiply for LLVM to contract with.  Cycles are
-    int64 (exact on device); the objective column replaces illegal and
-    padded lanes with the finite sentinels.  Legality arrives per class
-    — ``legal_rows`` (U, Ctot) and each design's row ``design_class``
-    (D,) — and is gathered to (D, Ctot) here on the device, so no
-    per-design mask crosses from the host.
+    — have no producer multiply for LLVM to contract with.  Then the
+    int64 design block (``cc_per_input``, ``design_class``), the lane
+    block (``wt_ipt``, ``write_cycles``, ``seg_ids``) and
+    ``legal_rows``.  Cycles are int64 (exact on device); the objective
+    column replaces illegal and padded lanes with the finite sentinels.
+    Legality arrives per class — ``legal_rows`` (U, Ctot) and each
+    design's row ``design_class`` — and is gathered to (D, Ctot) here
+    on the device, so no per-design mask crosses from the host.
 
     The per-segment argmin runs as two ``segment_min`` passes over the
     lane axis instead of one ``jnp.argmin`` per static segment slice —
@@ -961,15 +1107,16 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
     if fn is None:
         import jax
         import jax.numpy as jnp
+        from jax import lax
 
         from .compilecache import enable_compilation_cache
         enable_compilation_cache()
 
         def kernel(s_wl, s_bl, s_logic, s_adc, s_tree, s_dac, s_write,
-                   m_w, m_i, m_o, m_p, wt_ipt, cc_per_input,
-                   write_cycles, legal_rows, design_class, seg_ids,
-                   seg_starts):
-            legal = legal_rows[design_class]
+                   m_w, m_i, m_o, m_p, i64, lanes, legal_rows):
+            dz = _Rows((i64, _DESIGN_I64_ROWS))
+            ln = _Rows((lanes, _LANE_ROWS))
+            legal = legal_rows[dz.vec("design_class")]
             total = s_wl + s_bl
             total = total + s_logic
             total = total + (s_adc + s_tree)
@@ -979,7 +1126,8 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
             mem_total = mem_total + m_o
             mem_total = mem_total + m_p
             total = total + mem_total
-            cycles = wt_ipt * cc_per_input + write_cycles
+            cycles = (ln.row("wt_ipt") * dz.col("cc_per_input")
+                      + ln.row("write_cycles"))
             if objective == "energy":
                 col = jnp.where(legal, total, SENTINEL_F64)
             elif objective == "latency":
@@ -987,6 +1135,7 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
             else:                                 # edp
                 col = jnp.where(legal, total * cycles, SENTINEL_F64)
             col_t = col.T                          # (Ctot, D), lanes lead
+            seg_ids = ln.vec("seg_ids")
             seg_min = jax.ops.segment_min(
                 col_t, seg_ids, num_segments=n_segments + 1,
                 indices_are_sorted=True)           # (S+1, D)
@@ -995,7 +1144,9 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
                 jnp.where(col_t == seg_min[seg_ids], lane, SENTINEL_I64),
                 seg_ids, num_segments=n_segments + 1,
                 indices_are_sorted=True)[:n_segments]  # (S, D) global lane
-            best = first - seg_starts[:, None]     # within-segment index
+            seg_starts = lax.slice_in_dim(ln.row("seg_starts"), 0,
+                                          n_segments, axis=1)  # (1, S)
+            best = first - seg_starts.T            # within-segment index
             d = jnp.arange(total.shape[0])[None, :]
             return best, total[d, first], cycles[d, first]
 
@@ -1004,30 +1155,34 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
     return fn
 
 
-def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
-                          has_os: bool, n_inputs, rows_used, cols_used,
-                          weight_loads, schedule_os, alpha,
-                          active_macros, weight_tiles,
-                          wt_ipt, write_cycles, cc_per_input,
-                          weight_bits, input_bits, output_bits,
-                          psum_bits, per_bit, per_bit_spill, off_chip,
-                          legal_rows, design_class):
+def reduce_objective_grid(designs, *, block: DesignBlock, objective: str,
+                          seg_bounds: tuple, has_os: bool, n_inputs,
+                          rows_used, cols_used, weight_loads, schedule_os,
+                          active_macros, weight_tiles, wt_ipt,
+                          write_cycles, weight_bits, input_bits,
+                          output_bits, psum_bits, off_chip, legal_rows,
+                          design_class):
     """The reduced sweep's whole device chain: stage-1 grid kernel +
     fold + scale + traffic + sentinel-masked per-segment argmin,
     returning ``(best_idx, total, cycles)`` as (S, D) jax arrays — S
     segment rows of (D,) winners, the only data that ever reaches the
     host.
 
-    Legality is ``mapping.NetworkGrid``'s compact pair: ``legal_rows``
-    (U, Ctot), one row per legality class, and ``design_class`` (D,).
+    Per-design arguments come from ``block`` (:func:`put_design_block`,
+    already on the device); the lane columns, each (Ctot,) or
+    broadcastable to it, are packed into one int64 lane block and put
+    once for both executables.  Legality is ``mapping.NetworkGrid``'s
+    compact pair: ``legal_rows`` (U, Ctot), one row per legality class,
+    handed over as it is, and ``design_class`` (D,), which must be the
+    block's.  So a bucket hands the device two host arrays.
 
     Unsharded (the default), stage-1 and the term products run as ONE
     fused executable (:func:`_reduced_fused_kernel` — no ten-grid
     materialization between stages); with ``REPRO_SWEEP_SHARDS`` > 1
-    the shard_map grid kernel is kept and the split
-    :func:`_reduce_terms_kernel` consumes its gathered outputs.  Both
-    routes end at the same argmin executable, and both are bitwise
-    identical to the host oracle.
+    the shard_map grid kernel is kept, on its own loose arguments, and
+    the split :func:`_reduce_terms_kernel` consumes its gathered
+    outputs.  Both routes end at the same argmin executable, and both
+    are bitwise identical to the host oracle.
 
     The dispatch is asynchronous (nothing is blocked on here); callers
     pipeline over it and attribute device time where they synchronize.
@@ -1039,9 +1194,8 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
     """
     import jax
 
-    (n_inputs, rows_used, cols_used, weight_loads,
-     sched_os) = _coerce_tile_args(n_inputs, rows_used, cols_used,
-                                   weight_loads, schedule_os)
+    if not np.array_equal(design_class, block.design_class):
+        raise ValueError("design_class differs from the design block's")
     n_classes, lanes = legal_rows.shape
     n_designs = len(design_class)
     _GRID_KERNEL_SHAPES.add(
@@ -1054,50 +1208,38 @@ def reduce_objective_grid(designs, *, objective: str, seg_bounds: tuple,
     widths = [s1 - s0 for s0, s1 in seg_bounds]
     seg_ids = np.repeat(np.arange(len(seg_bounds) + 1),
                         widths + [lanes - seg_bounds[-1][1]])
-    seg_starts = np.asarray([s0 for s0, _ in seg_bounds], dtype=np.int64)
+    seg_starts = np.zeros(lanes, dtype=np.int64)
+    seg_starts[:len(seg_bounds)] = [s0 for s0, _ in seg_bounds]
+    lane_block = _pack(_LANE_ROWS, dict(
+        n_inputs=n_inputs, rows_used=rows_used, cols_used=cols_used,
+        weight_loads=weight_loads, schedule_os=schedule_os,
+        active_macros=active_macros, weight_tiles=weight_tiles,
+        weight_bits=weight_bits, input_bits=input_bits,
+        output_bits=output_bits, psum_bits=psum_bits, off_chip=off_chip,
+        wt_ipt=wt_ipt, write_cycles=write_cycles, seg_ids=seg_ids,
+        seg_starts=seg_starts),
+        np.int64, lanes)
 
-    if lane_shards() > 1:
-        # sharded stage-1: keep the split chain so shard_map owns the
-        # grid kernel (counters advance inside _dispatch_grid_kernel)
-        parts, _ = _dispatch_grid_kernel(
-            designs, n_inputs, rows_used, cols_used, weight_loads,
-            sched_os, alpha, realize=False)
-        (e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write, _macs,
-         x_adc, x_dac) = parts
-        terms_k = _reduce_terms_kernel(has_os)
-        with jax.enable_x64(True):
-            terms = terms_k(e_wl, e_bl, e_logic, e_adc, e_tree,
-                            e_dac, e_write, x_adc, x_dac,
-                            active_macros, weight_tiles, weight_bits,
-                            input_bits, output_bits, psum_bits,
-                            per_bit, per_bit_spill, off_chip)
-            return argmin_k(*terms, wt_ipt, cc_per_input,
-                            write_cycles, legal_rows, design_class,
-                            seg_ids, seg_starts)
-
-    _C_KERNEL_CALLS.inc()
-    _GRID_KERNEL_SHAPES.add((n_inputs.shape, n_designs))
-    _G_KERNEL_SHAPES.set(len(_GRID_KERNEL_SHAPES))
-    fused_k = _reduced_fused_kernel(has_os)
-    cst = _design_constants(designs)
-    col = lambda a: a[:, None]                     # (D,) -> (D, 1)
     with jax.enable_x64(True):
-        terms = fused_k(
-            col(cst["analog"]), col(cst["mmux1"]), col(cst["rows"]),
-            col(cst["d1"]), col(cst["bw"]), col(cst["m"]),
-            col(cst["cc_bs"]), col(cst["e_wl_line"]),
-            col(cst["e_bl_word"]), col(cst["p_logic"]),
-            col(cst["adc_e"]), col(cst["denom_adc"]),
-            col(cst["cols_per_adc"]), col(cst["f_tree_a"]),
-            col(cst["f_tree_d"]), col(cst["p_tree"]),
-            col(cst["denom_occ"]), col(cst["dac_e"]),
-            col(cst["p_write"]),
-            n_inputs, rows_used, cols_used, weight_loads, sched_os,
-            alpha, active_macros, weight_tiles, weight_bits,
-            input_bits, output_bits, psum_bits,
-            per_bit, per_bit_spill, off_chip)
-        return argmin_k(*terms, wt_ipt, cc_per_input, write_cycles,
-                        legal_rows, design_class, seg_ids, seg_starts)
+        lane_block = jax.device_put(lane_block)
+        _C_H2D.inc(2)                       # the lane block, legal_rows
+        if lane_shards() > 1:
+            # sharded stage-1: keep the split chain so shard_map owns the
+            # grid kernel (counters advance inside _dispatch_grid_kernel)
+            parts, _ = _dispatch_grid_kernel(
+                designs, *_coerce_tile_args(n_inputs, rows_used, cols_used,
+                                            weight_loads, schedule_os),
+                block.alpha, realize=False)
+            _C_H2D.inc(len(_RAW_DESIGN_ARGS) + 5)   # + 5 tile columns
+            terms = _reduce_terms_kernel(has_os)(
+                *parts[:7], *parts[8:], block.f64, lane_block)
+        else:
+            _C_KERNEL_CALLS.inc()
+            _GRID_KERNEL_SHAPES.add(((lanes,), n_designs))
+            _G_KERNEL_SHAPES.set(len(_GRID_KERNEL_SHAPES))
+            terms = _reduced_fused_kernel(has_os)(block.f64, block.i64,
+                                                  lane_block)
+        return argmin_k(*terms, block.i64, lane_block, legal_rows)
 
 
 def _design_constants(designs) -> dict[str, np.ndarray]:

@@ -877,6 +877,13 @@ class MappingCostGrid:
             + self.psum_bits
 
 
+def _design_cc_per_input(designs) -> np.ndarray:
+    """Cycles per streamed input operand of each design, (D,) int64:
+    AIMC waits on its shared ADCs, DIMC on its row mux."""
+    return np.where(designs.analog, designs.cc_bs * designs.adc_share,
+                    designs.cc_bs * designs.m_mux)
+
+
 def evaluate_grid(layer: Layer, designs, grid: MappingGrid,
                   alpha: float | None = None) -> MappingCostGrid:
     """Vectorized :func:`evaluate` over the full (design x candidate)
@@ -921,11 +928,9 @@ def evaluate_grid(layer: Layer, designs, grid: MappingGrid,
                 * weight_tiles * inputs_per_tile)
     spatial_utilization = occupied / capacity
 
-    cc_per_input = np.where(designs.analog, designs.cc_bs * designs.adc_share,
-                            designs.cc_bs * designs.m_mux)
     write_cycles = rows_used * weight_tiles * weight_loads
-    cycles = (weight_tiles * inputs_per_tile * cc_per_input[:, None]
-              + write_cycles)
+    cycles = (weight_tiles * inputs_per_tile
+              * _design_cc_per_input(designs)[:, None] + write_cycles)
 
     # OS restreams the weight tensor once per reload pass — the same
     # closed form as weight_loads (schedule.weight_refetch == .weight_loads)
@@ -1162,9 +1167,8 @@ def evaluate_network_grid(net: NetworkGrid, designs,
                           alpha: float | None = None, *,
                           reduce: bool = False,
                           objective: str = "energy",
-                          per_bit=None, resident_bytes=None,
-                          buffer_bytes: int = 1 << 20,
-                          dram_fj_per_bit: float | None = None):
+                          design_block=None, resident_bytes=None,
+                          buffer_bytes: int = 1 << 20):
     """Vectorized :func:`evaluate` over a fused workload bucket: one
     ``energy.tile_energy_grid`` jit dispatch for every layer shape in
     the bucket.  Per-layer loop bounds enter as columns gathered
@@ -1176,15 +1180,19 @@ def evaluate_network_grid(net: NetworkGrid, designs,
     ``reduce=True`` switches to the device-side reduction path: instead
     of realizing full (D, Ctot) cost grids on the host, the energy-
     total chain (same scalar add association, FMA-fenced), the traffic
-    pricing (``per_bit`` / ``resident_bytes`` / ``buffer_bytes`` /
-    ``dram_fj_per_bit``, as :func:`~repro.core.memory.traffic_energy_grid`
+    pricing (``resident_bytes`` / ``buffer_bytes`` and the rates of
+    ``design_block``, as :func:`~repro.core.memory.traffic_energy_grid`
     would price them) and the sentinel-masked first-min argmin all run
     inside a second jit graph, and a :class:`ReducedNetworkCost` of
     per-segment (S, D) winners comes back — asynchronously, without
-    blocking.  Bitwise identical to reducing the default
+    blocking.  ``design_block`` (:func:`reduced_design_block`) carries
+    the per-design arguments, ``alpha`` included, already on the
+    device.  Bitwise identical to reducing the default
     :class:`NetworkCostGrid` on the host (property-pinned in
     ``tests/core/test_reduced_sweep.py``)."""
     from .energy import DEFAULT_ALPHA, tile_energy_grid
+    if reduce and alpha is not None:
+        raise ValueError("reduce=True takes alpha from design_block")
     alpha = DEFAULT_ALPHA if alpha is None else alpha
     batch = net.cand
     lay = net.lane_layer
@@ -1215,8 +1223,6 @@ def evaluate_network_grid(net: NetworkGrid, designs,
     cols_used = np.minimum(batch.k_cols, k_dim)
     active_macros = batch.k_macros * batch.dup_macros
 
-    cc_per_input = np.where(designs.analog, designs.cc_bs * designs.adc_share,
-                            designs.cc_bs * designs.m_mux)
     write_cycles = rows_used * weight_tiles * weight_loads
 
     # OS restreams the weight tensor once per reload pass — the same
@@ -1231,12 +1237,12 @@ def evaluate_network_grid(net: NetworkGrid, designs,
 
     if reduce:
         return _reduced_network_cost(
-            net, designs, alpha, objective, per_bit, resident_bytes,
-            buffer_bytes, dram_fj_per_bit,
+            net, designs, objective, design_block, resident_bytes,
+            buffer_bytes,
             inputs_per_tile=inputs_per_tile, rows_used=rows_used,
             cols_used=cols_used, weight_loads=weight_loads, is_os=is_os,
             active_macros=active_macros, weight_tiles=weight_tiles,
-            cc_per_input=cc_per_input, write_cycles=write_cycles,
+            write_cycles=write_cycles,
             weight_bits=weight_bits, input_bits=input_bits,
             output_bits=output_bits, psum_bits=psum_bits)
 
@@ -1257,8 +1263,8 @@ def evaluate_network_grid(net: NetworkGrid, designs,
         *(_scale2(getattr(e_tile, f.name))
           for f in dataclasses.fields(e_tile)))
 
-    cycles = (weight_tiles * inputs_per_tile * cc_per_input[:, None]
-              + write_cycles)
+    cycles = (weight_tiles * inputs_per_tile
+              * _design_cc_per_input(designs)[:, None] + write_cycles)
 
     return NetworkCostGrid(
         net=net, macro_energy=macro_energy, weight_tiles=weight_tiles,
@@ -1267,41 +1273,57 @@ def evaluate_network_grid(net: NetworkGrid, designs,
         output_bits=output_bits, psum_bits=psum_bits)
 
 
-def _reduced_network_cost(net, designs, alpha, objective, per_bit,
-                          resident_bytes, buffer_bytes, dram_fj_per_bit,
-                          *, inputs_per_tile, rows_used, cols_used,
+def reduced_design_block(designs, design_class, *, per_bit,
+                         alpha: float | None = None,
+                         dram_fj_per_bit: float | None = None):
+    """The per-design arguments of :func:`evaluate_network_grid`'s
+    ``reduce=True`` route, put on the device once for every bucket of a
+    sweep over ``designs`` whose legality classes are ``design_class``
+    (:func:`repro.core.energy.put_design_block`).  ``per_bit`` is the
+    SRAM traffic rate (scalar or (D,)), as
+    :func:`~repro.core.memory.traffic_energy_grid` takes it."""
+    from .energy import DEFAULT_ALPHA, put_design_block
+    from .memory import DRAM_FJ_PER_BIT, spill_pricing_columns
+    pb, pb_spill = spill_pricing_columns(
+        per_bit, DRAM_FJ_PER_BIT if dram_fj_per_bit is None
+        else dram_fj_per_bit)
+    return put_design_block(
+        designs, design_class,
+        alpha=DEFAULT_ALPHA if alpha is None else alpha,
+        per_bit=pb, per_bit_spill=pb_spill,
+        cc_per_input=_design_cc_per_input(designs))
+
+
+def _reduced_network_cost(net, designs, objective, design_block,
+                          resident_bytes, buffer_bytes, *,
+                          inputs_per_tile, rows_used, cols_used,
                           weight_loads, is_os, active_macros,
-                          weight_tiles, cc_per_input, write_cycles,
+                          weight_tiles, write_cycles,
                           weight_bits, input_bits, output_bits,
                           psum_bits) -> ReducedNetworkCost:
     """``reduce=True`` tail of :func:`evaluate_network_grid`: stage-1
     kernel dispatch kept on device, stage-2 reduction composed on top.
     All host work here is integer/bool prep (exact by construction)."""
     from .energy import reduce_objective_grid
-    from .memory import DRAM_FJ_PER_BIT, spill_pricing_columns
     if objective not in ("energy", "latency", "edp"):
         raise KeyError(objective)
-    if per_bit is None or resident_bytes is None:
+    if design_block is None or resident_bytes is None:
         raise ValueError(
-            "reduce=True requires per_bit and resident_bytes")
-    dram = DRAM_FJ_PER_BIT if dram_fj_per_bit is None else dram_fj_per_bit
-    pb, pb_spill, off_chip = spill_pricing_columns(
-        per_bit, resident_bytes, buffer_bytes=buffer_bytes,
-        dram_fj_per_bit=dram)
+            "reduce=True requires design_block and resident_bytes")
     seg_bounds = tuple((int(net.starts[s]), int(net.starts[s + 1]))
                       for s in range(len(net.layers)))
     best_idx, total, cycles = reduce_objective_grid(
-        designs, objective=objective, seg_bounds=seg_bounds,
-        has_os=bool(is_os.any()),
+        designs, block=design_block, objective=objective,
+        seg_bounds=seg_bounds, has_os=bool(is_os.any()),
         n_inputs=inputs_per_tile, rows_used=rows_used,
         cols_used=cols_used, weight_loads=weight_loads,
-        schedule_os=is_os, alpha=alpha, active_macros=active_macros,
+        schedule_os=is_os, active_macros=active_macros,
         weight_tiles=weight_tiles,
         wt_ipt=weight_tiles * inputs_per_tile,
-        write_cycles=write_cycles, cc_per_input=cc_per_input[:, None],
+        write_cycles=write_cycles,
         weight_bits=weight_bits, input_bits=input_bits,
         output_bits=output_bits, psum_bits=psum_bits,
-        per_bit=pb, per_bit_spill=pb_spill, off_chip=off_chip,
+        off_chip=np.asarray(resident_bytes) > buffer_bytes,
         legal_rows=net.legal_rows, design_class=net.design_class)
     nbytes = sum(a.dtype.itemsize * a.size
                  for a in (best_idx, total, cycles))

@@ -220,22 +220,19 @@ def traffic_energy_grid(per_bit: np.ndarray | float, costs,
 
 
 def spill_pricing_columns(per_bit: np.ndarray | float,
-                          resident_bytes: int | np.ndarray = 0,
-                          buffer_bytes: int = 1 << 20,
                           dram_fj_per_bit: float = DRAM_FJ_PER_BIT):
     """Host-side prep for pricing traffic *inside* a jit graph.
 
     Splits :func:`traffic_energy_grid`'s NumPy work into the pieces a
-    device reduction can consume: the buffered rate column, the spill
-    rate column (the same ``per_bit + dram`` sum the host ``np.where``
-    arms compute, done here once in NumPy so the device never re-adds
-    it), and the per-lane boolean spill decision.  Returns
-    ``(per_bit (D,1) f64, per_bit_spill (D,1) f64, off_chip (C,) or
-    (1,) bool)``.
+    device reduction can consume: the buffered rate column and the
+    spill rate column (the same ``per_bit + dram`` sum the host
+    ``np.where`` arms compute, done here once in NumPy so the device
+    never re-adds it).  The per-lane spill decision is the caller's
+    ``resident_bytes > buffer_bytes``.  Returns ``(per_bit, per_bit_spill)``,
+    each (D,) float64 ((1,) for a scalar rate).
     """
-    per_bit = np.atleast_1d(np.asarray(per_bit, dtype=np.float64))[:, None]
-    off_chip = np.atleast_1d(np.asarray(resident_bytes) > buffer_bytes)
-    return per_bit, per_bit + dram_fj_per_bit, off_chip
+    per_bit = np.atleast_1d(np.asarray(per_bit, dtype=np.float64))
+    return per_bit, per_bit + dram_fj_per_bit
 
 
 def traffic_terms(xp, per_bit, per_bit_spill, off_chip,

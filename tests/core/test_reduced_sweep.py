@@ -157,6 +157,72 @@ def test_class_legality_matches_host_oracle(legality_seen, faults,
             "fixture's survivor mask no longer moves a winner"
 
 
+@pytest.mark.parametrize("objective", ["energy", "latency", "edp"])
+@pytest.mark.parametrize("faults", [False, True], ids=["faults-off",
+                                                       "faults-on"])
+def test_packed_dispatch_hands_few_host_arrays(monkeypatch, faults,
+                                               objective):
+    """Argument packing: a sweep of at least three buckets puts the
+    per-design block on the device once, each bucket's dispatch hands
+    it at most four host arrays (``dse.h2d_arrays``, and the
+    ``h2d_arrays`` attr of every ``dse.price_bucket`` span), and
+    winners, totals and int64 cycles stay bitwise the depth-0 host
+    oracle's."""
+    from repro import obs
+    from repro.core import energy
+    from repro.faults import FaultSpec, survivor_mask
+    grid = designs.macro_grid(rows=(64, 256), cols=(64, 512), bw=(2, 8),
+                              adc_bits=(4, 8), m_mux=(1, 4), tech_nm=(28,),
+                              n_macros=(1, 4))
+    layers = [_layer(40, 24, 5, 7), _layer(96, 8, 1, 1, name="r-b"),
+              _layer(12, 60, 16, 7, name="r-c")]
+    survivors = (survivor_mask(FaultSpec(column_fail_rate=0.4,
+                                         macro_fail_rate=0.4, seed=3),
+                               grid) if faults else None)
+    puts = []
+    real_put = energy.put_design_block
+
+    def put_spy(*a, **kw):
+        puts.append(1)
+        return real_put(*a, **kw)
+
+    monkeypatch.setattr(energy, "put_design_block", put_spy)
+    monkeypatch.setattr(dse, "_BUCKET_ELEMS", 1)      # a bucket per shape
+    obs.set_trace_enabled(True)
+    obs.drain_spans()
+    try:
+        host, red = _price_both(layers, grid, objective, ("ws", "os"),
+                                survivors=survivors)
+        h2d = obs.snapshot("dse.")["dse.h2d_arrays"]
+        spans = [r["attrs"] for r in obs.drain_spans()
+                 if r["name"] == "dse.price_bucket"
+                 and r["attrs"].get("reduced")]
+    finally:
+        obs.set_trace_enabled(None)
+    _assert_slots_bitwise(host, red)
+    assert len(spans) >= 3
+    assert len(puts) == 1
+    per_bucket = [a["h2d_arrays"] for a in spans]
+    assert per_bucket[0] == 2 + 2                 # the block, then a bucket
+    assert all(n <= 4 for n in per_bucket)
+    assert per_bucket[1:] == [2] * (len(spans) - 1)
+    assert h2d == sum(per_bucket) == 2 + 2 * len(spans)
+
+
+def test_reduce_refuses_a_loose_alpha():
+    """``reduce=True`` prices with the design block's ``alpha``; one
+    passed beside it would be ignored, so it is refused."""
+    from repro.core.mapping import evaluate_network_grid, network_grid
+    grid = _grid((64,), (64,), (2,), (4,), (1,), (28,))
+    layer = _layer(7, 5, 5, 1)
+    sch = normalize(("ws",))
+    dse.cache_clear()
+    (net,) = network_grid([layer], grid, schedules=sch,
+                          grids=[dse._grid_for(layer, grid, sch)])
+    with pytest.raises(ValueError, match="design_block"):
+        evaluate_network_grid(net, grid, 0.3, reduce=True)
+
+
 def _grid_1620():
     """The 1620-design sweep grid (``benchmarks.design_sweep.make_grid``):
     15 (d1, rows, n_macros) legality classes."""
@@ -173,8 +239,9 @@ def test_sweep_hands_class_legality_to_device(legality_seen, monkeypatch,
     """Mechanism pin on the 1620-design grid: a fault-free sweep never
     expands per-design legality on the host, every bucket hands the
     device its 15 class rows, and ``dse.legal_bytes`` counts exactly
-    U * Ctot + 4 * D per bucket (the ``dse.price_bucket`` span names U).
-    With faults on every design is its own class, U = D."""
+    U * Ctot per bucket (the ``dse.price_bucket`` span names U) plus
+    the designs' int64 class row, put once per sweep.  With faults on
+    every design is its own class, U = D."""
     from repro import obs
     from repro.core import mapping
     from repro.faults import FaultSpec
@@ -202,12 +269,12 @@ def test_sweep_hands_class_legality_to_device(legality_seen, monkeypatch,
         obs.set_trace_enabled(None)
     n_classes = len(grid) if faults else 15
     assert len(legality_seen) >= 2
-    expect = 0
+    expect = 8 * len(grid)
     for legal_rows, design_class in legality_seen:
         assert legal_rows.shape[0] == n_classes
         assert design_class.shape == (len(grid),)
         assert design_class.dtype == np.int32
-        expect += n_classes * legal_rows.shape[1] + 4 * len(grid)
+        expect += n_classes * legal_rows.shape[1]
     assert legal_bytes == expect
     assert [r["attrs"]["legal_rows"] for r in spans] == \
         [n_classes] * len(legality_seen)

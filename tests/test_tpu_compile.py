@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import designs, energy
+from repro.core import energy
 from repro.kernels.aimc_mvm import aimc_mvm
 from repro.kernels.dimc_mvm import dimc_mvm
 
@@ -58,37 +58,36 @@ def test_mvm_kernel_compiles_to_mosaic(one_chip, kernel, m, k, n):
 
 
 D, C, S, U = 8, 256, 4, 3
+N_F, N_I, N_L = (len(energy._DESIGN_F64_ROWS), len(energy._DESIGN_I64_ROWS),
+                 len(energy._LANE_ROWS))
+
+
+def _blocks(sharding):
+    return (_spec((N_F, D), np.float64, sharding),
+            _spec((N_I, D), np.int64, sharding),
+            _spec((N_L, C), np.int64, sharding))
 
 
 def test_raw_grid_kernel_compiles_f64(one_chip):
-    cst = energy._design_constants(designs.macro_grid(
-        rows=(64, 256, 1024), cols=(128, 512), adc_bits=(4, 8),
-        dac_bits=(1, 4), m_mux=(1, 16), tech_nm=(22,)))
+    """The raw grid kernel, fused with the term products, on the packed
+    (float design block, int design block, lane block) arguments."""
     with jax.enable_x64(True):
-        cols = [_spec((D, 1), np.asarray(v).dtype, one_chip)
-                for v in cst.values()]
-        tiles = [_spec((C,), np.int64, one_chip)] * 4 + [
-            _spec((C,), np.bool_, one_chip)]
-        alpha = _spec((), np.float64, one_chip)
-        compiled = jax.jit(energy._raw_grid_kernel()).lower(
-            *cols, *tiles, alpha).compile()
-    assert len(cols) == 19
+        compiled = energy._reduced_fused_kernel(True).lower(
+            *_blocks(one_chip)).compile()
     assert "f64" in compiled.as_text()
+    terms = compiled.out_info
+    assert len(terms) == 11
+    assert all(t.shape == (D, C) and t.dtype == np.float64 for t in terms)
 
 
 def test_reduce_argmin_kernel_compiles_f64(one_chip):
     with jax.enable_x64(True):
         terms = [_spec((D, C), np.float64, one_chip)] * 11
-        wt_ipt = _spec((C,), np.int64, one_chip)
-        cc_per_input = _spec((D, 1), np.int64, one_chip)
-        write_cycles = _spec((D, C), np.int64, one_chip)
+        _, i64, lanes = _blocks(one_chip)
         legal_rows = _spec((U, C), np.bool_, one_chip)
-        design_class = _spec((D,), np.int32, one_chip)
-        seg_ids = _spec((C,), np.int64, one_chip)
-        seg_starts = _spec((S,), np.int64, one_chip)
         compiled = energy._reduce_argmin_kernel("energy", S).lower(
-            *terms, wt_ipt, cc_per_input, write_cycles, legal_rows,
-            design_class, seg_ids, seg_starts).compile()
+            *terms, i64, lanes, legal_rows).compile()
+    assert "f64" in compiled.as_text()
     best, total, cycles = compiled.out_info
     assert best.shape == total.shape == cycles.shape == (S, D)
     assert total.dtype == np.float64 and cycles.dtype == np.int64
