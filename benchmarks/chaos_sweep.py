@@ -32,11 +32,7 @@ Env knobs
     ``FaultSpec.from_env()`` — the seed knob pins every survivor draw
     and the node-failure trace; the rate knob (when set) *prepends* its
     value to the swept rate axis so a CI lane can pin one extra
-    degraded point without editing the benchmark.  Composes with the
-    sweep-engine knobs: ``REPRO_SWEEP_PIPELINE`` / ``REPRO_SWEEP_SHARDS``
-    change only *how* the degraded lattice is priced (reduced/pipelined
-    vs host oracle, sharded vs single-lane) — results are bitwise
-    identical, faults or not.
+    degraded point without editing the benchmark.
 ``REPRO_TRACE`` / ``REPRO_TRACE_DIR``
     Span tracing; the run exports ``chaos_sweep_trace.json`` +
     ``chaos_sweep_telemetry.jsonl`` and records their paths under

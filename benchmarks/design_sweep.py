@@ -31,43 +31,17 @@ Env knobs
     ``JAX_ENABLE_COMPILATION_CACHE=false`` disables it).  With a warm
     cache, "cold" sweeps skip their XLA compiles entirely — across
     benchmark runs and CI jobs.
-``REPRO_SWEEP_SHARDS``
-    Lane-axis shard count for the fused grid kernel (``auto`` = one
-    shard per jax device, an integer is clamped to the device count,
-    default 1).  The padded candidate-lane axis is partitioned over a
-    1-D device mesh via ``shard_map``; output is bitwise identical to
-    the single-device path.  E.g. on a multi-core host:
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-    ``REPRO_SWEEP_SHARDS=auto python -m benchmarks.design_sweep
-    --networks``.
-``REPRO_SWEEP_PIPELINE``
-    Bucket-pipeline depth of the reduced sweep engine (``auto``/unset
-    = 2, an integer >= 1 is a depth, ``0``/``off``/``false`` falls back
-    to the full-grid host oracle).  With a depth >= 1 each bucket's
-    objective total and per-segment argmin run device-side
-    (``mapping.evaluate_network_grid(reduce=True)``) and only (S, D)
-    winners cross to the host, while up to N bucket dispatches stay in
-    flight ahead of finalization and the next lattice builds on a
-    background thread.  Results are bitwise identical to the host
-    oracle either way.  Composes with ``REPRO_SWEEP_SHARDS``: when the
-    lane axis is sharded the reduced path keeps the ``shard_map`` grid
-    kernel and only the fold/scale/argmin chain changes, so both knobs
-    can be on at once (shards split each bucket across devices,
-    the pipeline overlaps consecutive buckets).
 ``REPRO_FAULT_RATE`` / ``REPRO_FAULT_SEED``
     Degraded-mode sweep: a non-zero rate builds a seeded
     ``repro.faults.FaultSpec`` (rate applied to both stuck column
     groups and macro dropout, seed pinning the survivor draw) and the
     whole sweep prices only the mappings that survive — the survivor
     mask ANDs into the lattice's ``legal`` plane, so no cost kernel,
-    jit graph or compile count changes.  Composes freely with
-    ``REPRO_SWEEP_PIPELINE`` (the reduced engine folds the degraded
-    mask device-side, the host oracle applies it in ``np.where`` —
-    bitwise identical) and with ``REPRO_SWEEP_SHARDS`` (the mask rides
-    the lane axis through ``shard_map`` unchanged).  The artifact
-    records the active rate/seed under ``"faults"``; unset/0 is
-    bit-for-bit the pristine sweep.  The dedicated fault-rate axis
-    sweep lives in ``benchmarks.chaos_sweep``.
+    jit graph or compile count changes (the sweep folds the degraded
+    mask in on the device).  The artifact records the active
+    rate/seed under ``"faults"``; unset/0 is bit-for-bit the pristine
+    sweep.  The dedicated fault-rate axis sweep lives in
+    ``benchmarks.chaos_sweep``.
 ``REPRO_TRACE``
     Turn on span tracing (``repro.obs``).  The fused sweep then records
     nested wall-time spans — lattice builds, per-bucket jit dispatch
@@ -274,8 +248,6 @@ def run_networks(smoke: bool = False, dataflows: bool = False,
         "lattice_build_s": t_lattice,
         "kernel_calls_cold": kernel_cold["calls"],
         "kernel_distinct_shapes_cold": kernel_cold["distinct_shapes"],
-        "kernel_sharded_calls_cold": kernel_cold["sharded_calls"],
-        "lane_shards": energy.lane_shards(),
         "pipeline_depth": int(pipe_cold.get("dse.pipeline.depth", 0)),
         "pipeline_occupancy": float(
             pipe_cold.get("dse.pipeline.occupancy", 0.0)),

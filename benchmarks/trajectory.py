@@ -32,8 +32,7 @@ from .common import write_json_atomic
 #: bulky detail stays in the per-commit artifact upload)
 _FIELDS = ("benchmark", "smoke", "designs", "networks", "schedules",
            "cold_s", "warm_s", "lattice_build_s", "kernel_calls_cold",
-           "kernel_distinct_shapes_cold", "kernel_sharded_calls_cold",
-           "lane_shards", "lattice_slots", "padding_waste",
+           "kernel_distinct_shapes_cold", "lattice_slots", "padding_waste",
            # reduced-engine headline (device->host traffic + pipeline)
            "transfer_bytes_cold", "pipeline_depth", "pipeline_occupancy",
            # serving_sweep headline fields

@@ -2,7 +2,7 @@
 """Smoke run of the program's main paths on a TPU, in one process.
 
     python chip_smoke.py              # one chip: sweep, kernels, serve
-    python chip_smoke.py --chips 4    # four chips: mesh serve + sharded sweep
+    python chip_smoke.py --chips 4    # four chips: mesh serve
 
 One chip:
 
@@ -17,8 +17,7 @@ One chip:
   ``LM.forward``.
 
 Four chips (``--chips 4``): glm4-9b at full width on the (1, 4) mesh
-``serve.main`` builds, and the lane-sharded sweep compared bitwise with
-the unsharded one.
+``serve.main`` builds.
 
 Weights and inputs come from ``--seed``.  Timings printed here are
 single-run smoke figures, not benchmarks.  Exits non-zero, without the
@@ -281,37 +280,6 @@ def phase_mesh_serve(arch: str = "glm4-9b", smoke: bool = False,
             "prefill_vs_forward_rel_dev": rel}
 
 
-def phase_sharded_sweep(grid=None, networks=None, shards: int = 4) -> dict:
-    """The lane-sharded sweep must equal the unsharded one bitwise, and
-    must really have gone through the sharded kernel."""
-    import numpy as np
-
-    from benchmarks.design_sweep import make_grid
-    from repro.core import dse, energy
-
-    grid = make_grid() if grid is None else grid
-    networks = tinymlperf_networks() if networks is None else networks
-    runs = {}
-    for n in (shards, 1):
-        energy.set_lane_shards(n)
-        energy.grid_kernel_reset()
-        runs[n] = (dse.sweep_networks(networks, grid,
-                                      schedules=("ws", "os")),
-                   energy.grid_kernel_info())
-    energy.set_lane_shards(None)
-    (sharded, info), (single, _) = runs[shards], runs[1]
-    check(info["sharded_calls"] > 0,
-          f"sharded sweep: no dispatch went through shard_map ({info})")
-    for a, b in zip(sharded, single):
-        check(np.array_equal(a.energy_fj, b.energy_fj)
-              and np.array_equal(a.cycles, b.cycles),
-              f"sharded sweep: {a.network} totals differ from unsharded")
-        for sa, sb in zip(a._shapes, b._shapes):
-            check(np.array_equal(sa[2], sb[2]),
-                  f"sharded sweep: {a.network} winners differ")
-    return {"shards": shards, "grid_kernel_info": info, "bitwise": True}
-
-
 # --------------------------------------------------------------------------- #
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -346,8 +314,7 @@ def main(argv=None) -> int:
                   ("kernels", lambda: phase_kernels(seed=args.seed)),
                   ("serve", lambda: phase_serve(seed=args.seed)))
     else:
-        phases = (("sharded_sweep", phase_sharded_sweep),
-                  ("mesh_serve", lambda: phase_mesh_serve(seed=args.seed)))
+        phases = (("mesh_serve", lambda: phase_mesh_serve(seed=args.seed)),)
     for name, fn in phases:
         t0 = time.perf_counter()
         try:

@@ -42,8 +42,8 @@ Design-space sweeps
 takes a whole ``designs.MacroBatch`` (typically from
 ``designs.macro_grid``) and prices every (design x mapping-candidate)
 pair of every layer in one fused pass (``mapping.network_grid`` /
-``mapping.evaluate_network_grid`` on top of the jitted
-``energy.tile_energy_grid``).  Per design it keeps the per-layer
+``mapping.reduce_network_grid``: two jitted executables per bucket,
+only the winners brought back).  Per design it keeps the per-layer
 argmin under the chosen objective — the same winner, bitwise, that
 running ``best_mapping`` per design would keep — and returns a
 :class:`SweepResult`:
@@ -105,8 +105,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import math
-import os
 import queue
 import threading
 import time
@@ -339,10 +337,10 @@ _C_LAT_PAD_LANES = obs.counter("dse.lattice.pad_lanes")
 #: to amortize.
 _T_BUCKET_FIRST = obs.timer("dse.bucket.first_call")
 _T_BUCKET_WARM = obs.timer("dse.bucket.warm")
-#: reduced-path telemetry: device→host volume actually realized by the
-#: pricing loop (the host path ships the full component grids, the
-#: reduced path only the per-segment winners) and the pipeline's shape
-#: for the last sweep.
+#: device→host volume actually realized by the pricing loop (the sweep
+#: ships only the per-segment winners, the tests' full-grid reference
+#: the full component grids), and the pipeline's shape for the last
+#: sweep.
 _C_TRANSFER = obs.counter("dse.transfer_bytes")
 #: legality bytes handed to the device by the reduced engine: per bucket
 #: the class rows, per sweep each design's class index (an int64 row of
@@ -356,45 +354,9 @@ _C_PIPE_BUCKETS = obs.counter("dse.pipeline.buckets")
 _G_PIPE_DEPTH = obs.gauge("dse.pipeline.depth")
 _G_PIPE_OCC = obs.gauge("dse.pipeline.occupancy")
 
-#: in-flight depth of the reduced+pipelined bucket loop.  ``None`` = not
-#: yet resolved; resolved lazily from ``REPRO_SWEEP_PIPELINE`` so
-#: importing the module never reads the environment eagerly.  ``0``
-#: selects the legacy full-grid host path (the bitwise oracle).
-_SWEEP_PIPELINE: dict = {"depth": None}
-_PIPELINE_OFF = {"", "0", "off", "false", "none", "disabled"}
-_PIPELINE_AUTO_DEPTH = 2
-
-
-def sweep_pipeline() -> int:
-    """Active reduced-pipeline depth for the fused sweep's bucket loop.
-
-    ``REPRO_SWEEP_PIPELINE`` semantics: ``auto`` (the default — the
-    reduced path is on by default, it is bitwise identical to the host
-    oracle) resolves to depth 2; ``0``/``off``/``false``/``none``/
-    ``disabled`` select the full-grid host path; an integer ``N >= 1``
-    pins the in-flight bucket depth; anything unparsable falls back to
-    ``auto``.
-    """
-    d = _SWEEP_PIPELINE["depth"]
-    if d is None:
-        spec = os.environ.get("REPRO_SWEEP_PIPELINE", "auto").strip().lower()
-        if spec in _PIPELINE_OFF:
-            d = 0
-        elif spec == "auto":
-            d = _PIPELINE_AUTO_DEPTH
-        else:
-            try:
-                d = max(1, int(spec))
-            except ValueError:
-                d = _PIPELINE_AUTO_DEPTH
-        _SWEEP_PIPELINE["depth"] = d
-    return d
-
-
-def set_sweep_pipeline(depth: int | None) -> None:
-    """Override the pipeline depth (``None`` re-reads the env on the
-    next call; ``0`` forces the host-oracle path)."""
-    _SWEEP_PIPELINE["depth"] = None if depth is None else max(0, int(depth))
+#: buckets in flight in the sweep's bucket loop: bucket *i*'s device
+#: work and finalization overlap bucket *i+1*'s build and dispatch
+_PIPELINE_DEPTH = 2
 
 
 def _shape_key(layer: Layer) -> tuple:
@@ -655,9 +617,9 @@ def _with_survivors(net, survivors: SurvivorMask | None):
     class per design: ``legal_rows = legal & fault_legal(...)`` (D,
     Ctot) and ``design_class = arange(D)``.  Grids in ``_LATTICE_CACHE``
     stay fault-free (masks are per-sweep, caches are per-shape) and
-    every downstream path (host ``np.where`` sentinels, the reduced
-    kernel's class gather, sharded lanes) sees the degraded legality
-    through the fields they already consume.  The all-ones mapping
+    both consumers (the reduced kernel's class gather, the reference's
+    host ``np.where`` sentinels) see the degraded legality through the
+    fields they already consume.  The all-ones mapping
     survives any clamp-to->=1 mask, so every (layer, design) segment
     keeps >= 1 legal lane and sentinels still never win the argmin.
     """
@@ -673,8 +635,10 @@ def _price_buckets(buckets, designs: MacroBatch, objective: str,
                    alpha: float | None, per_bit, buffer_bytes: int,
                    dram: float,
                    survivors: SurvivorMask | None = None) -> list[tuple]:
-    """Price fused workload buckets; per shape slot return
-    ``(grid, best_idx (D,), total (D,), cycles (D,))``.
+    """Price fused workload buckets on the host, from full (D, Ctot)
+    cost grids; per shape slot return
+    ``(grid, best_idx (D,), total (D,), cycles (D,))``.  The body of
+    :func:`_price_shapes_reference`.
 
     Each bucket is one ``mapping.evaluate_network_grid`` pass — a
     single jit dispatch for every (layer, design, candidate) triple it
@@ -691,7 +655,7 @@ def _price_buckets(buckets, designs: MacroBatch, objective: str,
     trace+compile time (or a persistent compile-cache deserialize; the
     span's ``persistent_cache`` attr records whether one was active to
     attribute suspiciously-fast first calls).  Warm buckets are pure
-    execute: this host path realizes the kernel's outputs as NumPy
+    execute: this reference realizes the kernel's outputs as NumPy
     arrays inside the span, so its wall includes the device's time and
     the copy.  The split is what "compile vs execute" means per bucket.
     """
@@ -717,8 +681,8 @@ def _price_buckets(buckets, designs: MacroBatch, objective: str,
                    first_call=bool(new_shapes),
                    persistent_cache=persistent_cache_dir() is not None)
             # device→host accounting: this path realizes the kernel's
-            # natural unsharded output face — nine (D, Ctot) f64 grids
-            # plus the (Ctot,) macs row
+            # whole output face — nine (D, Ctot) f64 grids plus the
+            # (Ctot,) macs row
             _C_TRANSFER.inc((9 * net.n_designs + 1) * len(net) * 8)
             resident = np.asarray(
                 [_resident_bytes_cached(l) for l in net.layers],
@@ -762,45 +726,30 @@ def _price_buckets(buckets, designs: MacroBatch, objective: str,
     return out
 
 
-def _bucket_pad_quantum() -> int:
-    """Shard-aware lane pad quantum: with a sharded lane axis every
-    bucket's padded width must divide over the mesh; lcm keeps the
-    quantum a PAD_QUANTUM multiple so unsharded runs see the exact same
-    bucket shapes as before."""
-    from .energy import lane_shards
-    from .mapping import PAD_QUANTUM
-    shards = lane_shards()
-    return PAD_QUANTUM if shards <= 1 else math.lcm(PAD_QUANTUM, shards)
+def _price_shapes_reference(shape_layers: Sequence[Layer],
+                            designs: MacroBatch, objective: str,
+                            alpha: float | None, per_bit,
+                            buffer_bytes: int, dram: float, scheds,
+                            survivors: SurvivorMask | None = None
+                            ) -> list[tuple]:
+    """Full-grid host pricing of the same shapes as :func:`_price_shapes`,
+    with the same return: the tests' bitwise reference for the sweep.
 
-
-def _price_shapes(shape_layers: Sequence[Layer], designs: MacroBatch,
-                  objective: str, alpha: float | None, per_bit,
-                  buffer_bytes: int, dram: float, scheds,
-                  survivors: SurvivorMask | None = None) -> list[tuple]:
-    """Build (cached) per-shape lattices, fuse them into buckets, and
-    price everything; one entry per distinct shape, input order.
-
-    Routed by :func:`sweep_pipeline`: depth ``0`` runs the legacy
-    full-grid host path below (the bitwise oracle); any depth ``>= 1``
-    runs the reduced+pipelined engine — identical results, winners-only
-    transfers, overlapped build/dispatch/finalize stages.
+    Every bucket's nine (D, Ctot) float64 cost grids are realized on the
+    host and reduced in NumPy (:func:`_price_buckets`).  Nothing in the
+    program calls it; tests that call it by name, or put it in
+    ``_price_shapes``' place, hold the sweep's winners, totals and cycles
+    to it bit for bit.
     """
-    from .mapping import network_grid
-    depth = sweep_pipeline()
-    if depth > 0:
-        return _price_shapes_pipelined(shape_layers, designs, objective,
-                                       alpha, per_bit, buffer_bytes,
-                                       dram, scheds, depth,
-                                       survivors=survivors)
+    from .mapping import PAD_QUANTUM, network_grid
     grids = [_grid_for(l, designs, scheds) for l in shape_layers]
     max_lanes = max((len(g) for g in grids),
                     default=1)
     max_lanes = max(max_lanes, _BUCKET_ELEMS // max(1, len(designs)))
-    pad_q = _bucket_pad_quantum()
     with obs.span("dse.network_grid_build", shapes=len(shape_layers),
                   designs=len(designs)) as sp:
         buckets = network_grid(shape_layers, designs, schedules=scheds,
-                               grids=grids, pad_quantum=pad_q,
+                               grids=grids, pad_quantum=PAD_QUANTUM,
                                max_lanes=max_lanes)
         sp.set(buckets=len(buckets),
                lanes=sum(len(b) for b in buckets))
@@ -808,25 +757,25 @@ def _price_shapes(shape_layers: Sequence[Layer], designs: MacroBatch,
                           buffer_bytes, dram, survivors=survivors)
 
 
-def _bucket_builder(shape_layers, designs, scheds, pad_q, out_q,
+def _bucket_builder(shape_layers, designs, scheds, out_q,
                     stop: threading.Event, parent_span: int):
-    """Builder-thread body of the pipelined engine: greedily assemble
-    lane buckets (same ``_BUCKET_ELEMS`` byte budget as the host path;
-    shapes never split) and fuse each into one :class:`NetworkGrid`,
-    feeding the bounded queue so lattice construction — pure NumPy,
-    which runs concurrently because XLA execution on the consumer side
-    releases the GIL — overlaps bucket pricing.
+    """Builder-thread body of :func:`_price_shapes`: greedily assemble
+    lane buckets (``_BUCKET_ELEMS`` budget; shapes never split) and fuse
+    each into one :class:`NetworkGrid`, feeding the bounded queue so
+    lattice construction — pure NumPy, which runs concurrently because
+    XLA execution on the consumer side releases the GIL — overlaps
+    bucket pricing.
 
-    One accepted divergence from the host path's bucketing: the budget
-    is not raised to the largest single lattice, so when one shape
-    alone exceeds the byte budget the *boundaries* between buckets may
-    differ.  Results are bitwise identical either way — every shape
-    segment is priced independently.
+    One accepted divergence from :func:`_price_shapes_reference`'s
+    bucketing: the budget is not raised to the largest single lattice,
+    so when one shape alone exceeds the budget the *boundaries* between
+    buckets may differ.  Results are bitwise identical either way —
+    every shape segment is priced independently.
 
     ``parent_span`` is the starter's open span (the sweep's root), which
     this thread's spans name as their parent.
     """
-    from .mapping import network_grid
+    from .mapping import PAD_QUANTUM, network_grid
 
     obs.adopt_parent(parent_span)
 
@@ -853,7 +802,7 @@ def _bucket_builder(shape_layers, designs, scheds, pad_q, out_q,
                           designs=len(designs)) as sp:
                 (net,) = network_grid(
                     [shape_layers[s] for s in members], designs,
-                    schedules=scheds, grids=grids, pad_quantum=pad_q,
+                    schedules=scheds, grids=grids, pad_quantum=PAD_QUANTUM,
                     max_lanes=None)
                 sp.set(buckets=1, lanes=len(net))
             ok = put(("bucket", tuple(members), net))
@@ -906,23 +855,23 @@ def _next_bucket(out_q: queue.Queue, builder: threading.Thread) -> tuple:
                     "sweep bucket builder died without a result")
 
 
-def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
-                            objective: str, alpha: float | None,
-                            per_bit, buffer_bytes: int, dram: float,
-                            scheds, depth: int,
-                            survivors: SurvivorMask | None = None
-                            ) -> list[tuple]:
-    """Reduced + pipelined pricing engine (``REPRO_SWEEP_PIPELINE``).
+def _price_shapes(shape_layers: Sequence[Layer], designs: MacroBatch,
+                  objective: str, alpha: float | None, per_bit,
+                  buffer_bytes: int, dram: float, scheds,
+                  survivors: SurvivorMask | None = None) -> list[tuple]:
+    """Build (cached) per-shape lattices, fuse them into buckets, and
+    price everything; one entry per distinct shape, input order.
 
     Three overlapped stages: a builder thread assembles lattice buckets
     (:func:`_bucket_builder`), the main thread dispatches each bucket's
-    reduced evaluation asynchronously (stage-1 grid kernel + stage-2
-    device reduction, ``mapping.evaluate_network_grid(reduce=True)``)
-    and keeps up to ``depth`` buckets in flight before finalizing the
+    reduced evaluation asynchronously (``mapping.reduce_network_grid``:
+    the fused grid-and-terms executable, then the argmin one) and keeps
+    up to ``_PIPELINE_DEPTH`` buckets in flight before finalizing the
     oldest — so bucket *i*'s device execution and host finalization
     overlap bucket *i+1*'s build and dispatch.  Only the per-segment
     winners (``best_idx`` / ``total`` / ``cycles``, 3·S·D values) ever
-    cross the device→host boundary.
+    cross the device→host boundary.  Bitwise identical to
+    :func:`_price_shapes_reference`.
 
     Telemetry: the main thread's time is split into consecutive spans —
     ``dse.await_bucket`` (waiting on the builder's queue;
@@ -947,16 +896,17 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
     """
     from .compilecache import persistent_cache_dir
     from .energy import grid_kernel_info
-    from .mapping import evaluate_network_grid, reduced_design_block
+    from .mapping import reduce_network_grid, reduced_design_block
 
+    depth = _PIPELINE_DEPTH
     _G_PIPE_DEPTH.set(depth)
     out: list[tuple | None] = [None] * len(shape_layers)
     out_q: queue.Queue = queue.Queue(maxsize=max(2, depth + 1))
     stop = threading.Event()
     builder = threading.Thread(
         target=_bucket_builder,
-        args=(shape_layers, designs, scheds, _bucket_pad_quantum(),
-              out_q, stop, obs.current_span_id()),
+        args=(shape_layers, designs, scheds, out_q, stop,
+              obs.current_span_id()),
         name="repro-sweep-builder", daemon=True)
 
     pending: collections.deque = collections.deque()
@@ -996,10 +946,9 @@ def _price_shapes_pipelined(shape_layers, designs: MacroBatch,
                 resident = np.asarray(
                     [_resident_bytes_cached(l) for l in net.layers],
                     dtype=np.int64)[net.lane_layer]
-                red = evaluate_network_grid(
-                    net, designs, reduce=True, objective=objective,
-                    design_block=block, resident_bytes=resident,
-                    buffer_bytes=buffer_bytes)
+                red = reduce_network_grid(
+                    net, objective=objective, design_block=block,
+                    resident_bytes=resident, buffer_bytes=buffer_bytes)
                 sp.lap("dispatch")
                 _C_LEGAL_BYTES.inc(net.legal_rows.nbytes)
                 new_shapes = (grid_kernel_info()["distinct_shapes"]

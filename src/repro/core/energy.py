@@ -68,7 +68,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
 
 import numpy as np
 
@@ -467,43 +466,8 @@ def tile_energy_batch(macro: IMCMacro,
 # evaluated on the returned NumPy arrays in the scalar association.)
 
 _GRID_KERNEL = None          # lazily-built jax.jit closure
-_RAW_GRID_KERNEL = None      # the unjitted kernel fn (shared with shard_map)
-
-#: lane-axis shard count for the fused grid kernel.  ``None`` = not yet
-#: resolved; resolved lazily from ``REPRO_SWEEP_SHARDS`` ("auto" = all
-#: jax devices, an integer = min(n, devices), default/invalid = 1) so
-#: importing the module never touches the jax runtime.
-_LANE_SHARDS: dict = {"n": None}
-#: (shards, tile_rank) -> jitted shard_map closure
-_SHARDED_GRID_KERNELS: dict = {}
-
-
-def lane_shards() -> int:
-    """Active lane-axis shard count for :func:`tile_energy_grid`."""
-    n = _LANE_SHARDS["n"]
-    if n is None:
-        spec = os.environ.get("REPRO_SWEEP_SHARDS", "1").strip().lower()
-        import jax
-
-        avail = jax.device_count()
-        if spec == "auto":
-            n = avail
-        else:
-            try:
-                n = int(spec)
-            except ValueError:
-                n = 1
-            n = min(n, avail)
-        n = max(1, n)
-        _LANE_SHARDS["n"] = n
-    return n
-
-
-def set_lane_shards(n: int | None) -> None:
-    """Override the lane shard count (``None`` re-reads the env on the
-    next call).  Values above ``jax.device_count()`` are clamped lazily
-    by the sharded dispatch, invalid counts fall back to unsharded."""
-    _LANE_SHARDS["n"] = None if n is None else max(1, int(n))
+_RAW_GRID_KERNEL = None      # the unjitted kernel fn (shared with the
+                             # reduced route's fused kernel)
 
 #: dispatch/compile bookkeeping for the fused grid kernel.  jax caches
 #: compiled executables per argument-shape signature, so the number of
@@ -514,20 +478,16 @@ def set_lane_shards(n: int | None) -> None:
 #: only the shape *set* stays module-local (the registry holds its
 #: cardinality as a gauge).
 _C_KERNEL_CALLS = obs.counter("energy.kernel.calls")
-_C_KERNEL_SHARDED = obs.counter("energy.kernel.sharded_calls")
 _G_KERNEL_SHAPES = obs.gauge("energy.kernel.distinct_shapes")
 _GRID_KERNEL_SHAPES: set[tuple] = set()
 
 
 def grid_kernel_info() -> dict[str, int]:
-    """Fused-kernel dispatch stats: total ``calls``,
-    ``distinct_shapes`` (compile-count proxy) and ``sharded_calls``
-    (dispatches that went through the shard_map path) since the last
-    reset.  Compatibility view over the registry's ``energy.kernel.*``
-    metrics — the historical return shape is unchanged."""
+    """Fused-kernel dispatch stats: total ``calls`` and
+    ``distinct_shapes`` (compile-count proxy) since the last reset.  A
+    view over the registry's ``energy.kernel.*`` metrics."""
     return {"calls": _C_KERNEL_CALLS.value,
-            "distinct_shapes": len(_GRID_KERNEL_SHAPES),
-            "sharded_calls": _C_KERNEL_SHARDED.value}
+            "distinct_shapes": len(_GRID_KERNEL_SHAPES)}
 
 
 def grid_kernel_reset() -> None:
@@ -612,126 +572,6 @@ def _grid_kernel():
     return _GRID_KERNEL
 
 
-def _sharded_grid_kernel(shards: int, tile_rank: int):
-    """shard_map execution path: the lane (candidate) axis of the fused
-    grid kernel is partitioned over ``shards`` devices of a 1-D mesh,
-    design columns are replicated.  The kernel is purely elementwise,
-    so each device computes a disjoint lane slab with the identical
-    float ops the unsharded jit runs — the gathered result is bitwise
-    equal (pinned by ``tests/core/test_sharded_sweep.py``).
-
-    ``tile_rank`` is the rank the tile arguments reach the kernel with
-    (1 for (C,) candidate rows, 3 for (L, 1, C) layer stacks).  All ten
-    outputs are broadcast to the common face *inside* the mapped fn so
-    the out_specs stay uniform lane-last.
-    """
-    key = (shards, tile_rank)
-    fn = _SHARDED_GRID_KERNELS.get(key)
-    if fn is None:
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import Mesh, PartitionSpec as P
-
-        from .compilecache import enable_compilation_cache
-        enable_compilation_cache()
-        kernel = _raw_grid_kernel()
-
-        def wrapped(*args):
-            return tuple(jnp.broadcast_arrays(*kernel(*args)))
-
-        mesh = Mesh(np.asarray(jax.devices()[:shards]), ("lane",))
-        col_spec = P(None, None)                       # (D, 1) constants
-        if tile_rank == 1:
-            tile_spec, out_spec = P("lane"), P(None, "lane")
-        else:
-            tile_spec = P(None, None, "lane")
-            out_spec = P(None, None, "lane")
-        fn = jax.jit(jax.shard_map(
-            wrapped, mesh=mesh,
-            in_specs=(col_spec,) * 19 + (tile_spec,) * 5 + (P(),),
-            out_specs=(out_spec,) * 10, check_vma=False))
-        _SHARDED_GRID_KERNELS[key] = fn
-    return fn
-
-
-def _coerce_tile_args(n_inputs, rows_used, cols_used, weight_loads,
-                      schedule_os):
-    """Shared tile-argument canonicalization for both dispatch modes."""
-    n_inputs = np.atleast_1d(np.asarray(n_inputs, dtype=np.int64))
-    rows_used = np.atleast_1d(np.asarray(rows_used, dtype=np.int64))
-    cols_used = np.atleast_1d(np.asarray(cols_used, dtype=np.int64))
-    weight_loads = np.broadcast_to(
-        np.asarray(weight_loads, dtype=np.int64), n_inputs.shape)
-    sched_os = np.broadcast_to(
-        np.asarray(schedule_os, dtype=bool), n_inputs.shape)
-    return n_inputs, rows_used, cols_used, weight_loads, sched_os
-
-
-def _dispatch_grid_kernel(designs, n_inputs, rows_used, cols_used,
-                          weight_loads, sched_os, alpha, realize: bool):
-    """One fused grid-kernel dispatch (counters, shard selection, span).
-
-    The single code path behind both consumers: ``tile_energy_grid``
-    (``realize=True`` — results come back as host float64 arrays, so
-    the span wall covers dispatch through device completion) and the
-    reduced sweep's sharded stage-1 (``realize=False`` — the raw jax
-    arrays stay on device and the dispatch is *asynchronous*: the span
-    covers dispatch only, and device time is attributed by whoever
-    later blocks on the results, e.g. the reduced sweep's finalize
-    span).  Returns ``(parts, sharded)``.
-    """
-    import jax
-
-    # 1-D tile args broadcast straight against the (D, 1) design columns;
-    # layer-stacked (..., L, C) args get the design axis spliced in
-    # before the candidate axis.
-    tile = (lambda a: a) if n_inputs.ndim == 1 else (lambda a: a[..., None, :])
-
-    _C_KERNEL_CALLS.inc()
-    _GRID_KERNEL_SHAPES.add((n_inputs.shape, len(designs.rows)))
-    _G_KERNEL_SHAPES.set(len(_GRID_KERNEL_SHAPES))
-
-    # lane-sharded path: only when the lane axis divides evenly over the
-    # mesh and every tile arg shares the full lane shape (the fused
-    # sweep always satisfies both via the shard-aware pad quantum);
-    # anything else falls back to the single-device jit.
-    shards = lane_shards()
-    kern = None
-    sharded = False
-    if shards > 1 and n_inputs.shape[-1] % shards == 0 \
-            and rows_used.shape == n_inputs.shape \
-            and cols_used.shape == n_inputs.shape:
-        if shards <= jax.device_count():
-            kern = _sharded_grid_kernel(
-                shards, 1 if n_inputs.ndim == 1 else 3)
-            _C_KERNEL_SHARDED.inc()
-            sharded = True
-    if kern is None:
-        kern = _grid_kernel()
-
-    cst = _design_constants(designs)
-    col = lambda a: a[:, None]                     # (D,) -> (D, 1)
-    with jax.enable_x64(True):
-        parts = kern(
-            col(cst["analog"]), col(cst["mmux1"]), col(cst["rows"]),
-            col(cst["d1"]), col(cst["bw"]), col(cst["m"]),
-            col(cst["cc_bs"]), col(cst["e_wl_line"]),
-            col(cst["e_bl_word"]), col(cst["p_logic"]),
-            col(cst["adc_e"]), col(cst["denom_adc"]),
-            col(cst["cols_per_adc"]), col(cst["f_tree_a"]),
-            col(cst["f_tree_d"]), col(cst["p_tree"]),
-            col(cst["denom_occ"]), col(cst["dac_e"]), col(cst["p_write"]),
-            tile(n_inputs), tile(rows_used), tile(cols_used),
-            tile(weight_loads), tile(sched_os), alpha)
-        if realize:
-            # np.asarray forces execution, so the caller's span covers
-            # dispatch through device completion (compile included
-            # on a fresh shape).
-            parts = tuple(np.asarray(p, dtype=np.float64)
-                          for p in parts)
-    return parts, sharded
-
-
 def tile_energy_grid(designs, n_inputs, rows_used, cols_used,
                      weight_loads: np.ndarray | int = 1,
                      alpha: float = DEFAULT_ALPHA,
@@ -759,12 +599,31 @@ def tile_energy_grid(designs, n_inputs, rows_used, cols_used,
     sweep (``dse.sweep``/``sweep_networks``) relies on this to price a
     whole network in one compile.
     """
-    (n_inputs, rows_used, cols_used, weight_loads,
-     sched_os) = _coerce_tile_args(n_inputs, rows_used, cols_used,
-                                   weight_loads, schedule_os)
-    parts, _ = _dispatch_grid_kernel(designs, n_inputs, rows_used,
-                                     cols_used, weight_loads, sched_os,
-                                     alpha, realize=True)
+    import jax
+
+    n_inputs = np.atleast_1d(np.asarray(n_inputs, dtype=np.int64))
+    rows_used = np.atleast_1d(np.asarray(rows_used, dtype=np.int64))
+    cols_used = np.atleast_1d(np.asarray(cols_used, dtype=np.int64))
+    weight_loads = np.broadcast_to(
+        np.asarray(weight_loads, dtype=np.int64), n_inputs.shape)
+    sched_os = np.broadcast_to(
+        np.asarray(schedule_os, dtype=bool), n_inputs.shape)
+    # 1-D tile args broadcast straight against the (D, 1) design columns;
+    # layer-stacked (..., L, C) args get the design axis spliced in
+    # before the candidate axis.
+    tile = (lambda a: a) if n_inputs.ndim == 1 else (lambda a: a[..., None, :])
+
+    _C_KERNEL_CALLS.inc()
+    _GRID_KERNEL_SHAPES.add((n_inputs.shape, len(designs.rows)))
+    _G_KERNEL_SHAPES.set(len(_GRID_KERNEL_SHAPES))
+
+    cst = _design_constants(designs)
+    with jax.enable_x64(True):
+        parts = _grid_kernel()(
+            *(cst[k][:, None] for k in _RAW_DESIGN_ARGS),
+            tile(n_inputs), tile(rows_used), tile(cols_used),
+            tile(weight_loads), tile(sched_os), alpha)
+        parts = tuple(np.asarray(p, dtype=np.float64) for p in parts)
     (e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write, macs,
      x_adc, x_dac) = parts
     # OS conversion-phase terms fold in with the scalar association
@@ -781,10 +640,10 @@ def tile_energy_grid(designs, n_inputs, rows_used, cols_used,
 
 
 # --------------------------------------------------------------------------- #
-# device-side objective reduction (stage 2 of the reduced sweep path)          #
+# the reduced sweep route: grid kernel, terms and argmin on the device         #
 # --------------------------------------------------------------------------- #
 #: finite masked-lane sentinels for the fused argmin (shared with the
-#: host oracle in ``dse``).  Illegal and padded lanes never carry
+#: host reference in ``dse``).  Illegal and padded lanes never carry
 #: inf/NaN: their well-defined finite garbage is replaced by the largest
 #: representable value of the objective dtype, which any real candidate
 #: cost undercuts — so the argmin stays FMA-safe (no 0*inf / inf-inf
@@ -794,10 +653,8 @@ def tile_energy_grid(designs, n_inputs, rows_used, cols_used,
 SENTINEL_F64 = np.float64(np.finfo(np.float64).max)
 SENTINEL_I64 = np.int64(np.iinfo(np.int64).max)
 
-#: stage-2 jit caches: ``has_os -> terms closure`` (split form, for the
-#: sharded stage-1 path), ``has_os -> fused stage-1+terms closure``
-#: (unsharded fast path) and ``(objective, n_segments) -> argmin
-#: closure``.
+#: the reduced route's jit caches: ``has_os -> fused grid-and-terms
+#: closure`` and ``(objective, n_segments) -> argmin closure``.
 #:
 #: WHY TWO EXECUTABLES: XLA:CPU contracts ``a*b + c`` into a fused
 #: multiply-add during LLVM codegen whenever a float product feeds an
@@ -805,14 +662,17 @@ SENTINEL_I64 = np.int64(np.iinfo(np.int64).max)
 #: does NOT stop it (measured on this backend: identical 1-ULP drift
 #: with and without the barrier; a double ``bitcast_convert_type``
 #: fence gets folded away too).  Splitting at the executable boundary
-#: is the one fence codegen cannot see through: the *terms* side is
-#: addition-free in float except for uncontractable adds (see below),
-#: the *argmin* kernel consumes the materialized term buffers as
-#: program parameters so its chained adds have no producer multiply in
-#: scope.  Both dispatches stay asynchronous and the intermediate term
-#: buffers never leave the device.
+#: is the one fence codegen cannot see through, so each bucket runs one
+#: chain of two: the *terms* executable (:func:`_reduced_fused_kernel`:
+#: the grid kernel, the OS fold, the active-macro scaling and the
+#: traffic products) is addition-free in float except for uncontractable
+#: adds (see below), and the *argmin* executable
+#: (:func:`_reduce_argmin_kernel`) consumes its materialized term
+#: buffers as program parameters, so its chained adds have no producer
+#: multiply in scope.  Both dispatches stay asynchronous and the
+#: intermediate term buffers never leave the device.
 #:
-#: WHY THE FUSED TERMS KERNEL IS STILL SAFE: the raw grid kernel body
+#: WHY THE TERMS EXECUTABLE IS SAFE: the raw grid kernel body
 #: (:func:`_raw_grid_kernel`) contains NO float additions — every
 #: energy term is a chain of multiplies, divides and selects — so
 #: fusing the scaling and traffic *products* into the same module
@@ -823,7 +683,6 @@ SENTINEL_I64 = np.int64(np.iinfo(np.int64).max)
 #: FMA contraction needs a multiply feeding an add, so neither side of
 #: the fold can contract.  The add CHAIN (the objective total) is what
 #: must stay behind the executable boundary.
-_REDUCE_TERMS_KERNELS: dict = {}
 _REDUCED_FUSED_KERNELS: dict = {}
 _REDUCE_ARGMIN_KERNELS: dict = {}
 
@@ -857,7 +716,7 @@ _RAW_DESIGN_ARGS = ("analog", "mmux1", "rows", "d1", "bw", "m", "cc_bs",
 
 #: host arrays handed to the device by the reduced route: the design
 #: block's two at each put, and each bucket's dispatch (its lane block
-#: and ``legal_rows``; the sharded route adds its stage-1 columns).
+#: and ``legal_rows``).
 #: Kept with the sweep's counters, so ``dse.cache_clear`` resets it.
 _C_H2D = obs.counter("dse.h2d_arrays")
 
@@ -942,14 +801,12 @@ class DesignBlock:
     ``f64`` holds :data:`_DESIGN_F64_ROWS` and ``i64``
     :data:`_DESIGN_I64_ROWS`, each ``(rows, D)``.  ``design_class`` is
     the host copy of the classes the block holds: every bucket priced
-    with the block must carry the same.  ``alpha`` stays on the host for
-    the sharded route's stage-1, which keeps its own arguments.
+    with the block must carry the same.
     """
 
     f64: object                  # jax float64 (len(_DESIGN_F64_ROWS), D)
     i64: object                  # jax int64 (len(_DESIGN_I64_ROWS), D)
     design_class: np.ndarray     # (D,) int
-    alpha: float
 
 
 def put_design_block(designs, design_class, *, alpha: float, per_bit,
@@ -971,16 +828,16 @@ def put_design_block(designs, design_class, *, alpha: float, per_bit,
         f64, i64 = jax.device_put((f64, i64))
     _C_H2D.inc(2)
     return DesignBlock(f64=f64, i64=i64,
-                       design_class=np.asarray(design_class),
-                       alpha=float(alpha))
+                       design_class=np.asarray(design_class))
 
 
 def _terms(parts, f, lanes, has_os: bool):
-    """Stage-2a body: OS fold + active-macro scaling + traffic products.
+    """The terms half of :func:`_reduced_fused_kernel`: OS fold +
+    active-macro scaling + traffic products.
 
     ``parts`` are the raw grid kernel's seven energy terms and its two
     OS extras; ``f`` and ``lanes`` give the float design rows and the
-    lane rows (:class:`_Rows`).  Reproduces the host oracle's per-term
+    lane rows (:class:`_Rows`).  Reproduces the host reference's per-term
     float ops exactly: the fold (``e_adc + x_adc`` on raw kernel
     outputs, before scaling — adds whose operands end in
     ``fdiv``/``select``, uncontractable), the two-multiply ``(x *
@@ -1009,46 +866,23 @@ def _terms(parts, f, lanes, has_os: bool):
     return tuple(terms)
 
 
-def _reduce_terms_kernel(has_os: bool):
-    """Stage-2a alone (:func:`_terms`), for the sharded route: takes
-    the stage-1 terms and OS extras as program parameters, then the
-    float design block and the lane block."""
-    fn = _REDUCE_TERMS_KERNELS.get(has_os)
-    if fn is None:
-        import jax
-
-        from .compilecache import enable_compilation_cache
-        enable_compilation_cache()
-
-        def kernel(e_wl, e_bl, e_logic, e_adc, e_tree, e_dac, e_write,
-                   x_adc, x_dac, f64, lanes):
-            return _terms((e_wl, e_bl, e_logic, e_adc, e_tree, e_dac,
-                           e_write, x_adc, x_dac),
-                          _Rows((f64, _DESIGN_F64_ROWS)),
-                          _Rows((lanes, _LANE_ROWS)), has_os)
-
-        fn = jax.jit(kernel)
-        _REDUCE_TERMS_KERNELS[has_os] = fn
-    return fn
-
-
 def _reduced_fused_kernel(has_os: bool):
-    """Stage-1 grid kernel + stage-2a terms in ONE executable, on the
-    packed arguments ``(f64, i64, lanes)``.
+    """The first executable of each bucket: the grid kernel and the
+    terms (:func:`_terms`) in ONE jit module, on the packed arguments
+    ``(f64, i64, lanes)``.
 
-    The unsharded reduced path's fast dispatch: composes
-    :func:`_raw_grid_kernel` — fed (D, 1) design columns and (Ctot,)
-    lane rows sliced from the blocks — with :func:`_terms` inside a
-    single jit module, so stage-1's ten (D, C) float64 intermediates
-    are never materialized as buffers between executables — for a full
+    Composes :func:`_raw_grid_kernel` — fed (D, 1) design columns and
+    (Ctot,) lane rows sliced from the blocks — with :func:`_terms`, so
+    the grid kernel's ten (D, C) float64 intermediates are never
+    materialized as buffers between executables — for a full
     4M-element bucket that saves ~640 MB of memory traffic per dispatch
     plus one compile.
 
     Bitwise safety (see the cache-block comment above): the raw kernel
     body has no float adds, the OS fold adds operands end in
-    ``fdiv``/``select`` and their sums feed multiplies, so the merged
-    module exposes no ``fmul``→``fadd`` edge for LLVM to contract —
-    every float op lands exactly as in the split two-kernel chain
+    ``fdiv``/``select`` and their sums feed multiplies, so the module
+    exposes no ``fmul``→``fadd`` edge for LLVM to contract — every float
+    op lands exactly as in the host reference's NumPy chain
     (property-pinned in ``tests/core/test_reduced_sweep.py``).  Row
     slices add no float arithmetic.
     """
@@ -1076,7 +910,8 @@ def _reduced_fused_kernel(has_os: bool):
 
 
 def _reduce_argmin_kernel(objective: str, n_segments: int):
-    """Stage-2b: the exact scalar add association + masked argmin.
+    """The second executable of each bucket: the exact scalar add
+    association + masked argmin.
 
     The eleven term grids enter as program parameters, so the chained
     adds below — the same ``(((e_wl+e_bl)+e_logic)+(e_adc+e_tree))+...``
@@ -1155,15 +990,15 @@ def _reduce_argmin_kernel(objective: str, n_segments: int):
     return fn
 
 
-def reduce_objective_grid(designs, *, block: DesignBlock, objective: str,
+def reduce_objective_grid(*, block: DesignBlock, objective: str,
                           seg_bounds: tuple, has_os: bool, n_inputs,
                           rows_used, cols_used, weight_loads, schedule_os,
                           active_macros, weight_tiles, wt_ipt,
                           write_cycles, weight_bits, input_bits,
                           output_bits, psum_bits, off_chip, legal_rows,
                           design_class):
-    """The reduced sweep's whole device chain: stage-1 grid kernel +
-    fold + scale + traffic + sentinel-masked per-segment argmin,
+    """The reduced sweep's whole device chain: grid kernel + fold +
+    scale + traffic + sentinel-masked per-segment argmin,
     returning ``(best_idx, total, cycles)`` as (S, D) jax arrays — S
     segment rows of (D,) winners, the only data that ever reaches the
     host.
@@ -1176,21 +1011,17 @@ def reduce_objective_grid(designs, *, block: DesignBlock, objective: str,
     handed over as it is, and ``design_class`` (D,), which must be the
     block's.  So a bucket hands the device two host arrays.
 
-    Unsharded (the default), stage-1 and the term products run as ONE
-    fused executable (:func:`_reduced_fused_kernel` — no ten-grid
-    materialization between stages); with ``REPRO_SWEEP_SHARDS`` > 1
-    the shard_map grid kernel is kept, on its own loose arguments, and
-    the split :func:`_reduce_terms_kernel` consumes its gathered
-    outputs.  Both routes end at the same argmin executable, and both
-    are bitwise identical to the host oracle.
+    Two executables: the grid kernel and the term products in ONE
+    (:func:`_reduced_fused_kernel` — no ten-grid materialization
+    between them), then the argmin (:func:`_reduce_argmin_kernel`),
+    bitwise identical to the host reference.
 
     The dispatch is asynchronous (nothing is blocked on here); callers
     pipeline over it and attribute device time where they synchronize.
-    ``energy.kernel.calls`` advances one per bucket exactly like the
-    host path (the fused route increments it directly, the sharded
-    route through ``_dispatch_grid_kernel``), and the reduction
-    registers its own distinct kernel-shape entry (the compile-count
-    proxy — it re-traces per (lane count, segment count, objective)).
+    ``energy.kernel.calls`` advances one per bucket exactly like
+    :func:`tile_energy_grid`, and the reduction registers its own
+    distinct kernel-shape entry (the compile-count proxy — it re-traces
+    per (lane count, segment count, objective)).
     """
     import jax
 
@@ -1223,22 +1054,11 @@ def reduce_objective_grid(designs, *, block: DesignBlock, objective: str,
     with jax.enable_x64(True):
         lane_block = jax.device_put(lane_block)
         _C_H2D.inc(2)                       # the lane block, legal_rows
-        if lane_shards() > 1:
-            # sharded stage-1: keep the split chain so shard_map owns the
-            # grid kernel (counters advance inside _dispatch_grid_kernel)
-            parts, _ = _dispatch_grid_kernel(
-                designs, *_coerce_tile_args(n_inputs, rows_used, cols_used,
-                                            weight_loads, schedule_os),
-                block.alpha, realize=False)
-            _C_H2D.inc(len(_RAW_DESIGN_ARGS) + 5)   # + 5 tile columns
-            terms = _reduce_terms_kernel(has_os)(
-                *parts[:7], *parts[8:], block.f64, lane_block)
-        else:
-            _C_KERNEL_CALLS.inc()
-            _GRID_KERNEL_SHAPES.add(((lanes,), n_designs))
-            _G_KERNEL_SHAPES.set(len(_GRID_KERNEL_SHAPES))
-            terms = _reduced_fused_kernel(has_os)(block.f64, block.i64,
-                                                  lane_block)
+        _C_KERNEL_CALLS.inc()
+        _GRID_KERNEL_SHAPES.add(((lanes,), n_designs))
+        _G_KERNEL_SHAPES.set(len(_GRID_KERNEL_SHAPES))
+        terms = _reduced_fused_kernel(has_os)(block.f64, block.i64,
+                                              lane_block)
         return argmin_k(*terms, block.i64, lane_block, legal_rows)
 
 

@@ -982,9 +982,9 @@ class NetworkGrid:
     :data:`PAD_QUANTUM` multiple with benign :data:`_PAD_LANE` filler.
     ``lane_layer`` maps each lane back to its segment so per-layer loop
     bounds enter the vectorized cost formulas as gathered columns, and
-    one ``energy.tile_energy_grid`` call prices every
-    (layer, design, candidate) triple of the bucket in a single jit
-    dispatch.
+    one jit dispatch prices every (layer, design, candidate) triple of
+    the bucket (:func:`reduce_network_grid`, or
+    :func:`evaluate_network_grid` for the full grids).
 
     Masks: ``valid`` (Ctot,) marks real (non-pad) lanes; legality is
     held per class as in :class:`MappingGrid` — ``legal_rows`` (U, Ctot)
@@ -1143,8 +1143,8 @@ class NetworkCostGrid:
 
 @dataclasses.dataclass(frozen=True)
 class ReducedNetworkCost:
-    """Device-resident winners of one fused bucket (the ``reduce=True``
-    output of :func:`evaluate_network_grid`).
+    """Device-resident winners of one fused bucket
+    (:func:`reduce_network_grid`).
 
     ``best_idx`` / ``total`` / ``cycles`` are (S, D) *jax* arrays — one
     row per shape slot of the bucket, still on device and possibly
@@ -1163,37 +1163,13 @@ class ReducedNetworkCost:
     transfer_bytes: int
 
 
-def evaluate_network_grid(net: NetworkGrid, designs,
-                          alpha: float | None = None, *,
-                          reduce: bool = False,
-                          objective: str = "energy",
-                          design_block=None, resident_bytes=None,
-                          buffer_bytes: int = 1 << 20):
-    """Vectorized :func:`evaluate` over a fused workload bucket: one
-    ``energy.tile_energy_grid`` jit dispatch for every layer shape in
-    the bucket.  Per-layer loop bounds enter as columns gathered
-    through ``net.lane_layer``, so each lane's formulas see exactly the
-    scalars the per-layer :func:`evaluate_grid` path would — every
-    legal lane is bitwise identical to it (and hence to the scalar
-    oracle).
-
-    ``reduce=True`` switches to the device-side reduction path: instead
-    of realizing full (D, Ctot) cost grids on the host, the energy-
-    total chain (same scalar add association, FMA-fenced), the traffic
-    pricing (``resident_bytes`` / ``buffer_bytes`` and the rates of
-    ``design_block``, as :func:`~repro.core.memory.traffic_energy_grid`
-    would price them) and the sentinel-masked first-min argmin all run
-    inside a second jit graph, and a :class:`ReducedNetworkCost` of
-    per-segment (S, D) winners comes back — asynchronously, without
-    blocking.  ``design_block`` (:func:`reduced_design_block`) carries
-    the per-design arguments, ``alpha`` included, already on the
-    device.  Bitwise identical to reducing the default
-    :class:`NetworkCostGrid` on the host (property-pinned in
-    ``tests/core/test_reduced_sweep.py``)."""
-    from .energy import DEFAULT_ALPHA, tile_energy_grid
-    if reduce and alpha is not None:
-        raise ValueError("reduce=True takes alpha from design_block")
-    alpha = DEFAULT_ALPHA if alpha is None else alpha
+def _network_lanes(net: NetworkGrid) -> dict[str, np.ndarray]:
+    """The integer lane columns of a fused bucket, each (Ctot,): tiling
+    counts, the tile arguments of the energy kernel, write cycles and
+    traffic bits.  Per-layer loop bounds enter gathered through
+    ``net.lane_layer``, so each lane sees exactly the scalars the
+    per-layer :func:`evaluate_grid` path would.  Integer and bool only,
+    so exact by construction."""
     batch = net.cand
     lay = net.lane_layer
 
@@ -1234,22 +1210,34 @@ def evaluate_network_grid(net: NetworkGrid, designs,
     psum_bits = (o_elems * p_prec
                  * np.where(is_os, np.int64(0),
                             2 * np.maximum(0, n_acc_tiles - 1)))
+    return dict(inputs_per_tile=inputs_per_tile, rows_used=rows_used,
+                cols_used=cols_used, weight_loads=weight_loads,
+                is_os=is_os, active_macros=active_macros,
+                weight_tiles=weight_tiles, write_cycles=write_cycles,
+                weight_bits=weight_bits, input_bits=input_bits,
+                output_bits=output_bits, psum_bits=psum_bits)
 
-    if reduce:
-        return _reduced_network_cost(
-            net, designs, objective, design_block, resident_bytes,
-            buffer_bytes,
-            inputs_per_tile=inputs_per_tile, rows_used=rows_used,
-            cols_used=cols_used, weight_loads=weight_loads, is_os=is_os,
-            active_macros=active_macros, weight_tiles=weight_tiles,
-            write_cycles=write_cycles,
-            weight_bits=weight_bits, input_bits=input_bits,
-            output_bits=output_bits, psum_bits=psum_bits)
+
+def evaluate_network_grid(net: NetworkGrid, designs,
+                          alpha: float | None = None) -> NetworkCostGrid:
+    """Vectorized :func:`evaluate` over a fused workload bucket: one
+    ``energy.tile_energy_grid`` jit dispatch for every layer shape in
+    the bucket, its (D, Ctot) cost grids realized on the host.  Every
+    legal lane is bitwise identical to the per-layer
+    :func:`evaluate_grid` path (and hence to the scalar oracle).  The
+    sweep's own route is :func:`reduce_network_grid`; this face is its
+    full-grid reference."""
+    from .energy import DEFAULT_ALPHA, tile_energy_grid
+    alpha = DEFAULT_ALPHA if alpha is None else alpha
+    ln = _network_lanes(net)
+    inputs_per_tile, weight_tiles = ln["inputs_per_tile"], ln["weight_tiles"]
+    active_macros = ln["active_macros"]
 
     e_tile = tile_energy_grid(designs, n_inputs=inputs_per_tile,
-                              rows_used=rows_used, cols_used=cols_used,
-                              weight_loads=weight_loads,
-                              alpha=alpha, schedule_os=is_os)
+                              rows_used=ln["rows_used"],
+                              cols_used=ln["cols_used"],
+                              weight_loads=ln["weight_loads"],
+                              alpha=alpha, schedule_os=ln["is_os"])
 
     # (f * active_macros) * weight_tiles with one temporary per field —
     # the in-place second multiply performs the identical float op the
@@ -1264,21 +1252,22 @@ def evaluate_network_grid(net: NetworkGrid, designs,
           for f in dataclasses.fields(e_tile)))
 
     cycles = (weight_tiles * inputs_per_tile
-              * _design_cc_per_input(designs)[:, None] + write_cycles)
+              * _design_cc_per_input(designs)[:, None]
+              + ln["write_cycles"])
 
     return NetworkCostGrid(
         net=net, macro_energy=macro_energy, weight_tiles=weight_tiles,
         inputs_per_tile=inputs_per_tile, cycles=cycles,
-        weight_bits=weight_bits, input_bits=input_bits,
-        output_bits=output_bits, psum_bits=psum_bits)
+        weight_bits=ln["weight_bits"], input_bits=ln["input_bits"],
+        output_bits=ln["output_bits"], psum_bits=ln["psum_bits"])
 
 
 def reduced_design_block(designs, design_class, *, per_bit,
                          alpha: float | None = None,
                          dram_fj_per_bit: float | None = None):
-    """The per-design arguments of :func:`evaluate_network_grid`'s
-    ``reduce=True`` route, put on the device once for every bucket of a
-    sweep over ``designs`` whose legality classes are ``design_class``
+    """The per-design arguments of :func:`reduce_network_grid`, put on
+    the device once for every bucket of a sweep over ``designs`` whose
+    legality classes are ``design_class``
     (:func:`repro.core.energy.put_design_block`).  ``per_bit`` is the
     SRAM traffic rate (scalar or (D,)), as
     :func:`~repro.core.memory.traffic_energy_grid` takes it."""
@@ -1294,35 +1283,41 @@ def reduced_design_block(designs, design_class, *, per_bit,
         cc_per_input=_design_cc_per_input(designs))
 
 
-def _reduced_network_cost(net, designs, objective, design_block,
-                          resident_bytes, buffer_bytes, *,
-                          inputs_per_tile, rows_used, cols_used,
-                          weight_loads, is_os, active_macros,
-                          weight_tiles, write_cycles,
-                          weight_bits, input_bits, output_bits,
-                          psum_bits) -> ReducedNetworkCost:
-    """``reduce=True`` tail of :func:`evaluate_network_grid`: stage-1
-    kernel dispatch kept on device, stage-2 reduction composed on top.
-    All host work here is integer/bool prep (exact by construction)."""
+def reduce_network_grid(net: NetworkGrid, *, objective: str,
+                        design_block, resident_bytes,
+                        buffer_bytes: int) -> ReducedNetworkCost:
+    """The sweep's pricing of one fused bucket, on the device: the
+    energy kernel, the energy-total chain (same scalar add association,
+    FMA-fenced), the traffic pricing (``resident_bytes`` /
+    ``buffer_bytes`` and the rates of ``design_block``, as
+    :func:`~repro.core.memory.traffic_energy_grid` would price them) and
+    the sentinel-masked first-min argmin
+    (:func:`repro.core.energy.reduce_objective_grid`).  Returns the
+    per-segment (S, D) winners, asynchronously, without blocking.
+
+    ``design_block`` (:func:`reduced_design_block`) carries every
+    per-design argument, the designs' constants and ``alpha`` included,
+    already on the device.  Bitwise identical to reducing
+    :func:`evaluate_network_grid`'s :class:`NetworkCostGrid` on the host
+    (property-pinned in ``tests/core/test_reduced_sweep.py``).  All host
+    work here is integer/bool prep (exact by construction)."""
     from .energy import reduce_objective_grid
     if objective not in ("energy", "latency", "edp"):
         raise KeyError(objective)
-    if design_block is None or resident_bytes is None:
-        raise ValueError(
-            "reduce=True requires design_block and resident_bytes")
+    ln = _network_lanes(net)
     seg_bounds = tuple((int(net.starts[s]), int(net.starts[s + 1]))
-                      for s in range(len(net.layers)))
+                       for s in range(len(net.layers)))
     best_idx, total, cycles = reduce_objective_grid(
-        designs, block=design_block, objective=objective,
-        seg_bounds=seg_bounds, has_os=bool(is_os.any()),
-        n_inputs=inputs_per_tile, rows_used=rows_used,
-        cols_used=cols_used, weight_loads=weight_loads,
-        schedule_os=is_os, active_macros=active_macros,
-        weight_tiles=weight_tiles,
-        wt_ipt=weight_tiles * inputs_per_tile,
-        write_cycles=write_cycles,
-        weight_bits=weight_bits, input_bits=input_bits,
-        output_bits=output_bits, psum_bits=psum_bits,
+        block=design_block, objective=objective,
+        seg_bounds=seg_bounds, has_os=bool(ln["is_os"].any()),
+        n_inputs=ln["inputs_per_tile"], rows_used=ln["rows_used"],
+        cols_used=ln["cols_used"], weight_loads=ln["weight_loads"],
+        schedule_os=ln["is_os"], active_macros=ln["active_macros"],
+        weight_tiles=ln["weight_tiles"],
+        wt_ipt=ln["weight_tiles"] * ln["inputs_per_tile"],
+        write_cycles=ln["write_cycles"],
+        weight_bits=ln["weight_bits"], input_bits=ln["input_bits"],
+        output_bits=ln["output_bits"], psum_bits=ln["psum_bits"],
         off_chip=np.asarray(resident_bytes) > buffer_bytes,
         legal_rows=net.legal_rows, design_class=net.design_class)
     nbytes = sum(a.dtype.itemsize * a.size

@@ -1,13 +1,14 @@
-"""Reduced + pipelined sweep engine parity (device-side reduction).
+"""Sweep route parity (device-side reduction) against the full-grid
+reference.
 
-The ``REPRO_SWEEP_PIPELINE`` path prices buckets through
-``mapping.evaluate_network_grid(reduce=True)`` — objective assembly and
-the masked per-segment argmin run inside the jit graph and only (S, D)
-winners cross the device→host boundary.  The contract these tests pin:
-the reduced path is **bitwise identical** to the retained full-grid
-host oracle (``dse._price_buckets``) — argmins (first-minimum
-tie-breaks included), totals, cycles, and masked poison-pad lanes —
-across random grids, layers, schedules and objectives.
+The sweep prices buckets through ``mapping.reduce_network_grid`` —
+objective assembly and the masked per-segment argmin run inside the jit
+graph and only (S, D) winners cross the device→host boundary.  The
+contract these tests pin: the route (``dse._price_shapes``) is
+**bitwise identical** to the full-grid host reference
+(``dse._price_shapes_reference``) — argmins (first-minimum tie-breaks
+included), totals, cycles, and masked poison-pad lanes — across random
+grids, layers, schedules, objectives and pipeline depths.
 """
 
 import numpy as np
@@ -16,8 +17,9 @@ import pytest
 from repro.testing.hypocompat import (  # real hypothesis when installed
     given, settings, st)
 
-from repro.core import designs, dse, workloads
+from repro.core import designs, dse, energy, workloads
 from repro.core.schedule import normalize
+from repro.faults import FaultSpec
 
 GRID_STRAT = dict(
     rows=st.sampled_from([(64,), (64, 256), (128, 512)]),
@@ -36,12 +38,6 @@ LAYER_STRAT = dict(
 )
 
 
-@pytest.fixture(autouse=True)
-def _restore_pipeline():
-    yield
-    dse.set_sweep_pipeline(None)
-
-
 def _grid(rows, cols, bw, adc_bits, m_mux, tech_nm):
     return designs.macro_grid(rows=rows, cols=cols, bw=bw,
                               adc_bits=adc_bits, m_mux=m_mux,
@@ -54,38 +50,61 @@ def _layer(k, c, ox, oy, name="r-layer"):
 
 
 def _price_both(shape_layers, grid, objective, scheds, depth=2,
-                survivors=None):
-    """Price the same shapes through the host oracle and the reduced
-    pipelined engine; return both per-shape result lists."""
+                survivors=None, alpha=None):
+    """Price the same shapes through the full-grid reference and the
+    sweep's route with ``depth`` buckets in flight; return both
+    per-shape result lists."""
     per_bit, buffer_bytes, dram = dse._mem_pricing(grid, None)
     sch = normalize(scheds)
     dse.cache_clear()
-    dse.set_sweep_pipeline(0)
-    host = dse._price_shapes(shape_layers, grid, objective, None,
-                             per_bit, buffer_bytes, dram, sch,
-                             survivors=survivors)
+    host = dse._price_shapes_reference(shape_layers, grid, objective,
+                                       alpha, per_bit, buffer_bytes, dram,
+                                       sch, survivors=survivors)
     dse.cache_clear()
-    dse.set_sweep_pipeline(depth)
-    red = dse._price_shapes(shape_layers, grid, objective, None,
-                            per_bit, buffer_bytes, dram, sch,
-                            survivors=survivors)
+    saved, dse._PIPELINE_DEPTH = dse._PIPELINE_DEPTH, depth
+    try:
+        red = dse._price_shapes(shape_layers, grid, objective, alpha,
+                                per_bit, buffer_bytes, dram, sch,
+                                survivors=survivors)
+    finally:
+        dse._PIPELINE_DEPTH = saved
     return host, red
+
+
+def _sweep_both(monkeypatch, nets, grid, **kw):
+    """``dse.sweep_networks`` through the full-grid reference (put in
+    ``_price_shapes``' place) and through the route."""
+    dse.cache_clear()
+    with monkeypatch.context() as m:
+        m.setattr(dse, "_price_shapes", dse._price_shapes_reference)
+        ref = dse.sweep_networks(nets, grid, **kw)
+    dse.cache_clear()
+    return ref, dse.sweep_networks(nets, grid, **kw)
+
+
+def _assert_sweeps_bitwise(ref, out):
+    assert [r.network for r in ref] == [r.network for r in out]
+    for a, b in zip(ref, out):
+        assert np.array_equal(a.energy_fj, b.energy_fj)
+        assert np.array_equal(a.cycles, b.cycles)
+        assert len(a._shapes) == len(b._shapes)
+        for sa, sb in zip(a._shapes, b._shapes):
+            assert np.array_equal(sa[2], sb[2])    # winners per design
 
 
 @pytest.fixture
 def legality_seen(monkeypatch):
     """Record the legality pair of every reduced dispatch."""
-    from repro.core import energy
     seen = []
     real = energy.reduce_objective_grid
 
-    def spy(designs, **kw):
+    def spy(**kw):
         # any other (D, lanes) bool argument would be a per-design mask
         assert not [k for k, v in kw.items()
                     if k != "legal_rows" and np.ndim(v) == 2
                     and np.asarray(v).dtype == bool]
         seen.append((kw["legal_rows"], kw["design_class"]))
-        return real(designs, **kw)
+        return real(**kw)
 
     monkeypatch.setattr(energy, "reduce_objective_grid", spy)
     return seen
@@ -129,10 +148,10 @@ def test_reduced_matches_host_oracle(rows, cols, bw, adc_bits, m_mux,
 def test_class_legality_matches_host_oracle(legality_seen, faults,
                                             objective):
     """The reduced kernel gathers ``legal_rows[design_class]`` on the
-    device; winners, totals and cycles stay bitwise the host oracle's.
-    Fault-free buckets carry one row per (d1, rows, n_macros) class;
+    device; winners, totals and cycles stay bitwise the full-grid
+    reference's.  Fault-free buckets carry one row per (d1, rows, n_macros) class;
     with a survivor mask every design is its own class (U = D)."""
-    from repro.faults import FaultSpec, survivor_mask
+    from repro.faults import survivor_mask
     grid = designs.macro_grid(rows=(64, 256), cols=(64, 512), bw=(2, 8),
                               adc_bits=(4, 8), m_mux=(1, 4), tech_nm=(28,),
                               n_macros=(1, 4))
@@ -166,11 +185,10 @@ def test_packed_dispatch_hands_few_host_arrays(monkeypatch, faults,
     per-design block on the device once, each bucket's dispatch hands
     it at most four host arrays (``dse.h2d_arrays``, and the
     ``h2d_arrays`` attr of every ``dse.price_bucket`` span), and
-    winners, totals and int64 cycles stay bitwise the depth-0 host
-    oracle's."""
+    winners, totals and int64 cycles stay bitwise the full-grid
+    reference's."""
     from repro import obs
-    from repro.core import energy
-    from repro.faults import FaultSpec, survivor_mask
+    from repro.faults import survivor_mask
     grid = designs.macro_grid(rows=(64, 256), cols=(64, 512), bw=(2, 8),
                               adc_bits=(4, 8), m_mux=(1, 4), tech_nm=(28,),
                               n_macros=(1, 4))
@@ -210,17 +228,28 @@ def test_packed_dispatch_hands_few_host_arrays(monkeypatch, faults,
 
 
 def test_reduce_refuses_a_loose_alpha():
-    """``reduce=True`` prices with the design block's ``alpha``; one
-    passed beside it would be ignored, so it is refused."""
-    from repro.core.mapping import evaluate_network_grid, network_grid
-    grid = _grid((64,), (64,), (2,), (4,), (1,), (28,))
-    layer = _layer(7, 5, 5, 1)
+    """The route prices with the design block's ``alpha`` and takes no
+    other: a block built at ``alpha=0.3`` prices bitwise like the
+    reference at 0.3 (and unlike the default), and
+    ``mapping.reduce_network_grid`` has no ``alpha`` to pass beside
+    it."""
+    from repro.core.mapping import network_grid, reduce_network_grid
+    grid = _grid((64, 256), (64,), (2, 8), (4, 8), (1, 4), (28,))
+    layers = [_layer(40, 24, 5, 7), _layer(12, 60, 16, 7, name="r-c")]
+    host, red = _price_both(layers, grid, "energy", ("ws", "os"),
+                            alpha=0.3)
+    _assert_slots_bitwise(host, red)
+    default, _ = _price_both(layers, grid, "energy", ("ws", "os"))
+    assert not np.array_equal(default[0][2], red[0][2])
+
     sch = normalize(("ws",))
     dse.cache_clear()
-    (net,) = network_grid([layer], grid, schedules=sch,
-                          grids=[dse._grid_for(layer, grid, sch)])
-    with pytest.raises(ValueError, match="design_block"):
-        evaluate_network_grid(net, grid, 0.3, reduce=True)
+    (net,) = network_grid(layers[:1], grid, schedules=sch,
+                          grids=[dse._grid_for(layers[0], grid, sch)])
+    with pytest.raises(TypeError, match="alpha"):
+        reduce_network_grid(net, objective="energy", design_block=None,
+                            resident_bytes=None, buffer_bytes=0,
+                            alpha=0.3)
 
 
 def _grid_1620():
@@ -256,7 +285,6 @@ def test_sweep_hands_class_legality_to_device(legality_seen, monkeypatch,
     spec = FaultSpec(column_fail_rate=0.3, seed=1) if faults else None
     monkeypatch.setattr(dse, "_BUCKET_ELEMS", 1)      # a bucket per shape
     dse.cache_clear()
-    dse.set_sweep_pipeline(2)
     obs.set_trace_enabled(True)
     obs.drain_spans()
     try:
@@ -286,7 +314,7 @@ def test_sweep_hands_class_legality_to_device(legality_seen, monkeypatch,
 def test_first_min_tie_break_parity():
     """Latency columns carry massive lane ties (cycles ignore most
     mapping knobs); assert ties genuinely exist, then that the reduced
-    argmin picks the same (first) lane as the host oracle."""
+    argmin picks the same (first) lane as the reference."""
     from repro.core.mapping import evaluate_network_grid, network_grid
     grid = _grid((64, 256), (64,), (2,), (4, 8), (1, 4), (28,))
     layers = [_layer(48, 32, 5, 7, name="tie-layer")]
@@ -331,59 +359,114 @@ def test_pad_lanes_masked_and_winners_legal():
 # --------------------------------------------------------------------------- #
 # end-to-end: sweep_networks totals through the public entry point             #
 # --------------------------------------------------------------------------- #
-def test_sweep_networks_end_to_end_parity():
+def test_sweep_networks_end_to_end_parity(monkeypatch):
     grid = _grid((64, 256), (64,), (2, 8), (4, 8), (1, 4), (28,))
     nets = [("resnet8", workloads.resnet8()),
             ("ae", workloads.deep_autoencoder())]
-    dse.cache_clear()
-    dse.set_sweep_pipeline(0)
-    ref = dse.sweep_networks(nets, grid, schedules=("ws", "os"))
-    dse.cache_clear()
-    dse.set_sweep_pipeline(2)
-    out = dse.sweep_networks(nets, grid, schedules=("ws", "os"))
-    for a, b in zip(ref, out):
-        assert np.array_equal(a.energy_fj, b.energy_fj)
-        assert np.array_equal(a.cycles, b.cycles)
-        for sa, sb in zip(a._shapes, b._shapes):
-            assert np.array_equal(sa[2], sb[2])
+    ref, out = _sweep_both(monkeypatch, nets, grid,
+                           schedules=("ws", "os"))
+    _assert_sweeps_bitwise(ref, out)
 
 
-def test_reduced_transfer_accounting():
-    """The reduced path must ship >= 5x less than the host path (the
+def test_reduced_transfer_accounting(monkeypatch):
+    """The route must ship >= 5x less than the full-grid reference (the
     acceptance floor; real grids are orders of magnitude beyond it)."""
     from repro import obs
     grid = _grid((64, 256), (64,), (2, 8), (4, 8), (1, 4), (28,))
     nets = [("resnet8", workloads.resnet8())]
     dse.cache_clear()
-    dse.set_sweep_pipeline(0)
-    dse.sweep_networks(nets, grid)
+    with monkeypatch.context() as m:
+        m.setattr(dse, "_price_shapes", dse._price_shapes_reference)
+        dse.sweep_networks(nets, grid)
     host_bytes = obs.snapshot("dse.")["dse.transfer_bytes"]
     dse.cache_clear()
-    dse.set_sweep_pipeline(2)
     dse.sweep_networks(nets, grid)
     red_bytes = obs.snapshot("dse.")["dse.transfer_bytes"]
     assert host_bytes >= 5 * red_bytes
 
 
 # --------------------------------------------------------------------------- #
-# REPRO_SWEEP_PIPELINE resolution                                              #
+# the route against the reference on a wide grid, at every pipeline depth     #
 # --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("spec,expect", [
-    (None, 2),                 # unset -> auto
-    ("auto", 2),
-    ("", 0), ("0", 0), ("off", 0), ("false", 0), ("none", 0),
-    ("disabled", 0),
-    ("1", 1), ("3", 3),
-    ("-4", 1),                 # integers clamp to >= 1
-    ("garbage", 2),            # unparsable -> auto
-])
-def test_pipeline_env_resolution(monkeypatch, spec, expect):
-    if spec is None:
-        monkeypatch.delenv("REPRO_SWEEP_PIPELINE", raising=False)
-    else:
-        monkeypatch.setenv("REPRO_SWEEP_PIPELINE", spec)
-    dse.set_sweep_pipeline(None)     # force re-read
-    assert dse.sweep_pipeline() == expect
+def _wide_grid():
+    """Multi-macro, mux-16 and 1024-row designs beside small ones."""
+    return designs.macro_grid(
+        rows=(64, 256, 1024), cols=(128, 512), adc_bits=(4, 8),
+        dac_bits=(1, 2), m_mux=(1, 16), tech_nm=(22,), vdd=(0.8,),
+        n_macros=(1, 2, 4))
+
+
+@pytest.mark.parametrize("objective", ["energy", "latency", "edp"])
+@pytest.mark.parametrize("faults", [False, True], ids=["faults-off",
+                                                       "faults-on"])
+def test_route_matches_reference_on_wide_grid(monkeypatch, faults,
+                                              objective):
+    """Two networks over the wide grid, ws+os: the route's totals,
+    cycles and winners equal the reference's bit for bit, with survivor
+    masks off and on (and the mask really moves a total)."""
+    grid = _wide_grid()
+    nets = [("dae", workloads.deep_autoencoder()),
+            ("ds_cnn", workloads.ds_cnn())]
+    spec = (FaultSpec(column_fail_rate=0.3, macro_fail_rate=0.3, seed=5)
+            if faults else None)
+    ref, out = _sweep_both(monkeypatch, nets, grid, objective=objective,
+                           schedules=("ws", "os"), faults=spec)
+    _assert_sweeps_bitwise(ref, out)
+    if faults:
+        dse.cache_clear()
+        clean = dse.sweep_networks(nets, grid, objective=objective,
+                                   schedules=("ws", "os"))
+        assert any(not np.array_equal(c.energy_fj, o.energy_fj)
+                   for c, o in zip(clean, out)), \
+            "fixture's survivor mask no longer moves a total"
+
+
+@pytest.mark.parametrize("objective", ["energy", "latency", "edp"])
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_pipeline_depth_leaves_winners(monkeypatch, depth, objective):
+    """A bucket per shape, so several buckets are in flight: at depth
+    1, 2 and 3 the route's winners, totals and cycles equal the
+    reference's, and the ``dse.pipeline.*`` telemetry names the depth
+    and the buckets."""
+    from repro import obs
+    monkeypatch.setattr(dse, "_BUCKET_ELEMS", 1)
+    grid = _grid((64, 256), (64, 512), (2, 8), (4, 8), (1, 4), (28,))
+    layers = [_layer(40, 24, 5, 7), _layer(96, 8, 1, 1, name="r-b"),
+              _layer(12, 60, 16, 7, name="r-c"),
+              _layer(64, 32, 5, 1, name="r-d")]
+    host, red = _price_both(layers, grid, objective, ("ws", "os"),
+                            depth=depth)
+    _assert_slots_bitwise(host, red)
+    snap = obs.snapshot("dse.pipeline.")
+    assert snap["dse.pipeline.depth"] == depth
+    assert snap["dse.pipeline.buckets"] == len(layers)
+
+
+@pytest.mark.parametrize("var,value", [("REPRO_SWEEP_PIPELINE", "0"),
+                                       ("REPRO_SWEEP_SHARDS", "4")])
+def test_sweep_ignores_retired_switches(monkeypatch, var, value):
+    """The sweep reads no environment switch: with either retired
+    variable set it returns the same winners, ships the same bytes (the
+    winners alone: three 8-byte values per shape and design) and
+    dispatches the same kernels as without it."""
+    from repro import obs
+    grid = _grid((64, 256), (64,), (2, 8), (4, 8), (1, 4), (28,))
+    nets = [("resnet8", workloads.resnet8())]
+
+    def sweep():
+        dse.cache_clear()
+        energy.grid_kernel_reset()
+        res = dse.sweep_networks(nets, grid, schedules=("ws", "os"))
+        return (res, obs.snapshot("dse.")["dse.transfer_bytes"],
+                energy.grid_kernel_info())
+
+    monkeypatch.setenv(var, value)
+    res, nbytes, info = sweep()
+    monkeypatch.delenv(var)
+    base, base_bytes, base_info = sweep()
+    _assert_sweeps_bitwise(base, res)
+    assert nbytes == base_bytes == 3 * 8 * base[0].n_shapes * len(grid)
+    assert info == base_info
 
 
 def test_resident_bytes_memo():

@@ -9,12 +9,11 @@ Degraded parity: under random survivor masks the fused engine's
 per-(layer, design) winner — totals, argmins *including tie-breaks*,
 finite sentinels in dead lanes — is bitwise the scalar oracle
 ``best_mapping_scalar(..., survivors=...)`` filtered to surviving
-mappings, on both the host full-grid path (``REPRO_SWEEP_PIPELINE=0``)
-and the reduced+pipelined default.
+mappings; and the sweep's route agrees bitwise with the full-grid host
+reference (``dse._price_shapes_reference``) under faults.
 """
 
 import numpy as np
-import pytest
 
 from repro.core import designs, dse, workloads
 from repro.core.memory import MemoryModel
@@ -33,14 +32,6 @@ def _nets():
     layers = [workloads.dense(f"l{i}", 1, 24 + 8 * i, 8)
               for i in range(3)]
     return [("net_a", layers[:2]), ("net_b", layers[1:])]
-
-
-@pytest.fixture
-def pipeline_off(monkeypatch):
-    monkeypatch.setenv("REPRO_SWEEP_PIPELINE", "0")
-    monkeypatch.setitem(dse._SWEEP_PIPELINE, "depth", None)
-    yield
-    monkeypatch.setitem(dse._SWEEP_PIPELINE, "depth", None)
 
 
 def test_faults_off_is_bitwise_inert():
@@ -102,7 +93,8 @@ def test_degraded_parity_pipelined(rate, seed):
                             seed=seed))
 
 
-def test_degraded_parity_host_path(pipeline_off):
+def test_degraded_parity_host_path():
+    """ws+os at rate 0.3 against the scalar reference."""
     spec = FaultSpec(column_fail_rate=0.3, macro_fail_rate=0.3, seed=13)
     _check_parity(spec, schedules=("ws", "os"))
 
@@ -111,11 +103,9 @@ def test_host_and_pipelined_agree_bitwise(monkeypatch):
     grid = _grid()
     nets = _nets()
     spec = FaultSpec(column_fail_rate=0.4, macro_fail_rate=0.4, seed=5)
-    monkeypatch.setitem(dse._SWEEP_PIPELINE, "depth", 2)
     piped = dse.sweep_networks(nets, grid, faults=spec)
-    monkeypatch.setitem(dse._SWEEP_PIPELINE, "depth", 0)
+    monkeypatch.setattr(dse, "_price_shapes", dse._price_shapes_reference)
     host = dse.sweep_networks(nets, grid, faults=spec)
-    monkeypatch.setitem(dse._SWEEP_PIPELINE, "depth", None)
     for a, b in zip(piped, host):
         np.testing.assert_array_equal(a.energy_fj, b.energy_fj)
         np.testing.assert_array_equal(a.cycles, b.cycles)

@@ -73,13 +73,13 @@ def test_cache_info_keys_unchanged():
 def test_grid_kernel_info_keys_unchanged():
     energy.grid_kernel_reset()
     info = energy.grid_kernel_info()
-    assert info == {"calls": 0, "distinct_shapes": 0, "sharded_calls": 0}
+    assert info == {"calls": 0, "distinct_shapes": 0}
     dse.cache_clear()
     dse.sweep_networks(_nets(), _grid())
     info = energy.grid_kernel_info()
     assert info["calls"] >= 1
     assert info["distinct_shapes"] >= 1
-    assert set(info) == {"calls", "distinct_shapes", "sharded_calls"}
+    assert set(info) == {"calls", "distinct_shapes"}
 
 
 def test_compilation_cache_info_keys_unchanged():

@@ -149,13 +149,9 @@ def pipelined_sweeps(traced_on, monkeypatch):
     shape or two so the main thread awaits, dispatches and finalizes
     several buckets."""
     monkeypatch.setattr(dse, "_BUCKET_ELEMS", 1)
-    dse.set_sweep_pipeline(2)
-    try:
-        for _ in range(2):
-            dse.cache_clear()
-            dse.sweep_networks(_nets(), _grid(), schedules=("ws", "os"))
-    finally:
-        dse.set_sweep_pipeline(None)
+    for _ in range(2):
+        dse.cache_clear()
+        dse.sweep_networks(_nets(), _grid(), schedules=("ws", "os"))
     return obs.drain_spans()
 
 

@@ -5,8 +5,9 @@ values intact, placement on the surviving devices only.
 
 The multi-device leg runs in a subprocess with
 ``--xla_force_host_platform_device_count=4`` (the suite's own process
-pins a single CPU device — the ``tests/core/test_sharded_sweep.py``
-trick); in-process tests cover the pure planning math.
+pins a single CPU device — the
+``tests/runtime/test_compressed_psum_subprocess.py`` trick); in-process
+tests cover the pure planning math.
 """
 
 import json
