@@ -58,7 +58,10 @@ invariants the engine maintains:
 
 * **Slot dedup** — layers sharing ``_shape_key`` (loop bounds +
   precisions, not the name) occupy one lattice slot, across networks;
-  ``cache_info()`` reports slot counts and padding waste.
+  ``cache_info()`` reports slot counts and padding waste.  Slots that
+  agree on ``mapping.lattice_key`` (the loop bounds a lattice reads,
+  not ``B`` or a precision) share one memoized lattice: a serving
+  lowering's projection at several token counts is built once.
 * **Flat lane axis** — per-shape union lattices are *concatenated*
   (``mapping.NetworkGrid``), never padded to a rectangular
   (L, C_max): each segment keeps its own scalar enumeration order, so
@@ -303,9 +306,12 @@ _CACHE: "collections.OrderedDict[tuple, LayerResult]" = \
     collections.OrderedDict()
 _CACHE_MAX = 4096
 
-#: per-shape union-lattice memo: (shape, designs signature, schedules,
-#: max_candidates) -> mapping.MappingGrid.  Repeated sweeps over the
-#: same design grid (the warm path of the fused engine) skip lattice
+#: union-lattice memo: (``mapping.lattice_key``, designs signature,
+#: schedules, max_candidates) -> mapping.MappingGrid.  Keyed by the loop
+#: bounds a lattice reads, not by ``_shape_key`` (which keys results):
+#: shapes that differ only in ``B`` or a precision share one grid, so a
+#: cold sweep builds each distinct lattice once.  Repeated sweeps over
+#: the same design grid (the warm path of the fused engine) skip lattice
 #: construction entirely.  Bounded LRU: grids carry (C,) candidate
 #: columns and per-class legality rows, so beyond ``_LATTICE_CACHE_MAX``
 #: entries the least-recently-used are evicted — a long-lived process
@@ -322,6 +328,8 @@ _C_HITS = obs.counter("dse.cache.hits")
 _C_MISSES = obs.counter("dse.cache.misses")
 _C_EVICTIONS = obs.counter("dse.cache.evictions")
 _C_LAT_EVICTIONS = obs.counter("dse.lattice.evictions")
+#: ``_grid_for`` lookups answered by the lattice memo
+_C_LAT_HITS = obs.counter("dse.lattice.hits")
 #: fused-lattice bookkeeping: distinct shape slots priced, eligible
 #: layers they covered, and the lane/padding-waste tally of every
 #: bucket dispatched (see ``cache_info``).
@@ -377,9 +385,8 @@ def _cache_key(layer: Layer, macro: IMCMacro, mem: MemoryModel,
 #: memoized ``_layer_resident_bytes`` per distinct shape key — the
 #: bucket pricing loops would otherwise recompute the element-count sum
 #: for every (bucket, layer) visit of the same shape.  Unbounded on
-#: purpose: entries are a few machine words and the key space is the
-#: distinct-shape space, which ``_LATTICE_CACHE`` already bounds in
-#: practice.
+#: purpose: entries are a few machine words, the key space is the
+#: distinct-shape space, and ``cache_clear`` empties it with the memos.
 _RESIDENT_CACHE: dict[tuple, int] = {}
 
 
@@ -588,8 +595,8 @@ _BUCKET_ELEMS = 1 << 22
 def _grid_for(layer: Layer, designs: MacroBatch, scheds,
               max_candidates: int = 4096):
     """Cached ``mapping.candidate_grid`` (see ``_LATTICE_CACHE``)."""
-    from .mapping import candidate_grid
-    key = (_shape_key(layer), designs.signature(), _schedule_names(scheds),
+    from .mapping import candidate_grid, lattice_key
+    key = (lattice_key(layer), designs.signature(), _schedule_names(scheds),
            max_candidates)
     grid = _LATTICE_CACHE.get(key)
     if grid is None:
@@ -605,6 +612,7 @@ def _grid_for(layer: Layer, designs: MacroBatch, scheds,
         _LATTICE_CACHE[key] = grid
     else:
         _LATTICE_CACHE.move_to_end(key)
+        _C_LAT_HITS.inc()
     return grid
 
 
@@ -616,7 +624,7 @@ def _with_survivors(net, survivors: SurvivorMask | None):
     differ per design, so the bucket is re-wrapped with one legality
     class per design: ``legal_rows = legal & fault_legal(...)`` (D,
     Ctot) and ``design_class = arange(D)``.  Grids in ``_LATTICE_CACHE``
-    stay fault-free (masks are per-sweep, caches are per-shape) and
+    stay fault-free (masks are per-sweep, caches per lattice key) and
     both consumers (the reduced kernel's class gather, the reference's
     host ``np.where`` sentinels) see the degraded legality through the
     fields they already consume.  The all-ones mapping
@@ -804,7 +812,8 @@ def _bucket_builder(shape_layers, designs, scheds, out_q,
                     [shape_layers[s] for s in members], designs,
                     schedules=scheds, grids=grids, pad_quantum=PAD_QUANTUM,
                     max_lanes=None)
-                sp.set(buckets=1, lanes=len(net))
+                sp.set(buckets=1, lanes=len(net),
+                       lattices=len({id(g) for g in grids}))
             ok = put(("bucket", tuple(members), net))
             members, grids, lanes = [], [], 0
             return ok

@@ -712,6 +712,19 @@ def _cum0(counts: np.ndarray) -> np.ndarray:
     return out[:-1]
 
 
+def lattice_key(layer: Layer) -> tuple[int, ...]:
+    """The loop bounds a :class:`MappingGrid` depends on: ``K, C, FX,
+    FY`` (column and row unrolls) and the macro-duplication dims ``OX,
+    OY, G`` (their options, and their product in
+    ``n_spatial_temporal``).
+
+    :func:`candidate_grid` and :func:`candidate_grid_loop` read nothing
+    else of the layer — not ``B``, not a precision, not the name — so
+    two layers with the same key get bitwise-identical grids over the
+    same designs, schedules and ``max_candidates``."""
+    return tuple(layer.dim(d) for d in COL_DIMS + ROW_DIMS + MACRO_DUP_DIMS)
+
+
 def candidate_grid(layer: Layer, designs,
                    max_candidates: int = 4096,
                    schedules=None) -> MappingGrid:

@@ -62,7 +62,9 @@ def test_layer_result_cache_lru_recency(small_caps):
 def test_lattice_cache_bounded_across_long_sweep_sequence(small_caps):
     """Regression pin for the unbounded-growth bug: a long sequence of
     sweeps over many distinct shapes holds at most the cap's worth of
-    lattice entries, with the overflow reported as evictions."""
+    lattice entries, with the overflow reported as evictions.  Each
+    layer has its own ``C``, so each is a distinct ``lattice_key`` and
+    a lattice entry of its own."""
     grid = _grid()
     for i in range(12):
         layer = workloads.dense(f"s{i}", 1, 24 + i, 8)

@@ -555,6 +555,8 @@ def test_cache_info_reports_lattice_stats():
     layers = workloads.deep_autoencoder()
     dse.sweep("dae", layers, grid)
     info = dse.cache_info()
+    # slots count shapes (``_shape_key``), not lattices; the 5 shapes
+    # here also differ in C or K, so they are 5 lattices as well
     assert info["lattice_slots"] == 5            # 5 distinct dense shapes
     assert info["lattice_layers"] == len(layers)
     assert 0.0 <= info["padding_waste"] < 1.0

@@ -317,3 +317,64 @@ def test_network_grid_shares_design_class_and_pads_false():
                                     grids=mixed, pad_quantum=256)
     assert np.array_equal(net_m.design_class, np.arange(len(grid)))
     assert np.array_equal(net_m.legal_rows, net.legal)
+
+
+# --------------------------------------------------------------------------- #
+# lattice_key: what a lattice reads, and nothing else                          #
+# --------------------------------------------------------------------------- #
+def _assert_same_lattice(a: mapping.MappingGrid,
+                         b: mapping.MappingGrid) -> None:
+    """Every candidate column, the class rows and the class index."""
+    assert len(a) == len(b)
+    for f in mapping._CAND_FIELDS:
+        assert np.array_equal(getattr(a.cand, f), getattr(b.cand, f)), f
+    assert np.array_equal(a.legal_rows, b.legal_rows)
+    assert np.array_equal(a.design_class, b.design_class)
+
+
+@pytest.mark.parametrize("grid_fn", [_grid_1620, _grid_multi_macro])
+@given(k=st.integers(1, 96), c=st.integers(1, 96),
+       ox=st.sampled_from([1, 5, 16]), oy=st.sampled_from([1, 7, 16]),
+       g=st.sampled_from([1, 4]), fx=st.sampled_from([1, 3]),
+       fy=st.sampled_from([1, 3]), b=st.sampled_from([(1, 64), (8, 1024)]),
+       precs=st.sampled_from([((4, 4, 24), (8, 8, 32)),
+                              ((2, 8, 16), (4, 2, 24))]),
+       dataflows=st.sampled_from([None, ("ws", "os")]))
+@settings(max_examples=4, deadline=None)
+def test_layers_sharing_lattice_key_get_identical_grids(
+        grid_fn, k, c, ox, oy, g, fx, fy, b, precs, dataflows):
+    """Two layers that agree on ``lattice_key`` but differ in ``B``, in
+    every precision and in name get bitwise-identical grids from both
+    builders: the lattice memo may hand one layer's grid to the other."""
+    grid = grid_fn()
+    dims = dict(K=k, C=c, OX=ox, OY=oy, G=g, FX=fx, FY=fy)
+    (w0, i0, p0), (w1, i1, p1) = precs
+    a = workloads.Layer("prefill.q", "dense", dict(dims, B=b[0]),
+                        w_prec=w0, i_prec=i0, psum_prec=p0)
+    z = workloads.Layer("decode.q", "dense", dict(dims, B=b[1]),
+                        w_prec=w1, i_prec=i1, psum_prec=p1)
+    assert mapping.lattice_key(a) == mapping.lattice_key(z)
+    assert dse._shape_key(a) != dse._shape_key(z)
+    _assert_same_lattice(
+        mapping.candidate_grid(a, grid, schedules=dataflows),
+        mapping.candidate_grid(z, grid, schedules=dataflows))
+    _assert_same_lattice(
+        mapping.candidate_grid_loop(a, grid, schedules=dataflows),
+        mapping.candidate_grid_loop(z, grid, schedules=dataflows))
+
+
+def test_lattice_key_reads_each_lattice_dim_and_defaults_to_one():
+    """A layer's missing dims read 1, as ``Layer.dim`` does; changing
+    any of the seven dims the builders read changes the key, and ``B``
+    does not."""
+    base = dict(K=8, C=16, FX=3, FY=3, OX=5, OY=7, G=2)
+    key = mapping.lattice_key(workloads.Layer("x", "conv2d", base))
+    assert key == (8, 16, 3, 3, 5, 7, 2)
+    assert mapping.lattice_key(workloads.dense("d", 4, 16, 8)) == \
+        mapping.lattice_key(workloads.Layer("e", "dense",
+                                            dict(K=8, C=16, OX=1, G=1)))
+    for d in base:
+        bumped = workloads.Layer("x", "conv2d", dict(base, **{d: 11}))
+        assert mapping.lattice_key(bumped) != key, d
+    assert mapping.lattice_key(
+        workloads.Layer("x", "conv2d", dict(base, B=64))) == key

@@ -5,8 +5,9 @@ the regime-dependence golden pin."""
 import numpy as np
 import pytest
 
-from repro import configs
-from repro.core import designs, dse, lm_bridge, mapping
+from repro import configs, obs
+from repro.configs import deepseek_v3
+from repro.core import designs, dse, lm_bridge, mapping, workloads
 from repro.core.workloads import PhaseWorkload, ServingPoint
 from repro.testing.hypocompat import given, settings, st
 
@@ -135,3 +136,89 @@ def test_serving_result_pareto_and_records():
     for i, name in enumerate(_GRID.names):
         assert by_name[name]["pareto"] == bool(mask[i])
         assert by_name[name]["tokens_per_s"] == float(res.tokens_per_s[i])
+
+
+def _shapes_and_keys(layers) -> tuple[int, int]:
+    """Distinct result slots (``_shape_key``) and distinct lattices
+    (``mapping.lattice_key``) among the IMC-eligible ``layers``."""
+    shapes = {dse._shape_key(l): l for l in layers if l.imc_eligible}
+    return len(shapes), len({mapping.lattice_key(l)
+                             for l in shapes.values()})
+
+
+def _cold_counts(run) -> tuple[object, int, int]:
+    """``run()`` after a cleared memo; its result with the lattice
+    builds and memo hits it counted."""
+    dse.cache_clear()
+    obs.reset("mapping.")
+    out = run()
+    snap = obs.snapshot()
+    return (out, snap.get("mapping.lattice.builds", 0),
+            snap.get("dse.lattice.hits", 0))
+
+
+@pytest.mark.parametrize("cfg", [configs.get_smoke("glm4-9b"),
+                                 deepseek_v3.smoke_config()],
+                         ids=["glm4-9b", "deepseek-v3"])
+def test_serving_sweep_builds_one_lattice_per_key(monkeypatch, cfg):
+    """A serving lowering repeats each projection at several token
+    counts; a cold sweep builds one lattice per distinct
+    ``lattice_key`` and answers the other slots from the memo, with the
+    same winners, totals and cycles as the full-grid reference and the
+    scalar oracle."""
+    points = lm_bridge.serving_points(cfg, [(16, 1), (64, 4), (256, 2)],
+                                      gen_len=8)
+    n_shapes, n_keys = _shapes_and_keys(
+        [l for pt in points for ph in pt.phases for l in ph.layers])
+    assert n_keys < n_shapes, "fixture no longer repeats a projection"
+
+    fused, builds, hits = _cold_counts(
+        lambda: dse.sweep_serving(points, _GRID, schedules=("ws", "os")))
+    assert builds == n_keys
+    assert hits == n_shapes - n_keys
+    obs.set_trace_enabled(True)
+    obs.drain_spans()
+    try:
+        _cold_counts(lambda: dse.sweep_serving(points, _GRID,
+                                               schedules=("ws", "os")))
+        (fused_span,) = [s for s in obs.drain_spans()
+                         if s["name"] == "dse.network_grid_build"]
+    finally:
+        obs.set_trace_enabled(None)
+    assert fused_span["attrs"]["lattices"] == n_keys
+
+    with monkeypatch.context() as m:
+        m.setattr(dse, "_price_shapes", dse._price_shapes_reference)
+        ref, _, _ = _cold_counts(
+            lambda: dse.sweep_serving(points, _GRID, schedules=("ws", "os")))
+    for a, b in zip(ref, fused):
+        for col in _COLS:
+            assert np.array_equal(getattr(a, col), getattr(b, col)), col
+        for sa, sb in zip(a.phase_sweeps, b.phase_sweeps):
+            assert np.array_equal(sa.energy_fj, sb.energy_fj)
+            assert np.array_equal(sa.cycles, sb.cycles)
+            for xa, xb in zip(sa._shapes, sb._shapes):
+                assert np.array_equal(xa[2], xb[2])   # winners per design
+    for d in (0, len(_GRID) - 1):
+        for pt, res in zip(points, fused):
+            o = dse.serving_point_scalar(pt, _GRID.macro_at(d),
+                                         schedules=("ws", "os"))
+            for col in _COLS:
+                assert getattr(res, col)[d] == o[col], (pt.name, col, d)
+            assert res.phase_sweeps[0].network_result(d) == \
+                dse.map_network(f"{pt.name}/{pt.phases[0].tag}",
+                                list(pt.phases[0].layers),
+                                _GRID.macro_at(d), schedules=("ws", "os"))
+
+
+def test_cnn_sweep_has_no_lattice_hits():
+    """CNN shapes differ in the dims a lattice reads: every slot builds
+    its own lattice and the memo answers none of them in a cold sweep."""
+    nets = [("ds_cnn", workloads.ds_cnn()),
+            ("resnet8", workloads.resnet8()),
+            ("dae", workloads.deep_autoencoder())]
+    n_shapes, n_keys = _shapes_and_keys([l for _, ls in nets for l in ls])
+    assert n_keys == n_shapes
+    _, builds, hits = _cold_counts(lambda: dse.sweep_networks(nets, _GRID))
+    assert builds == n_shapes
+    assert hits == 0

@@ -98,6 +98,8 @@ def test_counters_track_sweep_work(restore_tracing):
     obs.reset("mapping.")
     dse.sweep_networks(_nets(), _grid())
     snap = obs.snapshot()
+    # one per distinct lattice_key; the 3 shapes differ in C, so one
+    # per distinct shape too
     assert snap["mapping.lattice.builds"] >= 3      # one per distinct shape
     assert snap["dse.lattice.slots"] >= 3
     assert snap["energy.kernel.calls"] >= 1
